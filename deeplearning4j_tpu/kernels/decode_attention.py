@@ -13,15 +13,15 @@ Layout: q [S, H, D], k/v caches [S, H, T_max, D], lengths [S] (valid
 prefix per slot, i.e. pos + 1). The cache keeps T contiguous per head
 — decode attention is then a batched matvec over contiguous [T, D]
 panels (measured ~2x over the [S, T, H, D] layout on CPU, and the
-kernel's [S*H, T, D] flatten becomes a free reshape instead of a
-transpose). Inactive or short slots mask via the per-slot validity
-column — the executable shape never changes, which is what keeps the
-serving decode loop at zero recompiles.
+kernel's per-(slot, head) [blk_k, D] tile is a contiguous slab).
+Inactive or short slots mask by their length — the executable shape
+never changes, which is what keeps the serving decode loop at zero
+recompiles.
 
 On TPU this runs the Pallas kernel; elsewhere the fused-XLA einsum path
 is the default (the Pallas interpreter is for parity tests only).
 Matmuls use preferred_element_type=f32 (pallas guide: pitfalls #5);
-masks use the validity-column idiom from `flash_attention`.
+validity is computed in-kernel from the scalar-prefetched lengths.
 """
 from __future__ import annotations
 
@@ -97,199 +97,126 @@ def decode_attention_xla(q, k, v, lengths):
 # ---------------------------------------------------------------------------
 # Pallas TPU kernel
 # ---------------------------------------------------------------------------
-def _decode_kernel(q_ref, k_ref, v_ref, vm_ref, o_ref, m_s, l_s, acc_s, *,
-                   blk_k: int, scale: float, precision):
-    ki = pl.program_id(1)
-    num_kb = pl.num_programs(1)
+def decode_kernel(len_ref, q_ref, k_ref, v_ref, *refs, quant: bool,
+                  blk_k: int, scale: float, precision):
+    """One grid step (sequence, head, key-block) of online-softmax
+    decode attention; shared with :mod:`.paged_attention`, whose only
+    difference is WHICH [blk_k, D] tile the index maps fetch.
 
-    @pl.when(ki == 0)
+    Refs (leading grid dims squeezed): len_ref [S] scalar-prefetched
+    lengths; q [1, D]; k/v [blk_k, D]; then, for an int8 cache
+    (``quant``), ks/vs [1, blk_k] per-position f32 scales; then the
+    output [1, D] and the :func:`decode_scratch` tiles.
+
+    Everything stays 2-D — the running max/sum are (1, 1) tiles and the
+    reductions keep their dims — because Mosaic has no layout for the
+    1-D result of reducing a one-row tile. Validity is computed from
+    the length twice, as a lane row (scores) and a sublane column (V
+    rows), instead of transposing one into the other."""
+    ks_ref, vs_ref = refs[:2] if quant else (None, None)
+    o_ref, m_s, l_s, acc_s = refs[-4:]
+    kb = pl.program_id(2)
+
+    @pl.when(kb == 0)
     def _init():
-        m_s[:] = jnp.full_like(m_s, _NEG_INF)
-        l_s[:] = jnp.zeros_like(l_s)
-        acc_s[:] = jnp.zeros_like(acc_s)
+        m_s[...] = jnp.full_like(m_s, _NEG_INF)
+        l_s[...] = jnp.zeros_like(l_s)
+        acc_s[...] = jnp.zeros_like(acc_s)
 
-    # bf16 caches keep bf16 operands (MXU-native, f32 accumulation);
-    # only a true f32 cache runs f32 dots
+    length = len_ref[pl.program_id(0)]
+    mask = kb * blk_k + lax.broadcasted_iota(
+        jnp.int32, (1, blk_k), 1) < length
+    # bf16/int8 caches keep bf16 operands (MXU-native, f32 accumulation;
+    # int8 in [-127, 127] casts to bf16 exactly); only a true f32 cache
+    # runs f32 dots
     od = jnp.float32 if k_ref.dtype == jnp.float32 else jnp.bfloat16
-    q = q_ref[0].astype(od)                           # [1, D]
-    k_blk = k_ref[0].astype(od)                       # [blk_k, D]
-    v_blk = v_ref[0].astype(od)
-    s = jnp.dot(q, k_blk.T, precision=precision,
-                preferred_element_type=jnp.float32) * scale   # [1, blk_k]
-    mask = (vm_ref[0][:, 0] > 0)[None, :]
-    s = jnp.where(mask, s, _NEG_INF)
-    m_prev = m_s[:, 0]
-    l_prev = l_s[:, 0]
-    m_new = jnp.maximum(m_prev, s.max(axis=1))
+    s = lax.dot_general(q_ref[...].astype(od), k_ref[...].astype(od),
+                        (((1,), (1,)), ((), ())), precision=precision,
+                        preferred_element_type=jnp.float32) * scale
+    if quant:
+        s = s * ks_ref[...]                               # K dequant
+    s = jnp.where(mask, s, _NEG_INF)                      # [1, blk_k]
+    m_prev = m_s[...]
+    m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
     # where-guard keeps fully-masked rows at p=0 (exp(-inf - -inf) = 1
     # would fabricate uniform attention for an empty slot)
-    p = jnp.where(mask, jnp.exp(s - m_new[:, None]), 0.0)
-    # zero masked V rows too: p=0 there, but 0 * NaN = NaN would leak
-    # a recycled slot's non-finite stale tail into the accumulator
-    v_blk = jnp.where(mask.reshape(-1, 1), v_blk, jnp.zeros((), od))
+    p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
     corr = jnp.exp(m_prev - m_new)
-    m_s[:, 0] = m_new
-    l_s[:, 0] = l_prev * corr + p.sum(axis=1)
-    acc_s[:] = acc_s[:] * corr[:, None] + jnp.dot(
+    m_s[...] = m_new
+    l_s[...] = l_s[...] * corr + p.sum(axis=1, keepdims=True)
+    if quant:
+        # V dequant folds into p. Where-guard required: a poisoned stale
+        # tail carries NaN in its SCALE (kv_quant.quantize_rows) and
+        # 0 * NaN = NaN; the int8 values themselves are always finite,
+        # so a masked lane contributes exactly 0
+        p = jnp.where(mask, p * vs_ref[...], 0.0)
+        v_blk = v_ref[...].astype(od)
+    else:
+        # zero masked V rows: p=0 there, but 0 * NaN = NaN would leak a
+        # recycled slot's non-finite stale tail into the accumulator
+        col = kb * blk_k + lax.broadcasted_iota(
+            jnp.int32, (blk_k, 1), 0) < length
+        v_blk = jnp.where(col, v_ref[...], 0).astype(od)
+    acc_s[...] = acc_s[...] * corr + jnp.dot(
         p.astype(od), v_blk, precision=precision,
         preferred_element_type=jnp.float32)
 
-    @pl.when(ki == num_kb - 1)
+    @pl.when(kb == pl.num_programs(2) - 1)
     def _finalize():
-        l = jnp.maximum(l_s[:, 0], 1e-30)
-        o_ref[0] = (acc_s[:] / l[:, None]).astype(o_ref.dtype)
+        o_ref[...] = (acc_s[...] / jnp.maximum(l_s[...], 1e-30)
+                      ).astype(o_ref.dtype)
 
 
-def _decode_kernel_quant(q_ref, k_ref, v_ref, ks_ref, vs_ref, vm_ref,
-                         o_ref, m_s, l_s, acc_s, *,
-                         blk_k: int, scale: float, precision):
-    """int8 variant: K/V refs hold int8 values, ks/vs the per-position
-    f32 scales. Dequant happens HERE, in VMEM — the scale is folded
-    post-dot for K and into the probabilities for V, so HBM only ever
-    streams int8 (pallas guide §quantization)."""
-    ki = pl.program_id(1)
-    num_kb = pl.num_programs(1)
-
-    @pl.when(ki == 0)
-    def _init():
-        m_s[:] = jnp.full_like(m_s, _NEG_INF)
-        l_s[:] = jnp.zeros_like(l_s)
-        acc_s[:] = jnp.zeros_like(acc_s)
-
-    # int8 in [-127, 127] casts to bf16 exactly; dots stay MXU-native
-    q = q_ref[0].astype(jnp.bfloat16)                 # [1, D]
-    k_blk = k_ref[0].astype(jnp.bfloat16)             # [blk_k, D]
-    v_blk = v_ref[0].astype(jnp.bfloat16)
-    kscale = ks_ref[0][:, 0][None, :]                 # [1, blk_k]
-    vscale = vs_ref[0][:, 0][None, :]
-    s = jnp.dot(q, k_blk.T, precision=precision,
-                preferred_element_type=jnp.float32) * scale
-    s = s * kscale                                    # K dequant
-    mask = (vm_ref[0][:, 0] > 0)[None, :]
-    s = jnp.where(mask, s, _NEG_INF)
-    m_prev = m_s[:, 0]
-    l_prev = l_s[:, 0]
-    m_new = jnp.maximum(m_prev, s.max(axis=1))
-    p = jnp.where(mask, jnp.exp(s - m_new[:, None]), 0.0)
-    # V dequant folds into p. Where-guard required: a poisoned stale
-    # tail carries NaN in its SCALE (kv_quant.quantize_rows) and
-    # 0 * NaN = NaN; the int8 values themselves are always finite, so
-    # a masked lane contributes exactly 0
-    pv = jnp.where(mask, p * vscale, 0.0)
-    corr = jnp.exp(m_prev - m_new)
-    m_s[:, 0] = m_new
-    l_s[:, 0] = l_prev * corr + p.sum(axis=1)
-    acc_s[:] = acc_s[:] * corr[:, None] + jnp.dot(
-        pv.astype(jnp.bfloat16), v_blk, precision=precision,
-        preferred_element_type=jnp.float32)
-
-    @pl.when(ki == num_kb - 1)
-    def _finalize():
-        l = jnp.maximum(l_s[:, 0], 1e-30)
-        o_ref[0] = (acc_s[:] / l[:, None]).astype(o_ref.dtype)
-
-
-def _decode_pallas_quant(q, k, v, lengths, block_k, precision, interpret):
-    """Quantized-pool path of :func:`decode_attention_pallas` — same
-    grid/flatten, two extra scale operands riding the K/V index maps."""
-    S, H, T, D = k.shape
-    blk_k = min(block_k, max(8, T))
-    t_pad = _cdiv(T, blk_k) * blk_k
-    kf = k.q.reshape(S * H, T, D)
-    vf = v.q.reshape(S * H, T, D)
-    ksf = k.scale.reshape(S * H, T, 1)
-    vsf = v.scale.reshape(S * H, T, 1)
-    qf = q.reshape(S * H, 1, D)
-    vm = (jnp.arange(T)[None, :] < lengths[:, None]).astype(
-        jnp.float32)[:, :, None]                       # [S, T, 1]
-    if t_pad != T:
-        pad = ((0, 0), (0, t_pad - T), (0, 0))
-        kf, vf, vm = jnp.pad(kf, pad), jnp.pad(vf, pad), jnp.pad(vm, pad)
-        ksf, vsf = jnp.pad(ksf, pad), jnp.pad(vsf, pad)
-    kernel = functools.partial(_decode_kernel_quant, blk_k=blk_k,
-                               scale=1.0 / (D ** 0.5), precision=precision)
-    out = pl.pallas_call(
-        kernel,
-        grid=(S * H, t_pad // blk_k),
-        in_specs=[
-            pl.BlockSpec((1, 1, D), lambda sh, ki: (sh, 0, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, blk_k, D), lambda sh, ki: (sh, ki, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, blk_k, D), lambda sh, ki: (sh, ki, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, blk_k, 1), lambda sh, ki: (sh, ki, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, blk_k, 1), lambda sh, ki: (sh, ki, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, blk_k, 1), lambda sh, ki: (sh // H, ki, 0),
-                         memory_space=pltpu.VMEM),
-        ],
-        out_specs=pl.BlockSpec((1, 1, D), lambda sh, ki: (sh, 0, 0),
-                               memory_space=pltpu.VMEM),
-        out_shape=jax.ShapeDtypeStruct((S * H, 1, D), q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((1, 1), jnp.float32),   # running max
-            pltpu.VMEM((1, 1), jnp.float32),   # running sum
-            pltpu.VMEM((1, D), jnp.float32),   # output accumulator
-        ],
-        interpret=interpret,
-    )(qf, kf, vf, ksf, vsf, vm)
-    return out.reshape(S, H, D)
+def decode_scratch(D: int):
+    """VMEM scratch of :func:`decode_kernel`."""
+    return [pltpu.VMEM((1, 1), jnp.float32),    # running max
+            pltpu.VMEM((1, 1), jnp.float32),    # running sum
+            pltpu.VMEM((1, D), jnp.float32)]    # output accumulator
 
 
 def decode_attention_pallas(q, k, v, lengths, block_k: int = 128,
                             precision=lax.Precision.DEFAULT,
                             interpret: Optional[bool] = None):
     """Pallas decode attention. Same contract as
-    :func:`decode_attention_xla`; grid (S*H, k-blocks) with the
-    per-slot validity column shared across heads via the ``sh // H``
-    index map (the `flash_attention` mask idiom). int8 QuantArray
-    caches route to the in-kernel-dequant variant."""
+    :func:`decode_attention_xla`; grid (S, H, k-blocks) with the
+    lengths scalar-prefetched, so validity is computed in-kernel and a
+    ragged last k-block needs no padded copy of the cache (whatever the
+    edge tile reads past T sits at positions >= length, masked). int8
+    QuantArray caches add their per-position scale rows as two more
+    operands riding the K/V index maps; dequant happens in VMEM, HBM
+    only ever streams int8."""
     if interpret is None:
         interpret = default_platform() != "tpu"
-    if is_quantized(k) or is_quantized(v):
-        if not (is_quantized(k) and is_quantized(v)):
-            raise ValueError("K and V caches must be quantized together")
-        return _decode_pallas_quant(q, k, v, lengths, block_k, precision,
-                                    interpret)
+    quant = is_quantized(k)
+    if quant != is_quantized(v):
+        raise ValueError("K and V caches must be quantized together")
     S, H, T, D = k.shape
-    blk_k = min(block_k, max(8, T))
-    t_pad = _cdiv(T, blk_k) * blk_k
-    # [S, H, T, D] -> [S*H, T_pad, D]: a free reshape, T is contiguous
-    kf = k.reshape(S * H, T, D)
-    vf = v.reshape(S * H, T, D)
-    qf = q.reshape(S * H, 1, D)
-    vm = (jnp.arange(T)[None, :] < lengths[:, None]).astype(
-        jnp.float32)[:, :, None]                       # [S, T, 1]
-    if t_pad != T:
-        pad = ((0, 0), (0, t_pad - T), (0, 0))
-        kf, vf, vm = jnp.pad(kf, pad), jnp.pad(vf, pad), jnp.pad(vm, pad)
-    kernel = functools.partial(_decode_kernel, blk_k=blk_k,
-                               scale=1.0 / (D ** 0.5), precision=precision)
+    blk_k = min(block_k, T)
+    q_spec = pl.BlockSpec((None, None, 1, D),
+                          lambda s, h, kb, lens: (s, h, 0, 0))
+    kv_spec = pl.BlockSpec((None, None, blk_k, D),
+                           lambda s, h, kb, lens: (s, h, kb, 0))
+    operands, in_specs = [q.reshape(S, H, 1, D)], [q_spec]
+    if quant:
+        sc_spec = pl.BlockSpec((None, None, 1, blk_k),
+                               lambda s, h, kb, lens: (s, h, 0, kb))
+        operands += [k.q, v.q, k.scale.reshape(S, H, 1, T),
+                     v.scale.reshape(S, H, 1, T)]
+        in_specs += [kv_spec, kv_spec, sc_spec, sc_spec]
+    else:
+        operands += [k, v]
+        in_specs += [kv_spec, kv_spec]
     out = pl.pallas_call(
-        kernel,
-        grid=(S * H, t_pad // blk_k),
-        in_specs=[
-            pl.BlockSpec((1, 1, D), lambda sh, ki: (sh, 0, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, blk_k, D), lambda sh, ki: (sh, ki, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, blk_k, D), lambda sh, ki: (sh, ki, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, blk_k, 1), lambda sh, ki: (sh // H, ki, 0),
-                         memory_space=pltpu.VMEM),
-        ],
-        out_specs=pl.BlockSpec((1, 1, D), lambda sh, ki: (sh, 0, 0),
-                               memory_space=pltpu.VMEM),
-        out_shape=jax.ShapeDtypeStruct((S * H, 1, D), q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((1, 1), jnp.float32),   # running max
-            pltpu.VMEM((1, 1), jnp.float32),   # running sum
-            pltpu.VMEM((1, D), jnp.float32),   # output accumulator
-        ],
+        functools.partial(decode_kernel, quant=quant, blk_k=blk_k,
+                          scale=1.0 / (D ** 0.5), precision=precision),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,          # lengths
+            grid=(S, H, _cdiv(T, blk_k)),
+            in_specs=in_specs, out_specs=q_spec,
+            scratch_shapes=decode_scratch(D)),
+        out_shape=jax.ShapeDtypeStruct((S, H, 1, D), q.dtype),
         interpret=interpret,
-    )(qf, kf, vf, vm)
+    )(jnp.asarray(lengths, jnp.int32), *operands)
     return out.reshape(S, H, D)
 
 

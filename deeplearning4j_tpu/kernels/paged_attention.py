@@ -37,8 +37,9 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .decode_attention import decode_attention_xla
-from .flash_attention import _NEG_INF, default_platform
+from .decode_attention import (decode_attention_xla, decode_kernel,
+                               decode_scratch)
+from .flash_attention import default_platform
 from .kv_quant import QuantArray, is_quantized
 
 
@@ -77,151 +78,12 @@ def paged_attention_xla(q, k_pool, v_pool, block_tables, lengths):
 # ---------------------------------------------------------------------------
 # Pallas TPU kernel
 # ---------------------------------------------------------------------------
-def _paged_kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
-                  m_s, l_s, acc_s, *, block_size: int, scale: float,
-                  precision):
-    s = pl.program_id(0)
-    bi = pl.program_id(2)
-    num_b = pl.num_programs(2)
-
-    @pl.when(bi == 0)
-    def _init():
-        m_s[:] = jnp.full_like(m_s, _NEG_INF)
-        l_s[:] = jnp.zeros_like(l_s)
-        acc_s[:] = jnp.zeros_like(acc_s)
-
-    # bf16 pools keep bf16 operands (MXU-native, f32 accumulation);
-    # only a true f32 pool runs f32 dots
-    od = jnp.float32 if k_ref.dtype == jnp.float32 else jnp.bfloat16
-    q = q_ref[0].astype(od)                               # [1, D]
-    k_blk = k_ref[0, 0].astype(od)                        # [Bs, D]
-    v_blk = v_ref[0, 0].astype(od)
-    sc = jnp.dot(q, k_blk.T, precision=precision,
-                 preferred_element_type=jnp.float32) * scale   # [1, Bs]
-    # validity from the global key position, computed in-kernel: the
-    # tables already steered the DMA, so the only per-position fact
-    # left is "is j < length" (covers stale tails AND padded entries)
-    key_pos = bi * block_size + lax.broadcasted_iota(
-        jnp.int32, (1, block_size), 1)
-    mask = key_pos < len_ref[s]
-    sc = jnp.where(mask, sc, _NEG_INF)
-    m_prev = m_s[:, 0]
-    l_prev = l_s[:, 0]
-    m_new = jnp.maximum(m_prev, sc.max(axis=1))
-    # where-guard keeps fully-masked rows at p=0 (exp(-inf - -inf) = 1
-    # would fabricate uniform attention for an empty sequence)
-    p = jnp.where(mask, jnp.exp(sc - m_new[:, None]), 0.0)
-    # zero masked V rows too: p=0 there, but 0 * NaN = NaN would leak
-    # a recycled block's non-finite stale tail into the accumulator
-    v_blk = jnp.where(mask.reshape(-1, 1), v_blk, jnp.zeros((), od))
-    corr = jnp.exp(m_prev - m_new)
-    m_s[:, 0] = m_new
-    l_s[:, 0] = l_prev * corr + p.sum(axis=1)
-    acc_s[:] = acc_s[:] * corr[:, None] + jnp.dot(
-        p.astype(od), v_blk, precision=precision,
-        preferred_element_type=jnp.float32)
-
-    @pl.when(bi == num_b - 1)
-    def _finalize():
-        l = jnp.maximum(l_s[:, 0], 1e-30)
-        o_ref[0] = (acc_s[:] / l[:, None]).astype(o_ref.dtype)
-
-
-def _paged_kernel_quant(tbl_ref, len_ref, q_ref, k_ref, v_ref, ks_ref,
-                        vs_ref, o_ref, m_s, l_s, acc_s, *,
-                        block_size: int, scale: float, precision):
-    """int8 variant: the pool refs hold int8 values, ks/vs the
-    per-block-per-head f32 scale rows — riding the SAME
-    scalar-prefetched table index maps, so each grid step's DMA pulls
-    one int8 block plus its [Bs] scale row. Dequant happens here in
-    VMEM (K post-dot, V folded into the probabilities); HBM only ever
-    streams int8 (pallas guide §quantization)."""
-    s = pl.program_id(0)
-    bi = pl.program_id(2)
-    num_b = pl.num_programs(2)
-
-    @pl.when(bi == 0)
-    def _init():
-        m_s[:] = jnp.full_like(m_s, _NEG_INF)
-        l_s[:] = jnp.zeros_like(l_s)
-        acc_s[:] = jnp.zeros_like(acc_s)
-
-    # int8 in [-127, 127] casts to bf16 exactly; dots stay MXU-native
-    q = q_ref[0].astype(jnp.bfloat16)                     # [1, D]
-    k_blk = k_ref[0, 0].astype(jnp.bfloat16)              # [Bs, D]
-    v_blk = v_ref[0, 0].astype(jnp.bfloat16)
-    kscale = ks_ref[0, 0][None, :]                        # [1, Bs]
-    vscale = vs_ref[0, 0][None, :]
-    sc = jnp.dot(q, k_blk.T, precision=precision,
-                 preferred_element_type=jnp.float32) * scale
-    sc = sc * kscale                                      # K dequant
-    key_pos = bi * block_size + lax.broadcasted_iota(
-        jnp.int32, (1, block_size), 1)
-    mask = key_pos < len_ref[s]
-    sc = jnp.where(mask, sc, _NEG_INF)
-    m_prev = m_s[:, 0]
-    l_prev = l_s[:, 0]
-    m_new = jnp.maximum(m_prev, sc.max(axis=1))
-    p = jnp.where(mask, jnp.exp(sc - m_new[:, None]), 0.0)
-    # V dequant folds into p. Where-guard required: a poisoned stale
-    # tail carries NaN in its SCALE (kv_quant.quantize_rows) and
-    # 0 * NaN = NaN; the int8 values themselves are always finite, so
-    # a masked lane contributes exactly 0
-    pv = jnp.where(mask, p * vscale, 0.0)
-    corr = jnp.exp(m_prev - m_new)
-    m_s[:, 0] = m_new
-    l_s[:, 0] = l_prev * corr + p.sum(axis=1)
-    acc_s[:] = acc_s[:] * corr[:, None] + jnp.dot(
-        pv.astype(jnp.bfloat16), v_blk, precision=precision,
-        preferred_element_type=jnp.float32)
-
-    @pl.when(bi == num_b - 1)
-    def _finalize():
-        l = jnp.maximum(l_s[:, 0], 1e-30)
-        o_ref[0] = (acc_s[:] / l[:, None]).astype(o_ref.dtype)
-
-
-def _paged_pallas_quant(q, k_pool, v_pool, block_tables, lengths,
-                        precision, interpret):
-    """Quantized-pool path of :func:`paged_attention_pallas` — same
-    grid and scalar-prefetched table, two extra scale operands whose
-    index maps aim at the SAME pool block as the values."""
-    S, H, D = q.shape
-    N, _, Bs, _ = k_pool.q.shape
-    B = block_tables.shape[1]
-    kernel = functools.partial(_paged_kernel_quant, block_size=Bs,
-                               scale=1.0 / (D ** 0.5),
-                               precision=precision)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,          # block_tables, lengths
-        grid=(S, H, B),
-        in_specs=[
-            pl.BlockSpec((1, 1, D), lambda s, h, bi, tbl, lens:
-                         (s, h, 0)),
-            pl.BlockSpec((1, 1, Bs, D), lambda s, h, bi, tbl, lens:
-                         (tbl[s, bi], h, 0, 0)),
-            pl.BlockSpec((1, 1, Bs, D), lambda s, h, bi, tbl, lens:
-                         (tbl[s, bi], h, 0, 0)),
-            pl.BlockSpec((1, 1, Bs), lambda s, h, bi, tbl, lens:
-                         (tbl[s, bi], h, 0)),
-            pl.BlockSpec((1, 1, Bs), lambda s, h, bi, tbl, lens:
-                         (tbl[s, bi], h, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, D), lambda s, h, bi, tbl, lens:
-                               (s, h, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((1, 1), jnp.float32),   # running max
-            pltpu.VMEM((1, 1), jnp.float32),   # running sum
-            pltpu.VMEM((1, D), jnp.float32),   # output accumulator
-        ],
-    )
-    return pl.pallas_call(
-        kernel, grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((S, H, D), q.dtype),
-        interpret=interpret,
-    )(jnp.asarray(block_tables, jnp.int32),
-      jnp.asarray(lengths, jnp.int32), q, k_pool.q, v_pool.q,
-      k_pool.scale, v_pool.scale)
+def _paged_kernel(tbl_ref, *refs, **kw):
+    # the tables are consumed by the index maps alone: by the time the
+    # body runs they have already steered the DMA, and the only
+    # per-position fact left is "is j < length" (covers stale tails AND
+    # padded table entries), which the shared body computes
+    decode_kernel(*refs, **kw)
 
 
 def paged_attention_pallas(q, k_pool, v_pool, block_tables, lengths,
@@ -231,46 +93,53 @@ def paged_attention_pallas(q, k_pool, v_pool, block_tables, lengths,
     :func:`paged_attention_xla`; grid (S, H, blocks-per-seq) with the
     block tables scalar-prefetched so the K/V index maps aim each grid
     step's DMA at ``pool[tbl[s, bi]]`` directly — no materialized
-    gather. int8 QuantArray pools route to the in-kernel-dequant
-    variant (their scale rows ride the same table index maps)."""
+    gather. The body is the slot kernel's
+    (:func:`~.decode_attention.decode_kernel`) with one pool block as
+    the key tile. int8 QuantArray pools add their per-block-per-head
+    scale rows as two more operands riding the SAME table index maps,
+    so each grid step pulls one int8 block plus its [Bs] scale row and
+    dequantizes in VMEM."""
     if interpret is None:
         interpret = default_platform() != "tpu"
-    if is_quantized(k_pool) or is_quantized(v_pool):
-        if not (is_quantized(k_pool) and is_quantized(v_pool)):
-            raise ValueError("K and V pools must be quantized together")
-        return _paged_pallas_quant(q, k_pool, v_pool, block_tables,
-                                   lengths, precision, interpret)
+    quant = is_quantized(k_pool)
+    if quant != is_quantized(v_pool):
+        raise ValueError("K and V pools must be quantized together")
     S, H, D = q.shape
     N, _, Bs, _ = k_pool.shape
     B = block_tables.shape[1]
-    kernel = functools.partial(_paged_kernel, block_size=Bs,
-                               scale=1.0 / (D ** 0.5),
-                               precision=precision)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,          # block_tables, lengths
-        grid=(S, H, B),
-        in_specs=[
-            pl.BlockSpec((1, 1, D), lambda s, h, bi, tbl, lens:
-                         (s, h, 0)),
-            pl.BlockSpec((1, 1, Bs, D), lambda s, h, bi, tbl, lens:
-                         (tbl[s, bi], h, 0, 0)),
-            pl.BlockSpec((1, 1, Bs, D), lambda s, h, bi, tbl, lens:
-                         (tbl[s, bi], h, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, D), lambda s, h, bi, tbl, lens:
-                               (s, h, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((1, 1), jnp.float32),   # running max
-            pltpu.VMEM((1, 1), jnp.float32),   # running sum
-            pltpu.VMEM((1, D), jnp.float32),   # output accumulator
-        ],
-    )
-    return pl.pallas_call(
-        kernel, grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((S, H, D), q.dtype),
+    # q/out/scales carry a unit second-minor dim: a one-row tile of an
+    # [.., H, D] array is not a block shape the TPU lowering takes
+    # (second-minor must be a multiple of 8 or the whole dim)
+    q_spec = pl.BlockSpec((None, None, 1, D),
+                          lambda s, h, bi, tbl, lens: (s, h, 0, 0))
+    kv_spec = pl.BlockSpec((None, None, Bs, D),
+                           lambda s, h, bi, tbl, lens:
+                           (tbl[s, bi], h, 0, 0))
+    operands, in_specs = [q.reshape(S, H, 1, D)], [q_spec]
+    if quant:
+        sc_spec = pl.BlockSpec((None, None, 1, Bs),
+                               lambda s, h, bi, tbl, lens:
+                               (tbl[s, bi], h, 0, 0))
+        operands += [k_pool.q, v_pool.q,
+                     k_pool.scale.reshape(N, H, 1, Bs),
+                     v_pool.scale.reshape(N, H, 1, Bs)]
+        in_specs += [kv_spec, kv_spec, sc_spec, sc_spec]
+    else:
+        operands += [k_pool, v_pool]
+        in_specs += [kv_spec, kv_spec]
+    out = pl.pallas_call(
+        functools.partial(_paged_kernel, quant=quant, blk_k=Bs,
+                          scale=1.0 / (D ** 0.5), precision=precision),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,          # block_tables, lengths
+            grid=(S, H, B),
+            in_specs=in_specs, out_specs=q_spec,
+            scratch_shapes=decode_scratch(D)),
+        out_shape=jax.ShapeDtypeStruct((S, H, 1, D), q.dtype),
         interpret=interpret,
     )(jnp.asarray(block_tables, jnp.int32),
-      jnp.asarray(lengths, jnp.int32), q, k_pool, v_pool)
+      jnp.asarray(lengths, jnp.int32), *operands)
+    return out.reshape(S, H, D)
 
 
 def paged_attention(q, k_pool, v_pool, block_tables, lengths,

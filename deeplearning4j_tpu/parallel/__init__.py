@@ -43,19 +43,6 @@ from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 
-def shard_map_compat(f, mesh, in_specs, out_specs, **kw):
-    """jax.shard_map across jax versions: new jax exposes it top-level
-    with `check_vma=`; 0.4.x has jax.experimental.shard_map with the
-    same flag named `check_rep=`. Normalize here so call sites can use
-    the modern spelling."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is None:
-        from jax.experimental.shard_map import shard_map as sm
-        if "check_vma" in kw:
-            kw["check_rep"] = kw.pop("check_vma")
-    return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw)
-
-
 def make_mesh(devices: Optional[Sequence] = None, data: Optional[int] = None,
               model: int = 1) -> Mesh:
     """Build a 2D ("data", "model") device mesh. Defaults to all devices on
@@ -564,7 +551,7 @@ class ParallelWrapper:
         # signatures, two compiles of the same program
         rs, ds = NamedSharding(mesh, repl), NamedSharding(mesh, data)
         sharded = jax.jit(
-            shard_map_compat(
+            jax.shard_map(
                 worker_step, mesh=mesh,
                 in_specs=(repl, data, repl, data, repl, repl, data, data,
                           data, repl),
@@ -691,7 +678,7 @@ class ParallelWrapper:
         # the update-mode builder)
         rs, ds = NamedSharding(mesh, repl), NamedSharding(mesh, data)
         sharded = jax.jit(
-            shard_map_compat(
+            jax.shard_map(
                 worker_step, mesh=mesh,
                 in_specs=(repl, repl, repl, data, repl, repl, data, data,
                           data, repl),

@@ -27,7 +27,6 @@ import numpy as np
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from . import shard_map_compat
 from .longseq import ring_attention
 from .pipeline import pipeline_apply
 from .tensor import tp_mlp
@@ -184,7 +183,7 @@ class DistributedTransformer:
         pspec_tree = self.specs
 
         @functools.partial(
-            shard_map_compat, mesh=mesh,
+            jax.shard_map, mesh=mesh,
             in_specs=(pspec_tree, P("dp", "sp"), P("dp", "sp")),
             out_specs=(P(),))
         def loss_sm(params, tokens, targets):

@@ -1,0 +1,154 @@
+"""What a CPU can certify about running on the chip (ISSUE 21).
+
+- Every Pallas kernel variant goes through libtpu's real compiler —
+  Pallas→TPU lowering and Mosaic — for a deviceless ``v5e`` topology, at
+  the served shapes (`chip_smoke.py`) and at the bench's toy shapes. The
+  interpret-mode parity tests cannot see a block shape or a vector
+  layout Mosaic refuses; this can. It is the cheap pre-check before chip
+  time, never a substitute for the run.
+- The compile-cache placement rule.
+- `chip_smoke.py` refuses to run without a TPU.
+"""
+import os
+import subprocess
+import sys
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from deeplearning4j_tpu.kernels.decode_attention import \
+    decode_attention_pallas
+from deeplearning4j_tpu.kernels.flash_attention import flash_attention
+from deeplearning4j_tpu.kernels.kv_quant import QuantArray
+from deeplearning4j_tpu.kernels.paged_attention import \
+    paged_attention_pallas
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+# S slots, H heads, D head_dim, Bs block_size, T cache capacity
+SHAPES = {
+    "served": dict(S=8, H=12, D=64, Bs=16, T=1024),   # GPT-2-small
+    "toy": dict(S=4, H=4, D=16, Bs=8, T=192),         # bench.py's LM
+}
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    """``compile_for(fn, *shape_dtype_structs)`` against one device of a
+    v5e:2x2 topology description — no device needed."""
+    # libtpu would otherwise ask a metadata server that is not there
+    os.environ.setdefault("TPU_SKIP_MDS_QUERY", "1")
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    topo = topologies.get_topology_desc(platform="tpu",
+                                        topology_name="v5e:2x2")
+    assert topo.devices[0].device_kind == "TPU v5 lite"
+    sharding = SingleDeviceSharding(topo.devices[0])
+
+    def compile_for(fn, *args):
+        args = jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=sharding), args)
+        text = jax.jit(fn).lower(*args).compile().as_text()
+        assert "tpu_custom_call" in text, "no Mosaic kernel in the program"
+    return compile_for
+
+
+def _kv(shape, dt):
+    """Abstract K (== V) cache operand of ``shape`` [..., D] at ``dt``."""
+    sds = jax.ShapeDtypeStruct
+    if dt == "int8":
+        return QuantArray(sds(shape, jnp.int8),
+                          sds(shape[:-1], jnp.float32))
+    return sds(shape, {"f32": jnp.float32, "bf16": jnp.bfloat16}[dt])
+
+
+@pytest.mark.parametrize("dt", ["f32", "bf16", "int8"])
+@pytest.mark.parametrize("size", list(SHAPES))
+def test_decode_kernels_compile_for_v5e(v5e, size, dt):
+    S, H, D, Bs, T = (SHAPES[size][k] for k in ("S", "H", "D", "Bs", "T"))
+    B = T // Bs
+    sds = jax.ShapeDtypeStruct
+    q, lens = sds((S, H, D), jnp.float32), sds((S,), jnp.int32)
+    pool = _kv((S * B + 1, H, Bs, D), dt)
+    v5e(lambda q, k, v, t, l: paged_attention_pallas(
+        q, k, v, t, l, interpret=False),
+        q, pool, pool, sds((S, B), jnp.int32), lens)
+    cache = _kv((S, H, T, D), dt)
+    v5e(lambda q, k, v, l: decode_attention_pallas(
+        q, k, v, l, interpret=False), q, cache, cache, lens)
+
+
+@pytest.mark.parametrize("size", list(SHAPES))
+def test_flash_attention_fwd_bwd_compile_for_v5e(v5e, size):
+    H, D, T = (SHAPES[size][k] for k in ("H", "D", "T"))
+    x = jax.ShapeDtypeStruct((2, T, H, D), jnp.float32)
+    km = jax.ShapeDtypeStruct((2, T), jnp.float32)
+
+    def fwd(q, k, v, km):
+        return flash_attention(q, k, v, causal=True, key_mask=km,
+                               interpret=False)
+    v5e(fwd, x, x, x, km)
+    v5e(jax.grad(lambda *a: fwd(*a).sum(), argnums=(0, 1, 2)),
+        x, x, x, km)
+
+
+# -- compile-cache placement --------------------------------------------
+_PLACE = ("import jax; "
+          "from deeplearning4j_tpu.compile_cache import place_compile_cache; "
+          "print(place_compile_cache()); "
+          "print(jax.config.jax_compilation_cache_dir)")
+
+
+def _placed(env_value):
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
+    if env_value is not None:
+        env["JAX_COMPILATION_CACHE_DIR"] = env_value
+    r = subprocess.run([sys.executable, "-c", _PLACE], env=env, cwd=ROOT,
+                       capture_output=True, text=True, timeout=120)
+    assert r.returncode == 0, r.stderr[-2000:]
+    return r.stdout.split()
+
+
+def test_compile_cache_placed_from_outside_is_left_alone(tmp_path):
+    assert _placed(str(tmp_path)) == [str(tmp_path), str(tmp_path)]
+
+
+def test_compile_cache_defaults_to_the_checkout():
+    want = os.path.join(ROOT, ".jax_cache")
+    assert _placed(None) == [want, want]
+
+
+def test_no_other_code_sets_a_cache_directory():
+    hits = []
+    for base, dirs, files in os.walk(ROOT):
+        dirs[:] = [d for d in dirs
+                   if d not in (".git", "chiprun_out", ".jax_cache",
+                                ".checkouts", "__pycache__")]
+        for f in files:
+            path = os.path.join(base, f)
+            if f.endswith((".py", ".sh")) and path != __file__:
+                with open(path, errors="replace") as fh:
+                    if "compilation_cache_dir" in fh.read():
+                        hits.append(os.path.relpath(path, ROOT))
+    assert hits == ["deeplearning4j_tpu/compile_cache.py"], hits
+
+
+# -- chip_smoke.py -------------------------------------------------------
+def test_chip_smoke_refuses_to_run_without_a_tpu():
+    """Importable with no side effects; run without an accelerator it
+    exits non-zero at once, says why on stderr and prints no result."""
+    r = subprocess.run(
+        [sys.executable, "-c",
+         "import sys, chip_smoke; sys.exit('jax' in sys.modules)"],
+        capture_output=True, text=True, timeout=60, cwd=ROOT)
+    assert r.returncode == 0, r.stderr[-2000:]
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    r = subprocess.run([sys.executable, os.path.join(ROOT, "chip_smoke.py")],
+                       capture_output=True, text=True, timeout=120,
+                       env=env, cwd=ROOT)
+    assert r.returncode == 2
+    assert "found no TPU" in r.stderr
+    assert r.stdout == ""

@@ -23,7 +23,6 @@ from deeplearning4j_tpu.parallel.pipeline import (pipeline_apply,
                                                   stack_stage_params)
 from deeplearning4j_tpu.parallel.moe import moe_ffn
 from deeplearning4j_tpu.parallel import compression as comp
-from deeplearning4j_tpu.parallel import shard_map_compat
 from deeplearning4j_tpu.parallel.transformer import (DistributedTransformer,
                                                      make_4d_mesh)
 
@@ -63,7 +62,7 @@ class TestRingAttention:
         q, k, v = _qkv(np_rng, T=32)
         mesh = self._mesh_sp(4)
 
-        @functools.partial(shard_map_compat, mesh=mesh,
+        @functools.partial(jax.shard_map, mesh=mesh,
                            in_specs=(P(None, "sp"),) * 3,
                            out_specs=P(None, "sp"))
         def f(q, k, v):
@@ -79,7 +78,7 @@ class TestRingAttention:
         q, k, v = _qkv(np_rng, B=1, T=16, H=2, D=4)
         mesh = self._mesh_sp(4)
 
-        @functools.partial(shard_map_compat, mesh=mesh,
+        @functools.partial(jax.shard_map, mesh=mesh,
                            in_specs=(P(None, "sp"),) * 3,
                            out_specs=P())
         def loss_ring(q, k, v):
@@ -110,7 +109,7 @@ class TestPipeline:
         def stage(p, a):
             return jnp.tanh(a @ p["w"])
 
-        @functools.partial(shard_map_compat, mesh=mesh,
+        @functools.partial(jax.shard_map, mesh=mesh,
                            in_specs=({"w": P("pp", None, None)}, P()),
                            out_specs=P())
         def run(params, x):
@@ -134,7 +133,7 @@ class TestPipeline:
         def stage(p, a):
             return jnp.tanh(a @ p["w"])
 
-        @functools.partial(shard_map_compat, mesh=mesh,
+        @functools.partial(jax.shard_map, mesh=mesh,
                            in_specs=({"w": P("pp", None, None)}, P()),
                            out_specs=P())
         def loss_sm(params, x):
@@ -168,7 +167,7 @@ class TestMoE:
         x = jnp.asarray(np_rng.randn(S * N_local, d).astype(np.float32))
 
         @functools.partial(
-            shard_map_compat, mesh=mesh,
+            jax.shard_map, mesh=mesh,
             in_specs=(P("ep", None), P(), P("ep", None, None), P("ep", None),
                       P("ep", None, None), P("ep", None)),
             out_specs=(P("ep", None), P()))
@@ -198,7 +197,7 @@ class TestMoE:
         x = jnp.asarray(np_rng.randn(S * N_local, d).astype(np.float32))
 
         @functools.partial(
-            shard_map_compat, mesh=mesh,
+            jax.shard_map, mesh=mesh,
             in_specs=(P("ep", None), P(), P("ep", None, None), P("ep", None),
                       P("ep", None, None), P("ep", None)),
             out_specs=(P("ep", None), P()))
