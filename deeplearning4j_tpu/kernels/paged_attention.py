@@ -42,6 +42,10 @@ from .decode_attention import (decode_attention_xla, decode_kernel,
 from .flash_attention import default_platform
 from .kv_quant import QuantArray, is_quantized
 
+#: the Pallas kernel's name: its custom call in the HLO, and the
+#: operation a device trace shows inside ``jit_step``
+KERNEL_NAME = "paged_attention_decode"
+
 
 def gather_blocks(pool, block_tables):
     """[N, H, Bs, D] pool + [S, B] tables -> [S, H, B*Bs, D] dense
@@ -137,6 +141,9 @@ def paged_attention_pallas(q, k_pool, v_pool, block_tables, lengths,
             scratch_shapes=decode_scratch(D)),
         out_shape=jax.ShapeDtypeStruct((S, H, 1, D), q.dtype),
         interpret=interpret,
+        # the custom call's instruction name in the HLO and so in a
+        # device trace (else it is named after the enclosing jit)
+        name=KERNEL_NAME,
     )(jnp.asarray(block_tables, jnp.int32),
       jnp.asarray(lengths, jnp.int32), *operands)
     return out.reshape(S, H, D)

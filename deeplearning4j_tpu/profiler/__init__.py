@@ -81,8 +81,11 @@ class OpProfiler:
 
     @contextlib.contextmanager
     def record(self, name: str):
-        """Time a named section (ref: processOpCall timing path). Blocks
-        on device completion so the timing is honest."""
+        """Time a named section on the host's clock (ref: processOpCall
+        timing path). It waits for nothing: a section that only
+        enqueues device work is timed as the enqueue, so a site that
+        means the device's time has to fetch a result inside the
+        section."""
         t0 = time.perf_counter()
         try:
             yield

@@ -132,7 +132,7 @@ from .fleet import (FleetError, FleetMetrics, FleetRouter,
                     NoReplicasError, Replica, ReplicaFleet)
 from .generation import GenerationEngine
 from .kvcache import KVCache, SlotTable
-from .metrics import (GenerationMetrics, ServingMetrics,
+from .metrics import (HTTP_WRITE_SPAN, GenerationMetrics, ServingMetrics,
                       profiler_sections, prometheus_text)
 from .offload import DiskRing, HostBlockStore, HostRun
 from .paging import BlockAllocator, BlockTable, PagedKVCache
@@ -532,13 +532,17 @@ class InferenceServer:
 
                 def chunk(obj):
                     data = (json.dumps(obj) + "\n").encode()
-                    self.wfile.write(f"{len(data):X}\r\n".encode()
-                                     + data + b"\r\n")
-                    self.wfile.flush()
+                    with jax.profiler.TraceAnnotation(HTTP_WRITE_SPAN):
+                        self.wfile.write(f"{len(data):X}\r\n".encode()
+                                         + data + b"\r\n")
+                        self.wfile.flush()
+                wrote = getattr(it, "wrote", None)
                 try:
                     try:
                         for item in it:
                             chunk(item)
+                            if wrote is not None:
+                                wrote()  # emit stamp -> on the socket
                     except OSError:
                         # client went away mid-stream: routine, not a
                         # server error — close the iterator NOW (its

@@ -44,8 +44,11 @@ import time
 from typing import Dict, List, Optional, Tuple
 from urllib.parse import parse_qs
 
+from jax.profiler import TraceAnnotation
+
 from ..tracing import new_request_id
 from .batcher import DeadlineExceededError
+from .metrics import HTTP_WRITE_SPAN
 
 _REASONS = {
     200: "OK", 400: "Bad Request", 404: "Not Found",
@@ -636,6 +639,7 @@ class AioReplicaFrontend(_AioFrontend):
         bench's ``connscale`` leg."""
         server = self._srv
         req = getattr(it, "_req", None)
+        wrote = getattr(it, "wrote", None)
         if req is None or getattr(req, "stream_q", None) is None:
             def pull():
                 try:
@@ -663,7 +667,8 @@ class AioReplicaFrontend(_AioFrontend):
                     # can never sleep through an item already queued
                     evt.clear()
                     try:
-                        kind, payload = req.stream_q.get_nowait()
+                        kind, payload, it._t_emit = \
+                            req.stream_q.get_nowait()
                         break
                     except _queue.Empty:
                         budget = req.deadline - time.perf_counter() + 1.0
@@ -707,7 +712,11 @@ class AioReplicaFrontend(_AioFrontend):
                     item = await anext_item()
                     if item is _END:
                         break
-                    await resp.chunk((json.dumps(item) + "\n").encode())
+                    with TraceAnnotation(HTTP_WRITE_SPAN):
+                        await resp.chunk(
+                            (json.dumps(item) + "\n").encode())
+                    if wrote is not None:
+                        wrote()     # emit stamp -> handed to the socket
             except _SOCK_EXC:
                 # client went away mid-stream: close the iterator NOW
                 # (abandons the request, freeing its cache slot)

@@ -210,16 +210,18 @@ class _GenRequest:
         self.spec_dt1: Optional[float] = None
         self.spec_vt0: Optional[float] = None
         self.spec_vt1: Optional[float] = None
-        # engine-cumulative pipeline counters snapshotted at decode
-        # entry; the terminal span reports the deltas over this
-        # request's decode lifetime (engine-wide, not per-lane — the
-        # sync is shared by the whole batch). None = never decoded on
-        # a pipelining engine
+        # the scheduler account's (busy, blocked) seconds snapshotted
+        # at decode entry; the terminal span reports the deltas over
+        # this request's decode lifetime (engine-wide, not per-lane —
+        # the sync is shared by the whole batch). None = never decoded
         self.pipe_d0: Optional[float] = None
         self.pipe_w0: Optional[float] = None
 
-    def _stream_push(self, item) -> None:
-        self.stream_q.put(item)
+    def _stream_push(self, kind: str, payload, t_emit=None) -> None:
+        """Queue one stream item. ``t_emit`` is the scheduler's emit
+        stamp of a token: the front-end measures from it to the
+        socket write (``stream.delay_s`` in ``/stats``)."""
+        self.stream_q.put((kind, payload, t_emit))
         cb = self.stream_notify
         if cb is not None:
             try:
@@ -256,6 +258,16 @@ class _TokenStream:
         self._req = req
         self._i = 0
         self._done = False
+        self._t_emit: Optional[float] = None  # of the item last returned
+
+    def wrote(self) -> None:
+        """The front-end has handed the item last returned to the
+        socket: account the time since the scheduler emitted it."""
+        t = self._t_emit
+        if t is not None:
+            self._t_emit = None
+            self._engine.metrics.note_stream_write(
+                time.perf_counter() - t)
 
     def __iter__(self) -> "Iterator[Dict]":
         return self
@@ -266,7 +278,8 @@ class _TokenStream:
         req = self._req
         budget = req.deadline - time.perf_counter() + 1.0
         try:
-            kind, payload = req.stream_q.get(timeout=max(budget, 0.001))
+            kind, payload, self._t_emit = req.stream_q.get(
+                timeout=max(budget, 0.001))
         except queue.Empty:
             self._done = True
             req.abandoned = True
@@ -664,12 +677,9 @@ class GenerationEngine:
         # across calls without the defensive .copy())
         self._all_host = np.ones(self.num_slots, bool)
         self._no_dev_tok = np.zeros(self.num_slots, np.int32)
-        # engine-cumulative pipeline accounting (seconds): the span
-        # from dispatch to results-on-host, and how long the host
-        # actually BLOCKED at the sync — terminal request spans and
-        # tools/trace_report.py's phase table read the deltas
-        self._step_span_s = 0.0
-        self._sync_wait_s = 0.0
+        # the scheduler's time account (metrics.SchedulerAccount):
+        # phase counters in /stats and gen.* spans in a profiler trace
+        self._sched = self.metrics.scheduler
         self._queue: "queue.Queue[_GenRequest]" = queue.Queue(
             maxsize=int(max_queue))
         # submit-wake: an idle scheduler parks on this event instead
@@ -683,7 +693,13 @@ class GenerationEngine:
             1, int(self.batch_queue_fraction * int(max_queue)))
         # cost-aware admission: measured EWMAs (per PROMPT TOKEN of
         # prefill, per STEP of decode) — 0.0 until the first call
-        # lands, so a cold engine admits everything
+        # lands, so a cold engine admits everything. What the decode
+        # EWMA is fed is a step's DISPATCH-TO-RESULTS span (the
+        # decode_step_ms samples), not the time a step adds: with the
+        # pipeline on, a span covers the wait behind the step before
+        # it and any chunk between, so it reads up to twice the
+        # loop's cycle (scheduler.loop_s over decode steps in /stats
+        # is the cycle). Admission reads it as a worst case.
         self._prefill_ms_per_tok = 0.0
         self._decode_ewma_ms = 0.0
         # -- fault tolerance (serving/faults.py) --------------------
@@ -1693,22 +1709,24 @@ class GenerationEngine:
         else:
             tr.span("error" if exc is not None else "decode",
                     **attrs).end()
-        if req.pipe_d0 is not None and self._step_span_s > req.pipe_d0:
-            # pipelined-decode accounting over this request's decode
-            # lifetime, rebuilt retroactively from engine-cumulative
-            # counters snapshotted at admission (the hot loop stores
-            # two floats per request, nothing else). ENGINE-wide, not
-            # per-lane: every lane in the batch shares one dispatch
-            # and one sync. device_ms is the dispatch->results span;
-            # sync_wait_ms is how long the scheduler actually blocked
-            # — their gap is host work that overlapped device compute.
-            dev_s = self._step_span_s - req.pipe_d0
-            wait_s = self._sync_wait_s - req.pipe_w0
-            tr.span("step_pipeline",
-                    device_ms=round(dev_s * 1e3, 3),
-                    sync_wait_ms=round(wait_s * 1e3, 3),
-                    overlap_frac=round(
-                        max(0.0, 1.0 - wait_s / dev_s), 4)).end()
+        if req.pipe_d0 is not None:
+            # the scheduler's time account over this request's decode
+            # lifetime (whole iterations), from the two numbers
+            # snapshotted at decode entry. ENGINE-wide, not per-lane:
+            # every lane in the batch shares one dispatch and one
+            # sync. wall_ms is the loop's non-idle wall; sync_wait_ms
+            # is the part of it spent blocked in a step's or a
+            # chunk's fetch — their gap is host work, the only part
+            # that dispatching ahead of the sync can hide.
+            busy_s, blocked_s = self._sched.busy_and_blocked()
+            wall_s = busy_s - req.pipe_d0
+            wait_s = blocked_s - req.pipe_w0
+            if wall_s > 0:
+                tr.span("step_pipeline",
+                        wall_ms=round(wall_s * 1e3, 3),
+                        sync_wait_ms=round(wait_s * 1e3, 3),
+                        overlap_frac=round(
+                            max(0.0, 1.0 - wait_s / wall_s), 4)).end()
         if req.spec_rounds:
             # speculative participation, rebuilt retroactively from the
             # per-request aggregates (the hot loop never touches the
@@ -1741,7 +1759,7 @@ class GenerationEngine:
             self.metrics.inc("server_errors")
         self._trace_terminal(req, exc=exc)
         if req.stream_q is not None:
-            req._stream_push(("error", exc))
+            req._stream_push("error", exc)
         req.event.set()
 
     def _emit(self, req: _GenRequest, token: int, now: float,
@@ -1760,7 +1778,7 @@ class GenerationEngine:
             self.metrics.itl_ms.record((now - req.t_last) * 1e3)
         req.t_last = now
         if req.stream_q is not None:
-            req._stream_push(("token", token))
+            req._stream_push("token", token, now)
             fi = self._faults
             if fi is not None and fi.fire("client_disconnect"):
                 # simulate the HTTP consumer hanging up mid-stream:
@@ -1798,7 +1816,7 @@ class GenerationEngine:
             self._release_slot(slot)
         self._trace_terminal(req, reason=reason)
         if req.stream_q is not None:
-            req._stream_push(("done", reason))
+            req._stream_push("done", reason)
         req.event.set()
 
     def _check_done(self, slot: int, req: _GenRequest, token: int,
@@ -1875,8 +1893,9 @@ class GenerationEngine:
         try:
             return self._queue.get_nowait()
         except queue.Empty:
-            self._wake.wait(
-                max(0.05, min(1.0, self._stall_timeout_s / 4.0)))
+            with self._sched.phase("idle"):
+                self._wake.wait(
+                    max(0.05, min(1.0, self._stall_timeout_s / 4.0)))
             return None
 
     def _admit(self):
@@ -1893,8 +1912,28 @@ class GenerationEngine:
         (re-stashing the request unless it was already failed — the
         attributed-device-failure path fails it inside
         :meth:`_prefill`); anything else fails just this request."""
-        if self.cache_backend == "paged":
-            return self._admit_paged()
+        with self._sched.phase("admit", step=self.metrics.decode_steps,
+                               slots=self._slots.active_count):
+            try:
+                if self.cache_backend == "paged":
+                    self._admit_paged()
+                else:
+                    self._admit_slots()
+            finally:
+                self._sched.head_blocked(self._head_blocked_cause())
+
+    def _head_blocked_cause(self) -> Optional[str]:
+        """Why the request at the head of the queue is still there
+        after an admission pass: "slots" (none free), "blocks" (a slot
+        is free, so only a failed block allocation holds a request),
+        or None (nothing waits)."""
+        held = self.cache_backend == "paged" and self._held is not None
+        if self._slots.free_count:
+            return "blocks" if held else None
+        waiting = held or self._requeue or self._queue.qsize()
+        return "slots" if waiting else None
+
+    def _admit_slots(self):
         while self._running and self._slots.free_count:
             if self._requeue:
                 req = self._requeue.popleft()
@@ -2196,55 +2235,76 @@ class GenerationEngine:
         compute regardless of prompt length."""
         st = self._prefilling[0]
         req = st.req
-        if req.abandoned:
-            self._prefilling.popleft()
-            self._release_slot(st.slot)
-            return
-        if time.perf_counter() > req.deadline:
-            self._prefilling.popleft()
-            self._release_slot(st.slot)
-            self._fail(req, DeadlineExceededError(
-                "deadline exceeded during chunked prefill "
-                f"({st.done_tokens}/{len(st.seq)} prompt tokens)"))
-            return
-        # injection seam: BEFORE any mutation — a TransientFault here
-        # leaves the chunk state at the deque head, so the retried
-        # iteration re-runs this same chunk
-        self._hit("prefill")
-        p0, bucket, clen = st.plan[st.idx]
-        tokens = np.zeros((1, bucket), np.int32)
-        tokens[0, :clen] = st.seq[p0:p0 + clen]
-        table = st.table.padded(st.tbl_bucket)
-        c0 = self.metrics.compiles
-        t0 = time.perf_counter()
-        try:
-            exe = self._get_chunk_exe(bucket, st.tbl_bucket)
-        except Exception as e:  # noqa: BLE001 — compile failed BEFORE
-            # any donation: only this request is affected
-            self._prefilling.popleft()
-            self._release_slot(st.slot)
-            self._fail(req, e)
-            return
-        try:
-            with self._profiler.record("generation.prefill"):
+        sched = self._sched
+        n_chunk = self.metrics.prefill_chunks   # this chunk's ordinal
+        with sched.phase("chunk_dispatch", chunk=n_chunk,
+                         slots=self._slots.active_count) as t0:
+            if req.abandoned:
+                self._prefilling.popleft()
+                self._release_slot(st.slot)
+                return
+            if t0 > req.deadline:
+                self._prefilling.popleft()
+                self._release_slot(st.slot)
+                self._fail(req, DeadlineExceededError(
+                    "deadline exceeded during chunked prefill "
+                    f"({st.done_tokens}/{len(st.seq)} prompt tokens)"))
+                return
+            # injection seam: BEFORE any mutation — a TransientFault
+            # here leaves the chunk state at the deque head, so the
+            # retried iteration re-runs this same chunk
+            self._hit("prefill")
+            p0, bucket, clen = st.plan[st.idx]
+            tokens = np.zeros((1, bucket), np.int32)
+            tokens[0, :clen] = st.seq[p0:p0 + clen]
+            table = st.table.padded(st.tbl_bucket)
+            c0 = self.metrics.compiles
+            try:
+                exe = self._get_chunk_exe(bucket, st.tbl_bucket)
+            except Exception as e:  # noqa: BLE001 — compile failed
+                # BEFORE any donation: only this request is affected
+                self._prefilling.popleft()
+                self._release_slot(st.slot)
+                self._fail(req, e)
+                return
+            try:
                 first, okd, self._kcs, self._vcs = exe(
                     self.model._params, self._kcs, self._vcs, tokens,
                     np.int32(p0), np.int32(clen), table,
                     np.uint32(req.seed), np.float32(req.temperature),
                     np.int32(req.top_k))
+            except Exception as e:  # noqa: BLE001
+                self._chunk_call_failed(st, e)
+        with sched.phase("chunk_wait", chunk=n_chunk):
+            try:
                 first = int(np.asarray(first))  # device sync
                 ok = bool(np.asarray(okd))
-        except Exception as e:  # noqa: BLE001 — the call died with the
-            # pools donated: attribute the failure to THIS request
-            # (fail it alone), then let the loop recompute-recover
-            # every other in-flight sequence's lost prefix
-            self._prefilling.popleft()
-            self._release_slot(st.slot)
-            self._fail(req, e)
-            raise CorruptedStateFault(
-                f"prefill chunk device call failed: {e!r}")
-        t1 = time.perf_counter()
+            except Exception as e:  # noqa: BLE001
+                self._chunk_call_failed(st, e)
+        t1 = sched.t        # the stamp that closed chunk_wait
+        with sched.phase("emit", chunk=n_chunk):
+            self._chunk_landed(st, first, ok, bucket, clen, c0, t0, t1)
+
+    def _chunk_call_failed(self, st: _ChunkState, e: BaseException):
+        """A chunk's device call (or its fetch) died with the pools
+        donated: attribute the failure to THIS request (fail it
+        alone), then let the loop recompute-recover every other
+        in-flight sequence's lost prefix."""
+        self._prefilling.popleft()
+        self._release_slot(st.slot)
+        self._fail(st.req, e)
+        raise CorruptedStateFault(
+            f"prefill chunk device call failed: {e!r}")
+
+    def _chunk_landed(self, st: _ChunkState, first: int, ok: bool,
+                      bucket: int, clen: int, c0: int, t0: float,
+                      t1: float):
+        """Host bookkeeping once a chunk's results are on the host;
+        after a prompt's final chunk the request becomes a decode
+        lane and its first token is emitted."""
+        req = st.req
         dt_ms = (t1 - t0) * 1e3
+        self._profiler.note("generation.prefill", t1 - t0)
         self.metrics.prefill_ms.record(dt_ms)
         if self.metrics.compiles == c0:
             # a sample that paid a lazy compile would poison the
@@ -2295,8 +2355,7 @@ class GenerationEngine:
         # next dispatch must feed it from tok_host, not the device
         self._tok_on_dev[st.slot] = False
         if req.pipe_d0 is None:
-            req.pipe_d0 = self._step_span_s
-            req.pipe_w0 = self._sync_wait_s
+            req.pipe_d0, req.pipe_w0 = self._sched.busy_and_blocked()
         self._tables[st.slot] = st.table.padded(self._blocks_per_seq)
         if self.enable_prefix_sharing and not resumed:
             # the prompt's full blocks now hold finished, immutable
@@ -2502,31 +2561,35 @@ class GenerationEngine:
         tokens = np.zeros((1, bucket), np.int32)
         tokens[0, :L] = seq
         c0 = self.metrics.compiles
-        t0 = time.perf_counter()
-        try:
-            exe = self._get_prefill_exe(bucket)
-        except Exception:
-            # compile failed BEFORE any donation: only this request is
-            # affected — free its slot and let the caller fail it
-            self._release_slot(slot)
-            raise
-        try:
-            with self._profiler.record("generation.prefill"):
+        sched = self._sched
+        n_chunk = self.metrics.prefills     # this prefill's ordinal
+        # a whole-prompt prefill is this backend's one chunk; the
+        # phases pause the admit phase they run inside
+        with sched.phase("chunk_dispatch", chunk=n_chunk,
+                         slots=self._slots.active_count) as t0:
+            try:
+                exe = self._get_prefill_exe(bucket)
+            except Exception:
+                # compile failed BEFORE any donation: only this
+                # request is affected — free its slot and let the
+                # caller fail it
+                self._release_slot(slot)
+                raise
+            try:
                 first, okd, self._kcs, self._vcs = exe(
                     self.model._params, self._kcs, self._vcs, tokens,
                     np.int32(L), np.int32(slot), np.uint32(req.seed),
                     np.float32(req.temperature), np.int32(req.top_k))
+            except Exception as e:  # noqa: BLE001
+                self._prefill_call_failed(slot, req, e)
+        with sched.phase("chunk_wait", chunk=n_chunk):
+            try:
                 first = int(np.asarray(first))  # device sync
                 ok = bool(np.asarray(okd))
-        except Exception as e:
-            # the call itself died mid-flight with the caches donated:
-            # attribute the failure to THIS request (fail it alone),
-            # then raise for recompute-recovery of everyone else
-            self._release_slot(slot)
-            self._fail(req, e)
-            raise CorruptedStateFault(
-                f"prefill device call failed: {e!r}")
-        t1 = time.perf_counter()
+            except Exception as e:  # noqa: BLE001
+                self._prefill_call_failed(slot, req, e)
+        t1 = sched.t        # the stamp that closed chunk_wait
+        self._profiler.note("generation.prefill", t1 - t0)
         dt_ms = (t1 - t0) * 1e3
         self.metrics.prefill_ms.record(dt_ms)
         if self.metrics.compiles == c0:
@@ -2562,8 +2625,7 @@ class GenerationEngine:
         # next dispatch must feed it from tok_host, not the device
         self._tok_on_dev[slot] = False
         if req.pipe_d0 is None:
-            req.pipe_d0 = self._step_span_s
-            req.pipe_w0 = self._sync_wait_s
+            req.pipe_d0, req.pipe_w0 = self._sched.busy_and_blocked()
         if self.speculation_k:
             self._spec_prime(slot, seq)
         self.metrics.active_slots = st.active_count
@@ -2576,6 +2638,15 @@ class GenerationEngine:
         self.metrics.tokens.record(1)
         self._emit(req, first, time.perf_counter())
         self._check_done(slot, req, first)
+
+    def _prefill_call_failed(self, slot: int, req: _GenRequest,
+                             e: BaseException):
+        """The prefill call (or its fetch) died mid-flight with the
+        caches donated: attribute the failure to THIS request (fail it
+        alone), then raise for recompute-recovery of everyone else."""
+        self._release_slot(slot)
+        self._fail(req, e)
+        raise CorruptedStateFault(f"prefill device call failed: {e!r}")
 
     def _note_prefill_cost(self, dt_ms: float, bucket: int):
         """Feed the per-PROMPT-TOKEN prefill EWMA (scheduler thread
@@ -2675,7 +2746,10 @@ class GenerationEngine:
             # draft-side fault — injected or real, transient or
             # corrupting — costs speculation only, never a recovery
             self._hit("draft")
-            with self._profiler.record("generation.spec_draft"):
+            # a synchronous call, dispatch and fetch in one: the
+            # scheduler is blocked on the device for all of it
+            with self._profiler.record("generation.spec_draft"), \
+                    self._sched.phase("decode_wait", spec="draft"):
                 props, dok, self._draft_kcs, self._draft_vcs = \
                     self._get_draft_exe()(
                         self._draft._params, self._draft_kcs,
@@ -2726,7 +2800,8 @@ class GenerationEngine:
             self._hit("verify")
             v0 = time.perf_counter()
             try:
-                with self._profiler.record("generation.spec_verify"):
+                with self._profiler.record("generation.spec_verify"), \
+                        self._sched.phase("decode_wait", spec="verify"):
                     tgt, n_acc, vok, self._kcs, self._vcs = \
                         self._get_verify_exe(tv if paged else None)(
                             self.model._params, self._kcs, self._vcs,
@@ -2828,8 +2903,10 @@ class GenerationEngine:
         # a TransientFault here is retryable with all state intact
         self._hit("device_step")
         c0 = self.metrics.compiles
-        t0 = time.perf_counter()
-        with self._profiler.record("generation.decode_step"):
+        sched = self._sched
+        n_step = self.metrics.decode_steps      # this step's ordinal
+        with sched.phase("decode_dispatch", step=n_step,
+                         slots=len(active)) as t0:
             if self.cache_backend == "paged":
                 nxt, okd, dnd, self._kcs, self._vcs = \
                     self._get_decode_exe()(
@@ -2849,10 +2926,20 @@ class GenerationEngine:
                         st.step.copy(), st.temp.copy(),
                         st.top_k.copy(), st.eos.copy(),
                         st.max_steps.copy())
+        with sched.phase("decode_wait", step=n_step):
             nxt = np.asarray(nxt)  # device sync: the step really ran
             ok = np.asarray(okd)
             done = np.asarray(dnd)
-        now = time.perf_counter()
+        now = sched.t           # the stamp that closed decode_wait
+        self._profiler.note("generation.decode_step", now - t0)
+        with sched.phase("emit", step=n_step, slots=len(active)):
+            self._apply_decode_step(active, nxt, ok, done, now, t0, c0)
+
+    def _apply_decode_step(self, active, nxt, ok, done, now: float,
+                           t0: float, c0: int):
+        """Host side of one synchronous decode step, its results on
+        the host: samples, token fan-out, retirement, gauges."""
+        st = self._slots
         dt_ms = (now - t0) * 1e3
         self.metrics.decode_step_ms.record(dt_ms)
         # feed the cost-aware-admission EWMA (scheduler thread only) —
@@ -2898,6 +2985,7 @@ class GenerationEngine:
             self.metrics.itl_ms.record_many(itl)
         if self.cache_backend == "paged":
             self._update_block_gauges()
+            self._sched.step_collected(self.metrics.kv_tokens_live)
 
     def _dispatch_decode(self) -> bool:
         """Launch one decode step WITHOUT waiting for its results (the
@@ -2917,36 +3005,42 @@ class GenerationEngine:
         # the not-yet-collected previous step stays queued
         self._hit("device_step")
         c0 = self.metrics.compiles
-        tok_dev = self._nxt_dev
-        if tok_dev is None:
-            tok_dev = self._no_dev_tok
-        use_host = ~self._tok_on_dev
-        t0 = time.perf_counter()
-        if self.cache_backend == "paged":
-            nxt, okd, dnd, self._kcs, self._vcs = self._get_decode_exe()(
-                self.model._params, self._kcs, self._vcs,
-                st.token.copy(), tok_dev, use_host, st.pos.copy(),
-                self._tables.copy(), st.seed.copy(), st.step.copy(),
-                st.temp.copy(), st.top_k.copy(), st.eos.copy(),
-                st.max_steps.copy())
-        else:
-            nxt, okd, dnd, self._kcs, self._vcs = self._get_decode_exe()(
-                self.model._params, self._kcs, self._vcs,
-                st.token.copy(), tok_dev, use_host, st.pos.copy(),
-                st.seed.copy(), st.step.copy(), st.temp.copy(),
-                st.top_k.copy(), st.eos.copy(), st.max_steps.copy())
-        self._nxt_dev = nxt
-        self._tok_on_dev[:] = False
-        self._tok_on_dev[active] = True
-        # batched cursor bookkeeping: two vectorized adds, no per-lane
-        # Python in the dispatch path
-        st.pos[active] += 1
-        st.step[active] += 1
-        self.metrics.inc("decode_steps")
-        self.metrics.occupancy_hist.record(len(active))
-        self._pending.append(
-            (nxt, okd, dnd, [(s, st.requests[s]) for s in active],
-             t0, c0))
+        n_step = self.metrics.decode_steps      # this step's ordinal
+        with self._sched.phase("decode_dispatch", step=n_step,
+                               slots=len(active)) as t0:
+            tok_dev = self._nxt_dev
+            if tok_dev is None:
+                tok_dev = self._no_dev_tok
+            use_host = ~self._tok_on_dev
+            if self.cache_backend == "paged":
+                nxt, okd, dnd, self._kcs, self._vcs = \
+                    self._get_decode_exe()(
+                        self.model._params, self._kcs, self._vcs,
+                        st.token.copy(), tok_dev, use_host,
+                        st.pos.copy(), self._tables.copy(),
+                        st.seed.copy(), st.step.copy(), st.temp.copy(),
+                        st.top_k.copy(), st.eos.copy(),
+                        st.max_steps.copy())
+            else:
+                nxt, okd, dnd, self._kcs, self._vcs = \
+                    self._get_decode_exe()(
+                        self.model._params, self._kcs, self._vcs,
+                        st.token.copy(), tok_dev, use_host,
+                        st.pos.copy(), st.seed.copy(), st.step.copy(),
+                        st.temp.copy(), st.top_k.copy(), st.eos.copy(),
+                        st.max_steps.copy())
+            self._nxt_dev = nxt
+            self._tok_on_dev[:] = False
+            self._tok_on_dev[active] = True
+            # batched cursor bookkeeping: two vectorized adds, no
+            # per-lane Python in the dispatch path
+            st.pos[active] += 1
+            st.step[active] += 1
+            self.metrics.inc("decode_steps")
+            self.metrics.occupancy_hist.record(len(active))
+            self._pending.append(
+                (nxt, okd, dnd, [(s, st.requests[s]) for s in active],
+                 t0, c0, n_step))
         return True
 
     def _collect_decode(self, keep: int = 0):
@@ -2955,64 +3049,73 @@ class GenerationEngine:
         step stays in flight while THIS host work overlaps it — that
         overlap is the entire point of the pipeline). The sync is the
         only blocking point; everything after runs off host arrays."""
-        st = self._slots
+        sched = self._sched
         while len(self._pending) > keep:
-            nxt_d, okd, dnd, lanes, t0, c0 = self._pending.popleft()
-            t_wait = time.perf_counter()
-            nxt = np.asarray(nxt_d)  # device sync: the step really ran
-            ok = np.asarray(okd)
-            done = np.asarray(dnd)
-            now = time.perf_counter()
-            span_s = now - t0         # dispatch -> results on host
-            wait_s = now - t_wait     # how long the host BLOCKED
-            self._profiler.note("generation.decode_step", span_s)
-            self._step_span_s += span_s
-            self._sync_wait_s += wait_s
-            dt_ms = span_s * 1e3
-            self.metrics.decode_step_ms.record(dt_ms)
-            self.metrics.decode_sync_wait_ms.record(wait_s * 1e3)
-            if self.metrics.compiles == c0:
-                self._decode_ewma_ms = dt_ms \
-                    if not self._decode_ewma_ms \
-                    else 0.8 * self._decode_ewma_ms + 0.2 * dt_ms
-            tokens = nxt.tolist()
-            flags = done.tolist()
-            emitted = 0
-            itl: List[float] = []
-            for slot, req in lanes:
-                if st.requests[slot] is not req \
-                        or req.finish_reason is not None \
-                        or req.error is not None:
-                    # the lane retired (or its slot changed hands)
-                    # while this step was in flight: its junk write
-                    # landed past the retired sequence's valid length
-                    # — masked and later overwritten, per the
-                    # no-zeroing invariant — and its sampled token is
-                    # simply never read
-                    continue
-                if not ok[slot]:
-                    # poison quarantine, same contract as the
-                    # synchronous path
-                    self.metrics.inc("quarantined")
-                    exc = PoisonRequestError(
-                        "request produced non-finite logits at decode "
-                        f"step {int(st.step[slot])}; quarantined")
-                    self._release_slot(slot)
-                    self._fail(req, exc)
-                    continue
-                token = tokens[slot]
-                # backfill the host mirror; the NEXT step's input (if
-                # already dispatched) came from tok_dev, not this
-                st.token[slot] = token
-                self._emit(req, token, now, itl_out=itl)
-                emitted += 1
-                self._retire(slot, req, token, flags[slot], now)
-            if emitted:
-                self.metrics.tokens.record(emitted)
-            if itl:
-                self.metrics.itl_ms.record_many(itl)
-            if self.cache_backend == "paged":
-                self._update_block_gauges()
+            nxt_d, okd, dnd, lanes, t0, c0, n_step = \
+                self._pending.popleft()
+            with sched.phase("decode_wait", step=n_step) as t_wait:
+                nxt = np.asarray(nxt_d)  # device sync: the step ran
+                ok = np.asarray(okd)
+                done = np.asarray(dnd)
+            now = sched.t       # the stamp that closed decode_wait
+            with sched.phase("emit", step=n_step, slots=len(lanes)):
+                self._apply_collected(lanes, nxt, ok, done, now,
+                                      now - t0, now - t_wait, c0)
+
+    def _apply_collected(self, lanes, nxt, ok, done, now: float,
+                         span_s: float, wait_s: float, c0: int):
+        """Host side of one collected step: ``span_s`` from its
+        dispatch to its results on the host (it overlaps the spans of
+        its neighbours once the pipeline is on: not a step's time),
+        ``wait_s`` of it blocked in the fetch."""
+        st = self._slots
+        self._profiler.note("generation.decode_step", span_s)
+        dt_ms = span_s * 1e3
+        self.metrics.decode_step_ms.record(dt_ms)
+        self.metrics.decode_sync_wait_ms.record(wait_s * 1e3)
+        if self.metrics.compiles == c0:
+            self._decode_ewma_ms = dt_ms \
+                if not self._decode_ewma_ms \
+                else 0.8 * self._decode_ewma_ms + 0.2 * dt_ms
+        tokens = nxt.tolist()
+        flags = done.tolist()
+        emitted = 0
+        itl: List[float] = []
+        for slot, req in lanes:
+            if st.requests[slot] is not req \
+                    or req.finish_reason is not None \
+                    or req.error is not None:
+                # the lane retired (or its slot changed hands)
+                # while this step was in flight: its junk write
+                # landed past the retired sequence's valid length
+                # — masked and later overwritten, per the
+                # no-zeroing invariant — and its sampled token is
+                # simply never read
+                continue
+            if not ok[slot]:
+                # poison quarantine, same contract as the
+                # synchronous path
+                self.metrics.inc("quarantined")
+                exc = PoisonRequestError(
+                    "request produced non-finite logits at decode "
+                    f"step {int(st.step[slot])}; quarantined")
+                self._release_slot(slot)
+                self._fail(req, exc)
+                continue
+            token = tokens[slot]
+            # backfill the host mirror; the NEXT step's input (if
+            # already dispatched) came from tok_dev, not this
+            st.token[slot] = token
+            self._emit(req, token, now, itl_out=itl)
+            emitted += 1
+            self._retire(slot, req, token, flags[slot], now)
+        if emitted:
+            self.metrics.tokens.record(emitted)
+        if itl:
+            self.metrics.itl_ms.record_many(itl)
+        if self.cache_backend == "paged":
+            self._update_block_gauges()
+            self._sched.step_collected(self.metrics.kv_tokens_live)
 
     def _drop_pending(self):
         """Discard in-flight pipelined state (recovery/poison/stop:
@@ -3038,10 +3141,18 @@ class GenerationEngine:
 
         The loop itself never dies to a fault — the heartbeat
         (``/healthz`` watchdog) goes stale only when an iteration
-        genuinely hangs."""
+        genuinely hangs.
+
+        Every iteration is accounted for in ``metrics.scheduler``
+        (:class:`~.metrics.SchedulerAccount`): the callees open the
+        phases ``admit``, ``chunk_dispatch``, ``chunk_wait``,
+        ``decode_dispatch``, ``decode_wait``, ``emit``, ``idle`` and
+        ``fault``; what none of them claims is ``other``."""
         paged = self.cache_backend == "paged"
         backoff = self._retry_backoff_s
         strikes = 0
+        sched = self._sched
+        sched.start()
         while self._running:
             self._beat = time.monotonic()
             try:
@@ -3068,30 +3179,36 @@ class GenerationEngine:
                 if strikes > self._max_step_retries:
                     # bounded give-up: rebuild rather than spin forever
                     self.metrics.inc("recoveries")
-                    try:
-                        self._recover(f"retries exhausted: {e!r}")
-                    except Exception as e2:  # noqa: BLE001
-                        self._poison(repr(e2))
+                    with sched.phase("fault", why="retries_exhausted"):
+                        try:
+                            self._recover(f"retries exhausted: {e!r}")
+                        except Exception as e2:  # noqa: BLE001
+                            self._poison(repr(e2))
                     strikes = 0
                     backoff = self._retry_backoff_s
                 else:
                     self.metrics.inc("retries")
-                    time.sleep(backoff)
+                    with sched.phase("fault", why="backoff"):
+                        time.sleep(backoff)
                     backoff = min(backoff * 2.0,
                                   self._retry_backoff_max_s)
             except Exception as e:  # noqa: BLE001 — cache-corrupting
                 # (donated buffers gone) or an unexpected scheduler
                 # error: rebuild all in-flight state by recompute
                 self.metrics.inc("recoveries")
-                try:
-                    self._recover(repr(e))
-                except Exception as e2:  # noqa: BLE001
-                    self._poison(repr(e2))
+                with sched.phase("fault", why="recover"):
+                    try:
+                        self._recover(repr(e))
+                    except Exception as e2:  # noqa: BLE001
+                        self._poison(repr(e2))
                 strikes = 0
                 backoff = self._retry_backoff_s
             else:
                 strikes = 0
                 backoff = self._retry_backoff_s
+            # one stamp ends this iteration's account and starts the
+            # next: what no phase claimed above went to ``other``
+            sched.tick(self.metrics.decode_steps)
         # shutdown cleanup runs HERE, on the scheduler thread — stop()
         # must not mutate the slot table from another thread while a
         # final device call might still be in flight

@@ -8,12 +8,32 @@ server returns :meth:`ServingMetrics.snapshot` per model.
 """
 from __future__ import annotations
 
+import heapq
 import re
 import threading
+import time
 from typing import Dict, List
+
+from jax.profiler import TraceAnnotation
 
 from ..profiler import (RESERVOIR_SNAPSHOT_KEYS, CountHistogram,
                         OpProfiler, RateMeter, Reservoir)
+
+#: The phases of one iteration of ``GenerationEngine._loop``. They
+#: partition it: at any instant the scheduler thread is in exactly one,
+#: and what no site claims is ``other``. Each is a cumulative counter
+#: under ``scheduler.phase_s`` in ``/stats`` and, while a
+#: ``jax.profiler`` session runs, a ``gen.<phase>`` span on the
+#: profiler's clock (docs/observability.md, "The scheduler's time
+#: account").
+SCHED_PHASES = ("admit", "chunk_dispatch", "chunk_wait",
+                "decode_dispatch", "decode_wait", "emit", "idle",
+                "fault", "other")
+SCHED_SPAN_PREFIX = "gen."
+#: the HTTP front-ends' write of one streamed token to the socket
+HTTP_WRITE_SPAN = "http.stream_write"
+#: why a request waits at the head of the queue
+HEAD_BLOCKED_CAUSES = ("blocks", "slots")
 
 
 class ServingMetrics:
@@ -102,6 +122,166 @@ class ServingMetrics:
         }
 
 
+class _Phase:
+    """``with account.phase(name, **attrs) as t0``: one stamp going in
+    (returned), one coming out (left in ``account.t``)."""
+
+    __slots__ = ("_acct", "_name", "_attrs")
+
+    def __init__(self, acct, name, attrs):
+        self._acct, self._name, self._attrs = acct, name, attrs
+
+    def __enter__(self) -> float:
+        return self._acct._enter(self._name, self._attrs)
+
+    def __exit__(self, *exc) -> None:
+        self._acct._exit()
+
+
+class SchedulerAccount:
+    """Where the generation scheduler's wall time goes, cut so that it
+    adds up: ``sum(phase_s.values()) == loop_s``.
+
+    One mechanism, two outputs. Each phase boundary takes one
+    ``time.perf_counter()`` stamp; the interval since the last stamp
+    is charged to the phase that was open (``other`` when none), and
+    the same interval is a ``jax.profiler.TraceAnnotation`` named
+    ``gen.<phase>`` that costs under a microsecond unless a profiler
+    session is recording. A phase opened inside another (the idle park
+    inside ``admit``, the slot backend's prefill inside ``admit``)
+    pauses its parent, counter and span alike, so the partition stays
+    exact.
+
+    Written by the scheduler thread only. Totals are committed once an
+    iteration under a lock, so a ``/stats`` reader always sees phases
+    that sum to ``loop_s`` and step counts of whole iterations: ratios
+    of two deltas of this block are exact averages over iterations.
+    """
+
+    def __init__(self, clock=time.perf_counter):
+        self._clock = clock     # tests put a clock they step by hand
+        self._lock = threading.Lock()
+        self.loop_s = 0.0
+        self.iterations = 0
+        self.phase_s = dict.fromkeys(SCHED_PHASES, 0.0)
+        self.phase_n = dict.fromkeys(SCHED_PHASES, 0)
+        self.head_blocked_s = dict.fromkeys(HEAD_BLOCKED_CAUSES, 0.0)
+        self.kv_live_token_steps = 0
+        #: how many of the longest non-idle iterations are kept
+        self.keep_slowest = 8
+        self._slowest: List[tuple] = []      # min-heap on seconds
+        # the open iteration (scheduler thread only)
+        self.t = clock()                     # the last stamp taken
+        self._t_iter = self.t
+        self._stack: List[list] = []         # [name, attrs, annotation]
+        self._it_s: Dict[str, float] = {}
+        self._it_n: Dict[str, int] = {}
+        self._it_kv = 0
+        self._it_blocked: Dict[str, float] = {}
+        self._blocked = None                 # (cause, since) or None
+
+    # -- scheduler thread ----------------------------------------------
+    def start(self) -> None:
+        """The scheduler thread is up: the account's clock starts."""
+        self.t = self._t_iter = self._clock()
+
+    def phase(self, name: str, **attrs) -> _Phase:
+        return _Phase(self, name, attrs)
+
+    def _charge(self, now: float) -> None:
+        name = self._stack[-1][0] if self._stack else "other"
+        self._it_s[name] = self._it_s.get(name, 0.0) + (now - self.t)
+        self.t = now
+
+    def _enter(self, name: str, attrs: dict) -> float:
+        now = self._clock()
+        self._charge(now)
+        if self._stack:
+            self._stack[-1][2].__exit__(None, None, None)
+        self._it_n[name] = self._it_n.get(name, 0) + 1
+        ann = TraceAnnotation(SCHED_SPAN_PREFIX + name, **attrs)
+        ann.__enter__()
+        self._stack.append([name, attrs, ann])
+        return now
+
+    def _exit(self) -> None:
+        self._charge(self._clock())
+        self._stack.pop()[2].__exit__(None, None, None)
+        if self._stack:
+            parent = self._stack[-1]
+            parent[2] = TraceAnnotation(SCHED_SPAN_PREFIX + parent[0],
+                                        **parent[1])
+            parent[2].__enter__()
+
+    def head_blocked(self, cause) -> None:
+        """Called once an admission pass: ``cause`` is why the request
+        at the head of the queue could not be admitted ("blocks": the
+        pool cannot cover it though a slot is free; "slots": no slot
+        is free), or None when nothing waits. The time until the next
+        call is charged to that cause."""
+        now = self._clock()
+        if self._blocked is not None:
+            was, since = self._blocked
+            self._it_blocked[was] = self._it_blocked.get(was, 0.0) \
+                + (now - since)
+        self._blocked = None if cause is None else (cause, now)
+
+    def step_collected(self, kv_tokens_live: int) -> None:
+        """One decode step's results are on the host: add the live KV
+        tokens it ran over (memory in use, integrated over steps)."""
+        self._it_kv += int(kv_tokens_live)
+
+    def tick(self, step: int) -> None:
+        """End of one iteration of the loop, start of the next."""
+        self._charge(self._clock())
+        it_s, t0 = self._it_s, self._t_iter
+        total = sum(it_s.values())
+        with self._lock:
+            self.loop_s += total
+            self.iterations += 1
+            for k, v in it_s.items():
+                self.phase_s[k] += v
+            for k, n in self._it_n.items():
+                self.phase_n[k] += n
+            for k, v in self._it_blocked.items():
+                self.head_blocked_s[k] += v
+            self.kv_live_token_steps += self._it_kv
+            if not it_s.get("idle") and self.keep_slowest > 0:
+                entry = (total, t0, int(step), it_s)
+                if len(self._slowest) < self.keep_slowest:
+                    heapq.heappush(self._slowest, entry)
+                elif total > self._slowest[0][0]:
+                    heapq.heapreplace(self._slowest, entry)
+        self._t_iter = self.t
+        self._it_s, self._it_n, self._it_blocked = {}, {}, {}
+        self._it_kv = 0
+
+    # -- readers ---------------------------------------------------------
+    def busy_and_blocked(self):
+        """(seconds the loop was not idle, seconds of those it was
+        blocked in a fetch), as of the last whole iteration."""
+        with self._lock:
+            p = self.phase_s
+            return (self.loop_s - p["idle"],
+                    p["decode_wait"] + p["chunk_wait"])
+
+    def snapshot(self) -> Dict:
+        with self._lock:
+            slowest = sorted(self._slowest, key=lambda e: -e[0])
+            return {
+                "loop_s": self.loop_s,
+                "iterations": self.iterations,
+                "phase_s": dict(self.phase_s),
+                "phase_n": dict(self.phase_n),
+                "head_blocked_s": dict(self.head_blocked_s),
+                "kv_live_token_steps": self.kv_live_token_steps,
+                # [t_start (perf_counter), seconds, decode step
+                # ordinal, {phase: seconds}], longest first
+                "slowest": [[t0, s, step, dict(ph)]
+                            for s, t0, step, ph in slowest],
+            }
+
+
 class GenerationMetrics:
     """Always-on counters for one continuous-batching generation
     engine. Same threading discipline as :class:`ServingMetrics`
@@ -138,12 +318,27 @@ class GenerationMetrics:
         self.ttft_ms = Reservoir(latency_window)    # submit -> 1st token
         self.itl_ms = Reservoir(latency_window)     # inter-token gap
         self.prefill_ms = Reservoir(latency_window)
+        # a step's dispatch-to-results-on-host span, over the last
+        # ``latency_window`` steps since process start. With the
+        # pipeline on, neighbouring spans overlap (a step is
+        # dispatched before the one ahead of it is collected), so
+        # this is NOT the time a step takes and its samples do not
+        # add up to wall time: ``scheduler`` below is the account
+        # that does
         self.decode_step_ms = Reservoir(latency_window)
         # pipelined decode (ISSUE 14): how long the scheduler actually
         # BLOCKED at the step-t sync after dispatching step t+1 — near
         # zero when host bookkeeping fully overlaps device compute,
         # approaching decode_step_ms when the device is the bottleneck
         self.decode_sync_wait_ms = Reservoir(latency_window)
+        # the scheduler's time account (phases, head-of-queue waits by
+        # cause, live KV integrated over steps): scheduler thread only
+        self.scheduler = SchedulerAccount()
+        # HTTP tier: engine's emit stamp -> the token's chunk handed to
+        # the socket (written by the front-end's threads, under _lock)
+        self.stream_chunks = 0
+        self.stream_delay_s = 0.0
+        self.stream_delay_max_s = 0.0
         self.queue_depth = 0       # gauge, updated by the scheduler
         self.queue_max = 0
         self.active_slots = 0      # gauge
@@ -224,9 +419,22 @@ class GenerationMetrics:
         with self._lock:
             setattr(self, field, getattr(self, field) + n)
 
+    def note_stream_write(self, delay_s: float):
+        """One streamed token reached the socket ``delay_s`` after the
+        scheduler emitted it."""
+        with self._lock:
+            self.stream_chunks += 1
+            self.stream_delay_s += delay_s
+            if delay_s > self.stream_delay_max_s:
+                self.stream_delay_max_s = delay_s
+
     def snapshot(self) -> Dict:
         occ = self.occupancy_hist
         steps = occ.total()
+        with self._lock:    # one write's three fields, never torn
+            stream = {"chunks": self.stream_chunks,
+                      "delay_s": self.stream_delay_s,
+                      "delay_max_s": self.stream_delay_max_s}
         paged = None
         if self.cache_backend == "paged":
             used = self.blocks_total - self.blocks_free
@@ -343,6 +551,8 @@ class GenerationMetrics:
             "decode_sync_wait_ms": {
                 k: round(v, 3) for k, v in
                 self.decode_sync_wait_ms.snapshot().items()},
+            "scheduler": self.scheduler.snapshot(),
+            "stream": stream,
             "kv_cache_bytes": self.cache_bytes,
             "kv_dtype": self.kv_dtype,
             "kv_bits": self.kv_bits,
@@ -359,11 +569,12 @@ class GenerationMetrics:
 
 
 def profiler_sections() -> Dict:
-    """The profiler's own `serving.*` section timings (populated when
-    ProfilingMode is OPERATIONS/ALL), merged into `GET /stats`."""
+    """The profiler's own `serving.*` and `generation.*` section
+    timings (populated when ProfilingMode is OPERATIONS/ALL), merged
+    into `GET /stats`."""
     return {name: stats for name, stats in
             OpProfiler.get_instance().timings().items()
-            if name.startswith("serving.")}
+            if name.startswith(("serving.", "generation."))}
 
 
 # -- Prometheus text exposition ----------------------------------------
@@ -396,6 +607,8 @@ _PROM_COUNTERS = frozenset({
     "restore_failures",
     "compiles", "hits", "misses", "evictions",
     "client_disconnects",
+    # the scheduler's time account and the HTTP tier's stream writes
+    "iterations", "kv_live_token_steps", "chunks",
     # fleet-side counters
     "routed", "hedges", "hedges_won", "hedge_budget_denied",
     "requests_lost", "ejections", "readmissions", "restarts",

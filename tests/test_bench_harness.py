@@ -123,13 +123,12 @@ def test_generation_scenario_harness_runs_on_cpu():
     assert res["chaos_recompiles_post_warmup"] == 0
     assert res["chaos_recoveries"] >= 1
     # traced re-run (ISSUE 10): per-request tracing enabled must still
-    # reproduce the tokens and record spans; the <5% overhead bound is
-    # gated at full scale via the recorded baseline — at CI's tiny
-    # sizes scheduling noise dominates, so bound it loosely here
+    # reproduce the tokens and record spans. What tracing costs is a
+    # time, so it is measured on the chip (PERF.md section 6), never
+    # bounded by a CPU timing here
     assert res["traced_tokens_per_sec"] > 0
     assert res["tokens_identical_traced"] is True
     assert res["trace_spans_recorded"] >= 8 * 3  # admission+queue+decode
-    assert res["trace_overhead_frac"] < 0.25
     # speculative leg (ISSUE 12): k=3 same-weights draft vs k=0 on the
     # long-context mix — tokens must be identical (the bit-identity
     # contract, measured not assumed), the accept path must actually

@@ -52,6 +52,7 @@ def v5e():
                                            sharding=sharding), args)
         text = jax.jit(fn).lower(*args).compile().as_text()
         assert "tpu_custom_call" in text, "no Mosaic kernel in the program"
+        return text
     return compile_for
 
 
@@ -62,6 +63,25 @@ def _kv(shape, dt):
         return QuantArray(sds(shape, jnp.int8),
                           sds(shape[:-1], jnp.float32))
     return sds(shape, {"f32": jnp.float32, "bf16": jnp.bfloat16}[dt])
+
+
+def test_paged_kernels_custom_call_is_named_after_the_kernel(v5e):
+    """A device trace names an operation by its HLO instruction: the
+    paged decode kernel's has to carry the kernel's own name, not the
+    enclosing jit's (the ledger's ``breakdown.device_ops``; the
+    benchmark's kernel metrics take any named custom call)."""
+    from deeplearning4j_tpu.kernels.paged_attention import KERNEL_NAME
+    S, H, D, Bs, T = (SHAPES["served"][k] for k in ("S", "H", "D", "Bs", "T"))
+    sds = jax.ShapeDtypeStruct
+    pool = sds((S * (T // Bs) + 1, H, Bs, D), jnp.float32)
+
+    def step(q, k, v, t, l):
+        return paged_attention_pallas(q, k, v, t, l, interpret=False)
+    text = v5e(step, sds((S, H, D), jnp.float32), pool, pool,
+               sds((S, T // Bs), jnp.int32), sds((S,), jnp.int32))
+    calls = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
+    assert len(calls) == 1
+    assert calls[0].lstrip().startswith(f"%{KERNEL_NAME}"), calls[0][:120]
 
 
 @pytest.mark.parametrize("dt", ["f32", "bf16", "int8"])

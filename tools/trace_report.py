@@ -162,13 +162,15 @@ def spec_savings(traces):
 
 def step_pipeline(traces):
     """Aggregate the decode scheduler's ``step_pipeline`` spans (one
-    per request on a pipelining engine): how much device time the
-    request's decode lifetime covered, how long the scheduler actually
-    BLOCKED waiting for results, and the realized overlap — the gap
-    between the two is host work (sampling, bookkeeping, admission)
-    that ran while the device computed. ``overlap_frac`` near 0 reads
-    as a synchronous lockstep loop; near 1, the host never waited."""
-    agg = {"requests": 0, "device_ms": 0.0, "sync_wait_ms": 0.0}
+    per request that decoded): the scheduler loop's non-idle wall time
+    over the request's decode lifetime (``wall_ms``), the part of it
+    the scheduler thread spent BLOCKED in a step's or a chunk's fetch
+    (``sync_wait_ms``), and the share that is left — both from the
+    engine's time account (``scheduler`` in ``/stats``), so the parts
+    add up. The gap between the two is the host's own work (admission,
+    dispatch, token fan-out). ``overlap_frac`` near 0 reads as a loop
+    that only waits for the device; near 1, the host never waited."""
+    agg = {"requests": 0, "wall_ms": 0.0, "sync_wait_ms": 0.0}
     fracs = []
     for t in traces:
         for s in t.get("spans", []):
@@ -176,18 +178,18 @@ def step_pipeline(traces):
                 continue
             a = s.get("attrs", {})
             agg["requests"] += 1
-            agg["device_ms"] += float(a.get("device_ms") or 0.0)
+            agg["wall_ms"] += float(a.get("wall_ms") or 0.0)
             agg["sync_wait_ms"] += float(a.get("sync_wait_ms") or 0.0)
             if a.get("overlap_frac") is not None:
                 fracs.append(float(a["overlap_frac"]))
     if not agg["requests"]:
         return {}
     agg["overlap_frac"] = round(
-        max(0.0, 1.0 - agg["sync_wait_ms"] / agg["device_ms"]), 4) \
-        if agg["device_ms"] > 0 else 0.0
+        max(0.0, 1.0 - agg["sync_wait_ms"] / agg["wall_ms"]), 4) \
+        if agg["wall_ms"] > 0 else 0.0
     agg["overlap_frac_p50"] = round(_pct(fracs, 50), 4)
     agg["overlap_frac_p99"] = round(_pct(fracs, 99), 4)
-    agg["device_ms"] = round(agg["device_ms"], 3)
+    agg["wall_ms"] = round(agg["wall_ms"], 3)
     agg["sync_wait_ms"] = round(agg["sync_wait_ms"], 3)
     return agg
 
@@ -375,8 +377,8 @@ def _fmt_human(rep):
         lines.append("-- decode pipelining (step_pipeline spans)")
         lines.append(
             f"   {pl['requests']:>5} request(s)  "
-            f"device {pl['device_ms']:.1f} ms  "
-            f"host-sync wait {pl['sync_wait_ms']:.1f} ms  "
+            f"loop wall {pl['wall_ms']:.1f} ms  "
+            f"blocked in fetches {pl['sync_wait_ms']:.1f} ms  "
             f"overlap {pl['overlap_frac']:.1%} "
             f"(p50 {pl['overlap_frac_p50']:.1%}, "
             f"p99 {pl['overlap_frac_p99']:.1%})")
