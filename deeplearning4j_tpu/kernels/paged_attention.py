@@ -4,25 +4,39 @@ The paged sibling of :mod:`.decode_attention`: one query row per
 sequence attends over a prefix whose K/V lives in POOL BLOCKS
 (``[num_blocks, H, block_size, D]``, `serving/paging.py`) addressed
 through a per-sequence block table, instead of a contiguous per-slot
-panel. The op stays HBM-bandwidth bound, so the kernel's job is
-unchanged — stream K/V once, keep online-softmax state in VMEM — with
-one addition: the block table drives WHICH pool block each grid step
-pulls. On TPU that is scalar prefetch (`pltpu.PrefetchScalarGridSpec`,
-pallas guide §12): the int32 tables land in SMEM before the kernel
-body runs, and the K/V BlockSpec index maps read them to aim the
-HBM→VMEM DMA at the right pool block — the gather costs no extra pass
-over memory.
+panel. The op is HBM-bandwidth bound by its bytes, but a Pallas grid
+step has a cost of its own (~0.35 us with three small operands on a
+v5e), so the kernel's job is to stream the LIVE K/V once in few, large
+steps and keep the online-softmax state in VMEM.
+
+The grid is ``(S, ceil(B / G))``: one grid step takes one slot, ALL its
+heads, and ``G`` table entries. A pool block ``[H, Bs, D]`` is
+contiguous in the pool, so it is one DMA; a pool enters the call as
+``G`` operands whose index maps read a scalar-prefetched table
+(`pltpu.PrefetchScalarGridSpec`, pallas guide section 12) and aim each
+at ``pool[tbl[s, c * G + g]]`` -- the gather costs no extra pass over
+memory, and Pallas's pipeline fetches chunk ``c + 1`` (or the next
+slot's first) while chunk ``c`` is computed. ``G`` follows from the
+block's VMEM footprint (:func:`blocks_per_chunk`). Past a slot's last
+live block every operand is aimed at the block it already holds, which
+starts no DMA, and the body is skipped (``pl.when``): a step costs its
+live keys plus ~70 ns an operand for each table entry walked. The
+scores of a block are a ``[H, Bs]`` tile made on the VPU (multiply by
+the head's query row, reduce over lanes) in f32; the MXU has no use
+for one query row a head. (The pools cannot be left in HBM for manual
+DMAs: Mosaic refuses to slice an HBM ref whose minor dimension, 64
+here, is not a multiple of 128.)
 
 Layout: q [S, H, D]; pools [N, H, Bs, D] (positions contiguous per
 head inside a block, same reasoning as the slot cache's [S, H, T, D]);
 block_tables [S, B] int32 pool indices (NULL_BLOCK-padded); lengths
 [S]. Key position ``j`` of sequence ``s`` lives at
 ``pool[block_tables[s, j // Bs], :, j % Bs]``; positions >= lengths[s]
-are masked, so padded table entries are never READ into the result —
-they only keep the gather shape static.
+are masked, so padded table entries are never READ into the result --
+they only keep the shapes static.
 
 Elsewhere the fused-XLA path gathers the blocks with ``jnp.take`` and
-reuses :func:`~.decode_attention.decode_attention_xla` — the gathered
+reuses :func:`~.decode_attention.decode_attention_xla` -- the gathered
 [S, H, B*Bs, D] view is bit-identical to a slot cache holding the same
 prefix, which is what makes paged-vs-slot token parity testable.
 """
@@ -37,9 +51,8 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .decode_attention import (decode_attention_xla, decode_kernel,
-                               decode_scratch)
-from .flash_attention import default_platform
+from .decode_attention import decode_attention_xla
+from .flash_attention import _NEG_INF, _cdiv, default_platform
 from .kv_quant import QuantArray, is_quantized
 
 #: the Pallas kernel's name: its custom call in the HLO, and the
@@ -82,80 +95,173 @@ def paged_attention_xla(q, k_pool, v_pool, block_tables, lengths):
 # ---------------------------------------------------------------------------
 # Pallas TPU kernel
 # ---------------------------------------------------------------------------
-def _paged_kernel(tbl_ref, *refs, **kw):
-    # the tables are consumed by the index maps alone: by the time the
-    # body runs they have already steered the DMA, and the only
-    # per-position fact left is "is j < length" (covers stale tails AND
-    # padded table entries), which the shared body computes
-    decode_kernel(*refs, **kw)
+#: VMEM the kernel fills with pool blocks in flight (K and V, two
+#: buffers each); the blocks a chunk holds follow from it. On a v5e at
+#: H 25, Bs 16, D 64, f32, 48 calls over 4,400 live keys take 9.5 ms at
+#: 2 blocks a chunk, 8.2 at 4, 8.6 at 8 and 10.5 at 16 (PERF.md, PR 27):
+#: a larger chunk saves grid steps and wastes more of its last tile
+_VMEM_BLOCK_BUDGET = 4 << 20
+
+
+def blocks_per_chunk(H: int, Bs: int, D: int, itemsize: int, B: int) -> int:
+    """Pool blocks one chunk of the kernel attends (``G``): the largest
+    power of two whose K and V double buffers fit the VMEM budget, as
+    Mosaic tiles a ``[H, Bs, D]`` block there (rows padded to the
+    sublane tile of the item size, ``D`` to 128 lanes), and no more
+    than the table holds."""
+    sublanes = 8 * 4 // itemsize
+    block = H * _cdiv(Bs, sublanes) * sublanes * _cdiv(D, 128) * 128 \
+        * itemsize
+    g = max(1, min(_VMEM_BLOCK_BUDGET // (4 * block), B))
+    return 1 << (g.bit_length() - 1)
+
+
+def _paged_kernel(tbl_ref, len_ref, q_ref, *refs, quant: bool, G: int,
+                  scale: float):
+    """One grid step (slot ``s``, chunk ``c``) of paged decode
+    attention: every head of the slot against the ``G`` pool blocks of
+    table entries ``c * G .. c * G + G - 1``.
+
+    Refs (the slot dim squeezed): tbl_ref [S, C * G] (the table entry
+    each operand fetches: the index maps alone read it) and len_ref
+    [S], scalar-prefetched; q_ref [H, D]; ``G`` K blocks then ``G`` V
+    blocks [H, Bs, D]; for an int8 pool ``G`` K then ``G`` V scale
+    tiles [H, Bs]; o_ref [H, D]; scratch m, l [H, 1] and acc [H, D].
+
+    The scores of a block are a [H, Bs] tile: a VPU multiply by the
+    head's query row and a lane reduction, in f32 whatever the pool
+    holds. A chunk whose first position is past the length runs no
+    body (and fetched nothing: the index maps repeat a block they
+    already hold). Inside the last live chunk, what a block holds past
+    the length (a stale tail, or another position's keys where the
+    index map repeated a block) is masked by position with ``where``,
+    never multiplied away: it may be NaN."""
+    k_refs, v_refs = refs[:G], refs[G:2 * G]
+    ks_refs, vs_refs = (refs[2 * G:3 * G], refs[3 * G:4 * G]) if quant \
+        else (None, None)
+    o_ref, m_s, l_s, acc_s = refs[-4:]
+    H, Bs, D = k_refs[0].shape
+    c = pl.program_id(1)
+    length = len_ref[pl.program_id(0)]
+
+    @pl.when(c == 0)
+    def _init():
+        m_s[...] = jnp.full_like(m_s, _NEG_INF)
+        l_s[...] = jnp.zeros_like(l_s)
+        acc_s[...] = jnp.zeros_like(acc_s)
+
+    @pl.when(c * (G * Bs) < length)
+    def _chunk():
+        q = q_ref[...].astype(jnp.float32)[:, None, :] * scale  # [H,1,D]
+        first = [(c * G + g) * Bs for g in range(G)]
+        lane = lax.broadcasted_iota(jnp.int32, (H, Bs), 1)
+        mask = [p0 + lane < length for p0 in first]
+        sc = []
+        for g in range(G):
+            x = jnp.sum(k_refs[g][...].astype(jnp.float32) * q, axis=-1)
+            if quant:
+                x = x * ks_refs[g][...]                   # K dequant
+            sc.append(jnp.where(mask[g], x, _NEG_INF))    # [H, Bs]
+        m_prev = m_s[...]
+        m_new = jnp.maximum(m_prev, functools.reduce(
+            jnp.maximum, sc).max(axis=-1, keepdims=True))
+        corr = jnp.exp(m_prev - m_new)
+        l_new, acc = l_s[...] * corr, acc_s[...] * corr
+        row = lax.broadcasted_iota(jnp.int32, (H, Bs, 1), 1)
+        for g in range(G):
+            # where-guard keeps fully-masked rows at p=0 (exp(-inf -
+            # -inf) = 1 would fabricate uniform attention)
+            p = jnp.where(mask[g], jnp.exp(sc[g] - m_new), 0.0)
+            l_new = l_new + p.sum(axis=-1, keepdims=True)
+            if quant:
+                # V dequant folds into p; a stale scale may be NaN
+                p = jnp.where(mask[g], p * vs_refs[g][...], 0.0)
+            # zero masked V rows: 0 * NaN = NaN would leak a stale tail
+            v = jnp.where(first[g] + row < length,
+                          v_refs[g][...].astype(jnp.float32), 0.0)
+            acc = acc + jnp.sum(p[:, :, None] * v, axis=1)
+        m_s[...], l_s[...], acc_s[...] = m_new, l_new, acc
+
+    @pl.when(c == pl.num_programs(1) - 1)
+    def _finalize():
+        # a free lane (length 0) ran no chunk: acc is 0 and so is its row
+        o_ref[...] = (acc_s[...] / jnp.maximum(l_s[...], 1e-30)
+                      ).astype(o_ref.dtype)
 
 
 def paged_attention_pallas(q, k_pool, v_pool, block_tables, lengths,
-                           precision=lax.Precision.DEFAULT,
                            interpret: Optional[bool] = None):
     """Pallas paged decode attention. Same contract as
-    :func:`paged_attention_xla`; grid (S, H, blocks-per-seq) with the
-    block tables scalar-prefetched so the K/V index maps aim each grid
-    step's DMA at ``pool[tbl[s, bi]]`` directly — no materialized
-    gather. The body is the slot kernel's
-    (:func:`~.decode_attention.decode_kernel`) with one pool block as
-    the key tile. int8 QuantArray pools add their per-block-per-head
-    scale rows as two more operands riding the SAME table index maps,
-    so each grid step pulls one int8 block plus its [Bs] scale row and
-    dequantizes in VMEM."""
+    :func:`paged_attention_xla`. Grid ``(S, ceil(B / G))``: one grid
+    step attends all heads of a slot over ``G`` table entries
+    (:func:`blocks_per_chunk`). A pool enters as ``G`` operands, each
+    one whole block ``[H, Bs, D]`` (contiguous in the pool) that the
+    scalar-prefetched table aims at ``pool[tbl[s, c * G + g]]``. Past
+    the slot's last live block an operand is aimed at the block it
+    fetched last, so the pipeline fetches nothing new, and the body is
+    skipped: the cost follows the live length, not the table span. An
+    int8 QuantArray pool brings its ``[H, Bs]`` scale tiles the same
+    way and is dequantized in VMEM."""
     if interpret is None:
         interpret = default_platform() != "tpu"
     quant = is_quantized(k_pool)
     if quant != is_quantized(v_pool):
         raise ValueError("K and V pools must be quantized together")
     S, H, D = q.shape
-    N, _, Bs, _ = k_pool.shape
+    pools = [k_pool.q, v_pool.q, k_pool.scale, v_pool.scale] if quant \
+        else [k_pool, v_pool]
+    Bs = pools[0].shape[2]
     B = block_tables.shape[1]
-    # q/out/scales carry a unit second-minor dim: a one-row tile of an
-    # [.., H, D] array is not a block shape the TPU lowering takes
-    # (second-minor must be a multiple of 8 or the whole dim)
-    q_spec = pl.BlockSpec((None, None, 1, D),
-                          lambda s, h, bi, tbl, lens: (s, h, 0, 0))
-    kv_spec = pl.BlockSpec((None, None, Bs, D),
-                           lambda s, h, bi, tbl, lens:
-                           (tbl[s, bi], h, 0, 0))
-    operands, in_specs = [q.reshape(S, H, 1, D)], [q_spec]
-    if quant:
-        sc_spec = pl.BlockSpec((None, None, 1, Bs),
-                               lambda s, h, bi, tbl, lens:
-                               (tbl[s, bi], h, 0, 0))
-        operands += [k_pool.q, v_pool.q,
-                     k_pool.scale.reshape(N, H, 1, Bs),
-                     v_pool.scale.reshape(N, H, 1, Bs)]
-        in_specs += [kv_spec, kv_spec, sc_spec, sc_spec]
-    else:
-        operands += [k_pool, v_pool]
-        in_specs += [kv_spec, kv_spec]
-    out = pl.pallas_call(
-        functools.partial(_paged_kernel, quant=quant, blk_k=Bs,
-                          scale=1.0 / (D ** 0.5), precision=precision),
+    G = blocks_per_chunk(H, Bs, D, pools[0].dtype.itemsize, B)
+    C = _cdiv(B, G)
+    # The table entry each of a chunk's G operands fetches, [S, C * G]:
+    # its own (c * G + g) while that is live, then the last live one
+    # this operand had, its first if it has none: an index that does
+    # not change starts no DMA. Worked out here, once a step (every
+    # layer's call shares it), so that an index map is one SMEM read.
+    lengths = jnp.minimum(jnp.asarray(lengths, jnp.int32), B * Bs)
+    last = (jnp.maximum(lengths, 1) - 1)[:, None] // Bs
+    ci, gi = jnp.divmod(jnp.arange(C * G, dtype=jnp.int32), G)
+    ci = jnp.minimum(ci, jnp.maximum(last - gi, 0) // G)
+    fetched = jnp.take_along_axis(
+        jnp.asarray(block_tables, jnp.int32),
+        jnp.minimum(ci * G + gi, B - 1), axis=1)
+
+    def entry(g, tail):
+        return lambda s, c, tbl, lens: (tbl[s, c * G + g],) + tail
+
+    q_spec = pl.BlockSpec((None, H, D), lambda s, c, tbl, lens: (s, 0, 0))
+    operands, in_specs = [q], [q_spec]
+    for pool in pools:
+        operands += [pool] * G
+        in_specs += [pl.BlockSpec((None,) + pool.shape[1:],
+                                  entry(g, (0,) * (pool.ndim - 1)))
+                     for g in range(G)]
+    return pl.pallas_call(
+        functools.partial(_paged_kernel, quant=quant, G=G,
+                          scale=1.0 / (D ** 0.5)),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,          # block_tables, lengths
-            grid=(S, H, B),
+            num_scalar_prefetch=2,          # fetched, lengths
+            grid=(S, C),
             in_specs=in_specs, out_specs=q_spec,
-            scratch_shapes=decode_scratch(D)),
-        out_shape=jax.ShapeDtypeStruct((S, H, 1, D), q.dtype),
+            scratch_shapes=[pltpu.VMEM((H, 1), jnp.float32),   # max
+                            pltpu.VMEM((H, 1), jnp.float32),   # sum
+                            pltpu.VMEM((H, D), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct((S, H, D), q.dtype),
         interpret=interpret,
         # the custom call's instruction name in the HLO and so in a
         # device trace (else it is named after the enclosing jit)
         name=KERNEL_NAME,
-    )(jnp.asarray(block_tables, jnp.int32),
-      jnp.asarray(lengths, jnp.int32), *operands)
-    return out.reshape(S, H, D)
+    )(fetched, lengths, *operands)
 
 
 def paged_attention(q, k_pool, v_pool, block_tables, lengths,
                     impl: str = "auto", **kw):
     """Dispatch: ``auto`` runs the Pallas kernel on TPU (scalar-
-    prefetched block gather + VMEM-resident softmax state), fused XLA
-    elsewhere. ``pallas`` / ``xla`` force a path (parity tests run
-    pallas in interpret mode on CPU so one kernel is tested
-    everywhere)."""
+    prefetched block gather bounded by the live lengths, VMEM-resident
+    softmax state), fused XLA elsewhere. ``pallas`` / ``xla`` force a
+    path (parity tests run pallas in interpret mode on CPU so one
+    kernel is tested everywhere)."""
     if impl == "auto":
         impl = "pallas" if default_platform() == "tpu" else "xla"
     if impl == "pallas":

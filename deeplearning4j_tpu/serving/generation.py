@@ -2887,6 +2887,16 @@ class GenerationEngine:
         return [s for s in range(self.num_slots)
                 if st.requests[s] is not None and st.step[s] > 0]
 
+    def _account_step_blocks(self, active: List[int]):
+        """Tell the account what the paged step about to be dispatched
+        attends: from the lengths it is dispatched with (``pos + 1`` of
+        the decode lanes), no device read."""
+        if self.cache_backend == "paged":
+            lengths = self._slots.pos[active] + 1
+            self._sched.step_dispatched(
+                int((-(-lengths // self.block_size)).sum()),
+                self.num_slots * self._blocks_per_seq)
+
     def _decode_step(self, skip=frozenset()):
         """One plain decode step. ``skip`` holds slots a speculative
         round already advanced this iteration: they ride the batch as
@@ -2907,6 +2917,7 @@ class GenerationEngine:
         n_step = self.metrics.decode_steps      # this step's ordinal
         with sched.phase("decode_dispatch", step=n_step,
                          slots=len(active)) as t0:
+            self._account_step_blocks(active)
             if self.cache_backend == "paged":
                 nxt, okd, dnd, self._kcs, self._vcs = \
                     self._get_decode_exe()(
@@ -3008,6 +3019,7 @@ class GenerationEngine:
         n_step = self.metrics.decode_steps      # this step's ordinal
         with self._sched.phase("decode_dispatch", step=n_step,
                                slots=len(active)) as t0:
+            self._account_step_blocks(active)
             tok_dev = self._nxt_dev
             if tok_dev is None:
                 tok_dev = self._no_dev_tok

@@ -167,6 +167,8 @@ class SchedulerAccount:
         self.phase_n = dict.fromkeys(SCHED_PHASES, 0)
         self.head_blocked_s = dict.fromkeys(HEAD_BLOCKED_CAUSES, 0.0)
         self.kv_live_token_steps = 0
+        self.kv_blocks_attended = 0
+        self.kv_blocks_spanned = 0
         #: how many of the longest non-idle iterations are kept
         self.keep_slowest = 8
         self._slowest: List[tuple] = []      # min-heap on seconds
@@ -177,6 +179,7 @@ class SchedulerAccount:
         self._it_s: Dict[str, float] = {}
         self._it_n: Dict[str, int] = {}
         self._it_kv = 0
+        self._it_attended = self._it_spanned = 0
         self._it_blocked: Dict[str, float] = {}
         self._blocked = None                 # (cause, since) or None
 
@@ -231,6 +234,16 @@ class SchedulerAccount:
         tokens it ran over (memory in use, integrated over steps)."""
         self._it_kv += int(kv_tokens_live)
 
+    def step_dispatched(self, blocks_attended: int,
+                        blocks_spanned: int) -> None:
+        """One paged decode step is dispatched: the pool blocks its
+        attention has to read (every live lane's ``ceil(length /
+        block_size)``) and the block-table entries it spans (slots x
+        table width). Their ratio is how far a length-bounded kernel
+        undercuts one that walks the table."""
+        self._it_attended += int(blocks_attended)
+        self._it_spanned += int(blocks_spanned)
+
     def tick(self, step: int) -> None:
         """End of one iteration of the loop, start of the next."""
         self._charge(self._clock())
@@ -246,6 +259,8 @@ class SchedulerAccount:
             for k, v in self._it_blocked.items():
                 self.head_blocked_s[k] += v
             self.kv_live_token_steps += self._it_kv
+            self.kv_blocks_attended += self._it_attended
+            self.kv_blocks_spanned += self._it_spanned
             if not it_s.get("idle") and self.keep_slowest > 0:
                 entry = (total, t0, int(step), it_s)
                 if len(self._slowest) < self.keep_slowest:
@@ -255,6 +270,7 @@ class SchedulerAccount:
         self._t_iter = self.t
         self._it_s, self._it_n, self._it_blocked = {}, {}, {}
         self._it_kv = 0
+        self._it_attended = self._it_spanned = 0
 
     # -- readers ---------------------------------------------------------
     def busy_and_blocked(self):
@@ -275,6 +291,8 @@ class SchedulerAccount:
                 "phase_n": dict(self.phase_n),
                 "head_blocked_s": dict(self.head_blocked_s),
                 "kv_live_token_steps": self.kv_live_token_steps,
+                "kv_blocks_attended": self.kv_blocks_attended,
+                "kv_blocks_spanned": self.kv_blocks_spanned,
                 # [t_start (perf_counter), seconds, decode step
                 # ordinal, {phase: seconds}], longest first
                 "slowest": [[t0, s, step, dict(ph)]
@@ -608,7 +626,8 @@ _PROM_COUNTERS = frozenset({
     "compiles", "hits", "misses", "evictions",
     "client_disconnects",
     # the scheduler's time account and the HTTP tier's stream writes
-    "iterations", "kv_live_token_steps", "chunks",
+    "iterations", "kv_live_token_steps", "kv_blocks_attended",
+    "kv_blocks_spanned", "chunks",
     # fleet-side counters
     "routed", "hedges", "hedges_won", "hedge_budget_denied",
     "requests_lost", "ejections", "readmissions", "restarts",

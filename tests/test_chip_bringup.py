@@ -21,15 +21,18 @@ from deeplearning4j_tpu.kernels.decode_attention import \
     decode_attention_pallas
 from deeplearning4j_tpu.kernels.flash_attention import flash_attention
 from deeplearning4j_tpu.kernels.kv_quant import QuantArray
-from deeplearning4j_tpu.kernels.paged_attention import \
-    paged_attention_pallas
+from deeplearning4j_tpu.kernels.paged_attention import (
+    KERNEL_NAME, paged_attention_pallas)
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
-# S slots, H heads, D head_dim, Bs block_size, T cache capacity
+# S slots, H heads, D head_dim, Bs block_size, T cache capacity, N pool
+# blocks (a table's worth for every slot and the null block, if not given)
 SHAPES = {
     "served": dict(S=8, H=12, D=64, Bs=16, T=1024),   # GPT-2-small
     "toy": dict(S=4, H=4, D=16, Bs=8, T=192),         # bench.py's LM
+    # the benchmark's cell, gpt2-xl.decode_backlog
+    "cell": dict(S=16, H=25, D=64, Bs=16, T=1024, N=321),
 }
 
 
@@ -65,12 +68,17 @@ def _kv(shape, dt):
     return sds(shape, {"f32": jnp.float32, "bf16": jnp.bfloat16}[dt])
 
 
+def _custom_calls(text):
+    """The instruction names of a compiled program's Mosaic kernels."""
+    return [ln.replace("ROOT ", "").split()[0].lstrip("%").split(".")[0]
+            for ln in text.splitlines() if "tpu_custom_call" in ln]
+
+
 def test_paged_kernels_custom_call_is_named_after_the_kernel(v5e):
     """A device trace names an operation by its HLO instruction: the
     paged decode kernel's has to carry the kernel's own name, not the
     enclosing jit's (the ledger's ``breakdown.device_ops``; the
     benchmark's kernel metrics take any named custom call)."""
-    from deeplearning4j_tpu.kernels.paged_attention import KERNEL_NAME
     S, H, D, Bs, T = (SHAPES["served"][k] for k in ("S", "H", "D", "Bs", "T"))
     sds = jax.ShapeDtypeStruct
     pool = sds((S * (T // Bs) + 1, H, Bs, D), jnp.float32)
@@ -79,9 +87,7 @@ def test_paged_kernels_custom_call_is_named_after_the_kernel(v5e):
         return paged_attention_pallas(q, k, v, t, l, interpret=False)
     text = v5e(step, sds((S, H, D), jnp.float32), pool, pool,
                sds((S, T // Bs), jnp.int32), sds((S,), jnp.int32))
-    calls = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
-    assert len(calls) == 1
-    assert calls[0].lstrip().startswith(f"%{KERNEL_NAME}"), calls[0][:120]
+    assert _custom_calls(text) == [KERNEL_NAME]
 
 
 @pytest.mark.parametrize("dt", ["f32", "bf16", "int8"])
@@ -91,10 +97,12 @@ def test_decode_kernels_compile_for_v5e(v5e, size, dt):
     B = T // Bs
     sds = jax.ShapeDtypeStruct
     q, lens = sds((S, H, D), jnp.float32), sds((S,), jnp.int32)
-    pool = _kv((S * B + 1, H, Bs, D), dt)
-    v5e(lambda q, k, v, t, l: paged_attention_pallas(
+    pool = _kv((SHAPES[size].get("N", S * B + 1), H, Bs, D), dt)
+    text = v5e(lambda q, k, v, t, l: paged_attention_pallas(
         q, k, v, t, l, interpret=False),
         q, pool, pool, sds((S, B), jnp.int32), lens)
+    # one kernel for the whole call, under the name the trace reads
+    assert _custom_calls(text) == [KERNEL_NAME]
     cache = _kv((S, H, T, D), dt)
     v5e(lambda q, k, v, l: decode_attention_pallas(
         q, k, v, l, interpret=False), q, cache, cache, lens)

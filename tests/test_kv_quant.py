@@ -283,6 +283,24 @@ class TestPagedKernelQuant:
             np.testing.assert_allclose(base, poisoned, rtol=1e-5,
                                        atol=1e-6)
 
+    @pytest.mark.parametrize("H,Bs,G", [(4, 4, 2), (4, 8, 2), (25, 16, 4)])
+    @pytest.mark.parametrize("dt", ["bf16", "int8"])
+    def test_every_grid_edge_matches_xla_quantized(self, monkeypatch, dt,
+                                                   H, Bs, G):
+        """Lengths on every block and chunk edge, tables out of pool
+        order, NaN in every dead place (in an int8 pool the poison is
+        carried by the scales)."""
+        from test_paged_generation import chunks_of, ragged_paged_case
+        chunks_of(monkeypatch, G)
+        q, kp, vp, tables, lens = ragged_paged_case(H, Bs, 8, G, seed=3)
+        kq, vq = _quant_cache(kp, dt), _quant_cache(vp, dt)
+        a = np.asarray(paged_attention_xla(q, kq, vq, tables, lens))
+        b = np.asarray(paged_attention_pallas(q, kq, vq, tables, lens,
+                                              interpret=True))
+        assert np.isfinite(b).all()
+        assert np.abs(b[0]).max() == 0.0            # the empty lane
+        np.testing.assert_allclose(a, b, rtol=2e-2, atol=2e-2)
+
     def test_mixed_quant_raises(self):
         q, kp, vp, tables, lens = self._inputs()
         with pytest.raises(ValueError, match="quantized together"):

@@ -7,6 +7,7 @@ identity with the slot backend over a 32-request mixed-length workload
 >=2x concurrency at equal pool bytes, block admission control, zero
 post-warmup recompiles, the no-zeroing-on-reuse invariant, and the
 paged stats surface."""
+import importlib
 import threading
 import time
 
@@ -46,6 +47,47 @@ def _ref_greedy(lm, prompt, n):
         out.append(t)
         toks.append(t)
     return out
+
+
+def ragged_paged_case(H, Bs, D, G, seed=0):
+    """Inputs that put every edge of the paged kernel's grid in one
+    call, for chunks of ``G`` blocks of ``Bs`` keys: lengths 0, 1, one
+    short of / at / one past a block edge and a chunk edge, two whole
+    chunks, and the full table (``2 G + 1`` blocks wide, so the last
+    chunk is ragged); tables that are a random permutation of the pool,
+    not in pool order; and NaN wherever no key lives: in a pool block
+    no table names, in the block every table entry past the length
+    names, and in the tail of each lane's last live block. Returns
+    (q, k_pool, v_pool, tables, lengths), the pools f32."""
+    B = 2 * G + 1
+    lens = sorted({0, 1, Bs - 1, Bs, Bs + 1, G * Bs - 1, G * Bs,
+                   G * Bs + 1, 2 * G * Bs, B * Bs})
+    S = len(lens)
+    N = S * B + 3                   # + null block, 2 poisoned blocks
+    rs = np.random.RandomState(seed)
+    ks = jax.random.split(jax.random.PRNGKey(seed), 3)
+    q = jax.random.normal(ks[0], (S, H, D))
+    kp = np.array(jax.random.normal(ks[1], (N, H, Bs, D)))
+    vp = np.array(jax.random.normal(ks[2], (N, H, Bs, D)))
+    unnamed, past = N - 1, N - 2
+    tables = rs.permutation(np.arange(1, N - 2)).reshape(S, B)
+    for pool in (kp, vp):
+        pool[[unnamed, past]] = np.nan
+        for s, n in enumerate(lens):
+            if n % Bs:
+                pool[tables[s, n // Bs], :, n % Bs:] = np.nan
+    for s, n in enumerate(lens):
+        tables[s, -(-n // Bs):] = past
+    return (q, jnp.asarray(kp), jnp.asarray(vp),
+            jnp.asarray(tables, jnp.int32), jnp.asarray(lens, jnp.int32))
+
+
+def chunks_of(monkeypatch, G):
+    """Make the paged kernel walk its table ``G`` blocks a grid step,
+    as a larger shape's VMEM budget would."""
+    mod = importlib.import_module(
+        "deeplearning4j_tpu.kernels.paged_attention")
+    monkeypatch.setattr(mod, "blocks_per_chunk", lambda *a: G)
 
 
 @pytest.fixture(scope="module")
@@ -189,6 +231,32 @@ class TestPagedAttentionKernel:
                 poisoned = np.asarray(impl(q, kp2, vp2, tbl, lens))
                 np.testing.assert_allclose(base[0], poisoned[0],
                                            rtol=1e-6)
+
+    @pytest.mark.parametrize("H,Bs,D,G", [
+        (4, 4, 8, 2), (4, 8, 8, 2), (4, 16, 8, 4),
+        (25, 4, 8, 2), (25, 8, 8, 4), (25, 16, 64, 8)])
+    def test_every_grid_edge_matches_xla_and_nan_stays_dark(
+            self, monkeypatch, H, Bs, D, G):
+        chunks_of(monkeypatch, G)
+        q, kp, vp, tbl, lens = ragged_paged_case(H, Bs, D, G)
+        a = np.asarray(paged_attention_xla(q, kp, vp, tbl, lens))
+        b = np.asarray(paged_attention_pallas(q, kp, vp, tbl, lens,
+                                              interpret=True))
+        assert np.isfinite(b).all()
+        assert np.abs(b[0]).max() == 0.0            # the empty lane
+        np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-5)
+
+    @pytest.mark.parametrize("shape,itemsize,B,want", [
+        ((25, 16, 64), 4, 64, 4),       # the benchmark's cell
+        ((25, 16, 64), 2, 64, 8), ((25, 16, 64), 1, 64, 8),
+        ((12, 16, 64), 4, 64, 8),       # chip_smoke's GPT-2 small
+        ((4, 8, 16), 4, 24, 16),        # no more than the table holds
+        ((4, 4, 8), 4, 1, 1), ((64, 128, 128), 4, 64, 1)])
+    def test_blocks_per_chunk_follows_the_vmem_budget(self, shape,
+                                                      itemsize, B, want):
+        from deeplearning4j_tpu.kernels.paged_attention import \
+            blocks_per_chunk
+        assert blocks_per_chunk(*shape, itemsize, B) == want
 
 
 # ---------------------------------------------------------------------------

@@ -167,7 +167,61 @@ def test_head_blocked_charges_the_time_until_the_next_pass():
                                                  "slots": 2.0}
 
 
+def test_blocks_attended_and_spanned_commit_with_the_iteration():
+    acct = SchedulerAccount(clock=_Clock())
+    acct.start()
+    acct.step_dispatched(7, 64)
+    acct.step_dispatched(9, 64)
+    s = acct.snapshot()
+    assert s["kv_blocks_attended"] == s["kv_blocks_spanned"] == 0
+    acct.tick(2)
+    s = acct.snapshot()
+    assert (s["kv_blocks_attended"], s["kv_blocks_spanned"]) == (16, 128)
+
+
 # -- the engine's loop -----------------------------------------------------
+@pytest.mark.parametrize("pipeline", [True, False],
+                         ids=["pipelined", "synchronous"])
+def test_blocks_attended_and_spanned_follow_the_dispatched_lengths(
+        lm, pipeline):
+    """One request at a time, so every step's lengths are known: a
+    prompt of P tokens decodes at lengths P + 1, P + 2, ... (the first
+    token comes from the prefill). Block 8, 4 slots, a table 4 wide."""
+    eng = _engine(lm, decode_pipeline=pipeline)
+    S, B, Bs = 4, 32 // 8, 8
+    want = steps = 0
+    try:
+        for P, M in ((5, 6), (8, 9), (15, 2), (1, 12)):
+            before = eng.stats()["decode_steps"]
+            out = eng.generate(list(range(1, P + 1)), max_tokens=M,
+                               temperature=0.0)
+            assert len(out["tokens"]) == M
+            s = _settled(eng)
+            n = s["decode_steps"] - before      # steps this request ran
+            assert M - 1 <= n <= M              # the pipeline may run one on
+            want += sum(-(-(P + 1 + k) // Bs) for k in range(n))
+            steps += n
+    finally:
+        eng.stop()
+    sc = s["scheduler"]
+    assert sc["phase_n"]["decode_dispatch"] == steps
+    assert sc["kv_blocks_spanned"] == steps * S * B
+    assert sc["kv_blocks_attended"] == want
+
+
+def test_slot_backend_spans_and_attends_no_pool_block(lm):
+    eng = GenerationEngine(lm, num_slots=4, max_queue=64,
+                           min_prompt_bucket=4, cache="slots")
+    eng.warmup()
+    try:
+        _burst(eng, n=2, max_tokens=4)
+        sc = _settled(eng)["scheduler"]
+    finally:
+        eng.stop()
+    assert sc["phase_n"]["decode_dispatch"] > 0
+    assert sc["kv_blocks_attended"] == sc["kv_blocks_spanned"] == 0
+
+
 def test_account_partitions_the_loop_of_a_mixed_run(lm):
     eng = _engine(lm)
     try:
@@ -387,6 +441,8 @@ def test_stream_write_is_counted_from_the_emit_stamp(lm, backend):
                     "dl4j_model_scheduler_phase_s_decode_wait",
                     "dl4j_model_scheduler_head_blocked_s_blocks",
                     "dl4j_model_scheduler_kv_live_token_steps_total",
+                    "dl4j_model_scheduler_kv_blocks_attended_total",
+                    "dl4j_model_scheduler_kv_blocks_spanned_total",
                     "dl4j_model_stream_chunks_total",
                     "dl4j_model_stream_delay_s"):
             assert re.search(rf"^{fam}\{{", text, re.M), fam

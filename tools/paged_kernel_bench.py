@@ -1,0 +1,127 @@
+#!/usr/bin/env python3
+"""The paged decode kernel alone, on the chip, at the benchmark cell's
+shape (S 16, H 25, D 64, Bs 16, a table of 64, a pool of 321 blocks):
+48 calls in one program, which is what one decode step of GPT-2 XL
+makes, over three sets of lengths.
+
+    chiprun -- python3 tools/paged_kernel_bench.py [--kv f32|bf16|int8]
+        [--budgets-mb 2,4,8,16]
+
+Prints, for XLA's gather path and for the Pallas kernel at each VMEM
+budget (so at each ``G``, blocks a grid step), the milliseconds per 48
+calls and the largest error against the XLA path at ``highest``
+precision, and writes them to ``chiprun_out/paged_kernel_bench_<kv>.json``.
+``ragged`` is 15 lanes of 122-640 keys and a free lane (4,400 live
+keys), ``full`` 16 lanes of 320 (the pool holds no more), ``ones``
+every lane at length 1: the walk over the table and nothing else.
+A scratch tool of PR 27 (PERF.md sections 5 and 6: how ``G`` was
+chosen), kept for the PRs that change the pool's layout. It measures
+nothing off a TPU.
+"""
+from __future__ import annotations
+
+import argparse
+import importlib
+import json
+import os
+import sys
+import time
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
+
+S, H, D, BS, B, N, LAYERS = 16, 25, 64, 16, 64, 321, 48
+RAGGED = [301, 122, 275, 155, 179, 314, 378, 545, 169, 363, 268, 187, 274,
+          229, 1, 640]
+CASES = {"ragged": RAGGED, "full": [320] * S, "ones": [1] * S}
+
+
+def main(argv=None) -> int:
+    ap = argparse.ArgumentParser()
+    ap.add_argument("--kv", choices=("f32", "bf16", "int8"), default="f32")
+    ap.add_argument("--budgets-mb", default="2,4,8,16")
+    a = ap.parse_args(argv)
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from jax import lax
+    if jax.devices()[0].platform != "tpu":
+        print("paged_kernel_bench: JAX found no TPU; a time from anything "
+              "else is not a device number", file=sys.stderr)
+        return 2
+    from deeplearning4j_tpu.kernels.kv_quant import quantize_rows
+    pa = importlib.import_module(
+        "deeplearning4j_tpu.kernels.paged_attention")
+
+    ks = jax.random.split(jax.random.PRNGKey(0), 3)
+    q = jax.random.normal(ks[0], (S, H, D), jnp.float32)
+    cast = {"f32": lambda x: x, "bf16": lambda x: x.astype(jnp.bfloat16),
+            "int8": quantize_rows}[a.kv]
+    kp = cast(jax.random.normal(ks[1], (N, H, BS, D), jnp.float32))
+    vp = cast(jax.random.normal(ks[2], (N, H, BS, D), jnp.float32))
+    itemsize = {"f32": 4, "bf16": 2, "int8": 1}[a.kv]
+    rs = np.random.RandomState(0)
+
+    def tables(lens):
+        """Every live lane owns a scattered run of pool blocks."""
+        tbl = np.zeros((S, B), np.int32)
+        free = list(rs.permutation(np.arange(1, N)))
+        for s, n in enumerate(lens):
+            for i in range(-(-n // BS) if n > 1 else 0):
+                tbl[s, i] = free.pop()
+        return jnp.asarray(tbl), jnp.asarray(lens, jnp.int32)
+
+    def step_of(fn):
+        """48 calls in one program, each fed by the one before."""
+        def run(q, kp, vp, tbl, lens):
+            return lax.fori_loop(
+                0, LAYERS,
+                lambda i, x: q + 1e-3 * fn(x, kp, vp, tbl, lens), q)
+        return jax.jit(run)
+
+    def ms(f, *args, n=10):
+        f(*args).block_until_ready()
+        t = time.perf_counter()
+        for _ in range(n):
+            out = f(*args)
+        out.block_until_ready()
+        return (time.perf_counter() - t) / n * 1e3
+
+    variants = {"xla": pa.paged_attention_xla}
+    budget = pa._VMEM_BLOCK_BUDGET
+    for mb in (int(x) for x in a.budgets_mb.split(",")):
+        def kernel(*args, mb=mb):
+            pa._VMEM_BLOCK_BUDGET = mb << 20      # read while tracing
+            try:
+                return pa.paged_attention_pallas(*args)
+            finally:
+                pa._VMEM_BLOCK_BUDGET = budget
+        pa._VMEM_BLOCK_BUDGET = mb << 20
+        g = pa.blocks_per_chunk(H, BS, D, itemsize, B)
+        pa._VMEM_BLOCK_BUDGET = budget
+        variants[f"pallas_G{g}"] = kernel
+    res = {}
+    for case, lens in CASES.items():
+        tbl, ln = tables(lens)
+        with jax.default_matmul_precision("highest"):
+            ref = jax.jit(pa.paged_attention_xla)(q, kp, vp, tbl, ln)
+        for name, fn in variants.items():
+            err = float(jnp.max(jnp.abs(
+                jax.jit(fn)(q, kp, vp, tbl, ln) - ref)))
+            t = ms(step_of(fn), q, kp, vp, tbl, ln)
+            res[f"{case}.{name}"] = {"ms_per_48_calls": t, "max_err": err}
+            print(f"{case:7s} {name:11s} {t:9.3f} ms / 48 calls   "
+                  f"err {err:.2e}", flush=True)
+    live = sum(RAGGED)
+    print(f"ragged: {live} live keys, {live * 2 * H * D * 4 * LAYERS} f32 "
+          f"bytes a step")
+    os.makedirs(os.path.join(ROOT, "chiprun_out"), exist_ok=True)
+    with open(os.path.join(ROOT, "chiprun_out",
+                           f"paged_kernel_bench_{a.kv}.json"), "w") as f:
+        json.dump(res, f, indent=1)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())
