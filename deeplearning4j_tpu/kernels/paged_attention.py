@@ -82,14 +82,24 @@ def gather_blocks(pool, block_tables):
 def paged_attention_xla(q, k_pool, v_pool, block_tables, lengths):
     """Fused-XLA paged decode attention (CPU/GPU and reference path).
 
-    q: [S, H, D]; k_pool/v_pool: [N, H, Bs, D]; block_tables: [S, B];
-    lengths: [S] — positions >= lengths[s] (stale block tails, padded
-    table entries) are masked out. Shapes depend only on (S, B, Bs),
-    never on live lengths or which blocks a request owns.
+    q: [S, H_q, D]; k_pool/v_pool: [N, H_kv, Bs, D] with
+    ``H_q = g * H_kv`` (query head i reads KV head i // g);
+    block_tables: [S, B]; lengths: [S] — positions >= lengths[s] (stale
+    block tails, padded table entries) are masked out. Shapes depend
+    only on (S, B, Bs), never on live lengths or which blocks a request
+    owns.
     """
-    return decode_attention_xla(q, gather_blocks(k_pool, block_tables),
-                                gather_blocks(v_pool, block_tables),
-                                lengths)
+    k = gather_blocks(k_pool, block_tables)
+    v = gather_blocks(v_pool, block_tables)
+    S, Hq, D = q.shape
+    Hkv = k.shape[1]
+    if Hq == Hkv:
+        return decode_attention_xla(q, k, v, lengths)
+    # grouped-query heads: query head i reads KV head i // g. The g
+    # members of a group are mapped over one gathered panel
+    out = jax.vmap(lambda qg: decode_attention_xla(qg, k, v, lengths),
+                   in_axes=2, out_axes=2)(q.reshape(S, Hkv, Hq // Hkv, D))
+    return out.reshape(S, Hq, D)
 
 
 # ---------------------------------------------------------------------------
@@ -101,23 +111,29 @@ def paged_attention_xla(q, k_pool, v_pool, block_tables, lengths):
 #: 2 blocks a chunk, 8.2 at 4, 8.6 at 8 and 10.5 at 16 (PERF.md, PR 27):
 #: a larger chunk saves grid steps and wastes more of its last tile
 _VMEM_BLOCK_BUDGET = 4 << 20
+#: and no more than this many, however small a block is: the body is
+#: unrolled over a chunk's blocks (and the members of a query group).
+#: Swept where it binds, on a v5e at 32 query heads over H 8, Bs 16,
+#: D 64, bf16, 3 calls over 4,400 live keys: 1.15 ms at 2 blocks a
+#: chunk, 0.87 at 4, 0.79 at 8, 0.80 at 16 (PERF.md, PR 30)
+_MAX_BLOCKS = 8
 
 
 def blocks_per_chunk(H: int, Bs: int, D: int, itemsize: int, B: int) -> int:
     """Pool blocks one chunk of the kernel attends (``G``): the largest
     power of two whose K and V double buffers fit the VMEM budget, as
-    Mosaic tiles a ``[H, Bs, D]`` block there (rows padded to the
-    sublane tile of the item size, ``D`` to 128 lanes), and no more
-    than the table holds."""
+    Mosaic tiles a ``[H, Bs, D]`` block there (``H`` the KV heads; rows
+    padded to the sublane tile of the item size, ``D`` to 128 lanes),
+    and no more than the table holds or :data:`_MAX_BLOCKS`."""
     sublanes = 8 * 4 // itemsize
     block = H * _cdiv(Bs, sublanes) * sublanes * _cdiv(D, 128) * 128 \
         * itemsize
-    g = max(1, min(_VMEM_BLOCK_BUDGET // (4 * block), B))
+    g = max(1, min(_VMEM_BLOCK_BUDGET // (4 * block), B, _MAX_BLOCKS))
     return 1 << (g.bit_length() - 1)
 
 
 def _paged_kernel(tbl_ref, len_ref, q_ref, *refs, quant: bool, G: int,
-                  scale: float):
+                  scale: float, g: int = 1):
     """One grid step (slot ``s``, chunk ``c``) of paged decode
     attention: every head of the slot against the ``G`` pool blocks of
     table entries ``c * G .. c * G + G - 1``.
@@ -135,7 +151,13 @@ def _paged_kernel(tbl_ref, len_ref, q_ref, *refs, quant: bool, G: int,
     already hold). Inside the last live chunk, what a block holds past
     the length (a stale tail, or another position's keys where the
     index map repeated a block) is masked by position with ``where``,
-    never multiplied away: it may be NaN."""
+    never multiplied away: it may be NaN.
+
+    Grouped-query heads (``g`` query heads to a KV head, ``H`` the KV
+    heads): q, o and the scratch hold ``g * H`` rows, member ``j`` of
+    every group in rows ``j * H .. (j + 1) * H`` (the wrapper lays them
+    out so), and each member multiplies the same K and V tiles, loaded
+    once. With ``g == 1`` this is the body it was."""
     k_refs, v_refs = refs[:G], refs[G:2 * G]
     ks_refs, vs_refs = (refs[2 * G:3 * G], refs[3 * G:4 * G]) if quant \
         else (None, None)
@@ -152,35 +174,47 @@ def _paged_kernel(tbl_ref, len_ref, q_ref, *refs, quant: bool, G: int,
 
     @pl.when(c * (G * Bs) < length)
     def _chunk():
-        q = q_ref[...].astype(jnp.float32)[:, None, :] * scale  # [H,1,D]
-        first = [(c * G + g) * Bs for g in range(G)]
+        first = [(c * G + b) * Bs for b in range(G)]
         lane = lax.broadcasted_iota(jnp.int32, (H, Bs), 1)
         mask = [p0 + lane < length for p0 in first]
-        sc = []
-        for g in range(G):
-            x = jnp.sum(k_refs[g][...].astype(jnp.float32) * q, axis=-1)
-            if quant:
-                x = x * ks_refs[g][...]                   # K dequant
-            sc.append(jnp.where(mask[g], x, _NEG_INF))    # [H, Bs]
-        m_prev = m_s[...]
-        m_new = jnp.maximum(m_prev, functools.reduce(
-            jnp.maximum, sc).max(axis=-1, keepdims=True))
-        corr = jnp.exp(m_prev - m_new)
-        l_new, acc = l_s[...] * corr, acc_s[...] * corr
         row = lax.broadcasted_iota(jnp.int32, (H, Bs, 1), 1)
-        for g in range(G):
-            # where-guard keeps fully-masked rows at p=0 (exp(-inf -
-            # -inf) = 1 would fabricate uniform attention)
-            p = jnp.where(mask[g], jnp.exp(sc[g] - m_new), 0.0)
-            l_new = l_new + p.sum(axis=-1, keepdims=True)
-            if quant:
-                # V dequant folds into p; a stale scale may be NaN
-                p = jnp.where(mask[g], p * vs_refs[g][...], 0.0)
+
+        def k_tile(b):
+            return k_refs[b][...].astype(jnp.float32)
+
+        def v_tile(b):
             # zero masked V rows: 0 * NaN = NaN would leak a stale tail
-            v = jnp.where(first[g] + row < length,
-                          v_refs[g][...].astype(jnp.float32), 0.0)
-            acc = acc + jnp.sum(p[:, :, None] * v, axis=1)
-        m_s[...], l_s[...], acc_s[...] = m_new, l_new, acc
+            return jnp.where(first[b] + row < length,
+                             v_refs[b][...].astype(jnp.float32), 0.0)
+
+        if g > 1:       # the members of a group share the tiles
+            kf, vf = ([k_tile(b) for b in range(G)],
+                      [v_tile(b) for b in range(G)])
+            k_tile, v_tile = kf.__getitem__, vf.__getitem__
+        for j in range(g):
+            rows = slice(None) if g == 1 else pl.ds(j * H, H)
+            q = q_ref[rows, :].astype(jnp.float32)[:, None, :] * scale
+            sc = []
+            for b in range(G):
+                x = jnp.sum(k_tile(b) * q, axis=-1)
+                if quant:
+                    x = x * ks_refs[b][...]                   # K dequant
+                sc.append(jnp.where(mask[b], x, _NEG_INF))    # [H, Bs]
+            m_prev = m_s[rows, :]
+            m_new = jnp.maximum(m_prev, functools.reduce(
+                jnp.maximum, sc).max(axis=-1, keepdims=True))
+            corr = jnp.exp(m_prev - m_new)
+            l_new, acc = l_s[rows, :] * corr, acc_s[rows, :] * corr
+            for b in range(G):
+                # where-guard keeps fully-masked rows at p=0 (exp(-inf
+                # - -inf) = 1 would fabricate uniform attention)
+                p = jnp.where(mask[b], jnp.exp(sc[b] - m_new), 0.0)
+                l_new = l_new + p.sum(axis=-1, keepdims=True)
+                if quant:
+                    # V dequant folds into p; a stale scale may be NaN
+                    p = jnp.where(mask[b], p * vs_refs[b][...], 0.0)
+                acc = acc + jnp.sum(p[:, :, None] * v_tile(b), axis=1)
+            m_s[rows, :], l_s[rows, :], acc_s[rows, :] = m_new, l_new, acc
 
     @pl.when(c == pl.num_programs(1) - 1)
     def _finalize():
@@ -192,7 +226,9 @@ def _paged_kernel(tbl_ref, len_ref, q_ref, *refs, quant: bool, G: int,
 def paged_attention_pallas(q, k_pool, v_pool, block_tables, lengths,
                            interpret: Optional[bool] = None):
     """Pallas paged decode attention. Same contract as
-    :func:`paged_attention_xla`. Grid ``(S, ceil(B / G))``: one grid
+    :func:`paged_attention_xla` (grouped-query heads included: the
+    query heads of a group are more rows against the same K tile).
+    Grid ``(S, ceil(B / G))``: one grid
     step attends all heads of a slot over ``G`` table entries
     (:func:`blocks_per_chunk`). A pool enters as ``G`` operands, each
     one whole block ``[H, Bs, D]`` (contiguous in the pool) that the
@@ -210,9 +246,14 @@ def paged_attention_pallas(q, k_pool, v_pool, block_tables, lengths,
     S, H, D = q.shape
     pools = [k_pool.q, v_pool.q, k_pool.scale, v_pool.scale] if quant \
         else [k_pool, v_pool]
-    Bs = pools[0].shape[2]
+    Hkv, Bs = pools[0].shape[1:3]
+    g = H // Hkv
+    if g * Hkv != H:
+        raise ValueError(f"{H} query heads over {Hkv} KV heads")
+    if g > 1:       # member j of every group in rows j * Hkv ..
+        q = q.reshape(S, Hkv, g, D).swapaxes(1, 2).reshape(S, H, D)
     B = block_tables.shape[1]
-    G = blocks_per_chunk(H, Bs, D, pools[0].dtype.itemsize, B)
+    G = blocks_per_chunk(Hkv, Bs, D, pools[0].dtype.itemsize, B)
     C = _cdiv(B, G)
     # The table entry each of a chunk's G operands fetches, [S, C * G]:
     # its own (c * G + g) while that is live, then the last live one
@@ -237,9 +278,9 @@ def paged_attention_pallas(q, k_pool, v_pool, block_tables, lengths,
         in_specs += [pl.BlockSpec((None,) + pool.shape[1:],
                                   entry(g, (0,) * (pool.ndim - 1)))
                      for g in range(G)]
-    return pl.pallas_call(
+    out = pl.pallas_call(
         functools.partial(_paged_kernel, quant=quant, G=G,
-                          scale=1.0 / (D ** 0.5)),
+                          scale=1.0 / (D ** 0.5), g=g),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,          # fetched, lengths
             grid=(S, C),
@@ -253,6 +294,9 @@ def paged_attention_pallas(q, k_pool, v_pool, block_tables, lengths,
         # device trace (else it is named after the enclosing jit)
         name=KERNEL_NAME,
     )(fetched, lengths, *operands)
+    if g > 1:
+        out = out.reshape(S, g, Hkv, D).swapaxes(1, 2).reshape(S, H, D)
+    return out
 
 
 def paged_attention(q, k_pool, v_pool, block_tables, lengths,

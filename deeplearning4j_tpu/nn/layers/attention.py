@@ -56,7 +56,8 @@ def _span_attend(q, kk, vv, gpos, p0c, out_dtype):
     and :meth:`SelfAttentionLayer.apply_prefill_paged` (block-table
     gather).
 
-    q: [C, H, Dh] span queries; kk/vv: [H, T, Dh] panels — plain f32
+    q: [C, H_q, Dh] span queries (H_q a multiple of H: grouped-query
+    heads); kk/vv: [H, T, Dh] panels — plain f32
     (bit-identical to the pre-quantization math), bf16, or int8
     QuantArrays with [H, T] scales; gpos: [C] global positions (row c
     sees keys j <= gpos[c]); p0c: scalar — first position NOT written
@@ -68,6 +69,14 @@ def _span_attend(q, kk, vv, gpos, p0c, out_dtype):
     (kernels/decode_attention.py), checkable in StableHLO
     (tools/perf_audit.py::audit_kv_quant)."""
     H, T, Dh = kk.shape
+    C, Hq = q.shape[:2]
+    if Hq != H:
+        # grouped-query heads (query head i reads KV head i // g): the
+        # g members of a group are mapped over the one gathered panel
+        out = jax.vmap(
+            lambda qg: _span_attend(qg, kk, vv, gpos, p0c, out_dtype),
+            in_axes=2, out_axes=2)(q.reshape(C, H, Hq // H, Dh))
+        return out.reshape(C, Hq, Dh)
     scale = 1.0 / jnp.sqrt(jnp.float32(Dh))
     valid = jnp.arange(T)[None, None, :] <= gpos[None, :, None]
     written = (jnp.arange(T) < p0c)[None, :, None]

@@ -474,6 +474,35 @@ class GenerationEngine:
             raise ValueError(f"cache must be 'slots' or 'paged', "
                              f"got {cache!r}")
         self.cache_backend = cache
+        # a model whose layers keep state that is not keys and values
+        # DECLARES it (``slot_state_shapes(num_slots)``: arrays with the
+        # slot first) and this engine allocates it beside the pools.
+        # What cannot carry such state is refused, not served wrong
+        # (docs/generation.md, "Models with state a slot")
+        self._state_shapes = list(
+            getattr(model, "slot_state_shapes", lambda n: [])(
+                self.num_slots))
+        self._stateful = bool(self._state_shapes)
+        if self._stateful:
+            if cache != "paged":
+                raise ValueError(
+                    "a model with slot state is served by the paged "
+                    "backend only (cache='paged'): its prefill runs in "
+                    "chunks that hand the state on")
+            if self.speculation_k:
+                raise ValueError(
+                    "speculation_k > 0 is refused for a model with slot "
+                    "state: a rejected draft rolls the KV cursor back, "
+                    "and the slot state has no cursor to roll")
+            if int(offload_host_bytes) > 0:
+                raise ValueError(
+                    "offload_host_bytes > 0 is refused for a model with "
+                    "slot state: a demoted run holds keys and values "
+                    "only, and a restore could not rebuild the state")
+            # a matched prefix skips the chunks that would have built
+            # the state: neither the prefix index nor the session
+            # store is consulted
+            enable_prefix_sharing = False
         if cache == "paged":
             self.block_size = int(block_size)
             if not 1 <= self.block_size <= self.max_seq_len:
@@ -587,6 +616,15 @@ class GenerationEngine:
         self.metrics.quant_scale_bytes = self._cache.scale_nbytes()
         self._kcs = self._cache.ks
         self._vcs = self._cache.vs
+        self._state = self._fresh_state()
+        self.metrics.slot_state_bytes = int(sum(
+            a.nbytes for a in self._state))
+        # a model whose forwards return counters beside their logits
+        # owns what they mean: ``step_account()`` gives the object that
+        # takes each step's and each chunk's vector and shows in /stats
+        self._model_account = getattr(model, "step_account",
+                                      lambda: None)()
+        self.metrics.model_account = self._model_account
         self._slots = SlotTable(self.num_slots)
         # -- speculative decoding state -----------------------------
         self._draft = None
@@ -651,6 +689,10 @@ class GenerationEngine:
         # batching loses its amortization (measured 0.5x vs sequential
         # on CPU with copies; 4x+ with donation)
         self._donate = (1, 2)
+        # the paged programs also take (and donate) the slot state: an
+        # empty list for a model that declares none, which adds nothing
+        # to its programs
+        self._donate_paged = (1, 2, 3)
         # -- pipelined decode (ISSUE 14) ----------------------------
         # With the pipeline on (default; speculation forces it off —
         # verify rounds are inherently synchronous), the scheduler
@@ -737,6 +779,13 @@ class GenerationEngine:
         return KVCache(self.model.cache_shapes(self.max_seq_len),
                        self.num_slots, kv_dtype=self.kv_dtype)
 
+    def _fresh_state(self):
+        """The arrays a model keeps a slot (``slot_state_shapes``),
+        zeroed once here and never again: a request's first chunk
+        starts from zeros whatever its slot held."""
+        return [jnp.zeros(shape, dtype)
+                for shape, dtype in self._state_shapes]
+
     def _update_block_gauges(self):
         """Push allocator + liveness gauges into the metrics object
         (snapshot() reads them lock-free from the stats thread).
@@ -822,17 +871,32 @@ class GenerationEngine:
         impl = self.decode_impl
 
         if self.cache_backend == "paged":
-            def step(params, kcs, vcs, tok_host, tok_dev, use_host,
-                     pos, tables, seeds, steps, temps, top_ks, eos,
-                     max_steps):
+            stateful = self._stateful
+
+            def step(params, kcs, vcs, state, tok_host, tok_dev,
+                     use_host, pos, tables, seeds, steps, temps, top_ks,
+                     eos, max_steps):
                 tokens = jnp.where(use_host, tok_host, tok_dev)
-                logits, kcs, vcs = model.forward_decode_paged(
-                    params, tokens, pos, kcs, vcs, tables, impl)
+                counters = ()
+                if stateful:
+                    # a lane is LIVE when it has blocks and tokens left
+                    # to emit: a mid-prefill slot (its chunks own its
+                    # state) and a lane the pipeline ran once past its
+                    # end write no state and count nowhere
+                    live = (tables[:, 0] != NULL_BLOCK) \
+                        & (steps < max_steps)
+                    logits, kcs, vcs, state, counters = \
+                        model.forward_decode_paged(
+                            params, tokens, pos, kcs, vcs, tables, impl,
+                            state=state, live=live)
+                else:
+                    logits, kcs, vcs = model.forward_decode_paged(
+                        params, tokens, pos, kcs, vcs, tables, impl)
                 ok = jnp.all(jnp.isfinite(logits), axis=-1)  # per lane
                 nxt = _sample_batch(logits, temps, top_ks, seeds, steps)
                 done = ((nxt == eos) & (eos >= 0)) \
                     | (steps + 1 >= max_steps)
-                return nxt, ok, done, kcs, vcs
+                return nxt, ok, done, kcs, vcs, state, counters
             return step
 
         def step(params, kcs, vcs, tok_host, tok_dev, use_host, pos,
@@ -849,10 +913,19 @@ class GenerationEngine:
     def _chunk_fn(self):
         model = self.model
 
-        def chunk(params, kcs, vcs, tokens, p0, chunk_len, table, seed,
-                  temp, top_k):
-            logits, kcs, vcs = model.forward_prefill_chunk(
-                params, tokens, p0, chunk_len, kcs, vcs, table)
+        stateful = self._stateful
+
+        def chunk(params, kcs, vcs, state, tokens, p0, chunk_len, table,
+                  slot, seed, temp, top_k):
+            counters = ()
+            if stateful:
+                logits, kcs, vcs, state, counters = \
+                    model.forward_prefill_chunk(
+                        params, tokens, p0, chunk_len, kcs, vcs, table,
+                        state=state, slot=slot)
+            else:
+                logits, kcs, vcs = model.forward_prefill_chunk(
+                    params, tokens, p0, chunk_len, kcs, vcs, table)
             # guard only rows < chunk_len: padded tail rows attend
             # positions past the live length — stale block junk that
             # is allowed to be anything (no-zeroing invariant)
@@ -865,7 +938,7 @@ class GenerationEngine:
             # sample is bit-identical across backends
             key = jax.random.fold_in(jax.random.PRNGKey(seed), 0)
             first = _sample_one(last, temp, top_k, key)
-            return first, ok, kcs, vcs
+            return first, ok, kcs, vcs, state, counters
         return chunk
 
     def _prefill_fn(self):
@@ -904,6 +977,7 @@ class GenerationEngine:
             S = self.num_slots
             if self.cache_backend == "paged":
                 args = (self.model._params, self._kcs, self._vcs,
+                        self._state,
                         np.zeros(S, np.int32), np.zeros(S, np.int32),
                         np.ones(S, bool), np.zeros(S, np.int32),
                         np.full((S, self._blocks_per_seq), NULL_BLOCK,
@@ -919,8 +993,10 @@ class GenerationEngine:
                         np.zeros(S, np.float32), np.zeros(S, np.int32),
                         np.full(S, -1, np.int32), np.zeros(S, np.int32))
             with self._profiler.record("generation.compile"):
-                exe = compile_memoized(self._decode_fn(), args,
-                                       self._donate)
+                exe = compile_memoized(
+                    self._decode_fn(), args,
+                    self._donate_paged if self.cache_backend == "paged"
+                    else self._donate)
             self.metrics.inc("compiles")
             self._decode_exe = exe
             return exe
@@ -938,13 +1014,15 @@ class GenerationEngine:
             if exe is not None:
                 return exe
             args = (self.model._params, self._kcs, self._vcs,
+                    self._state,
                     np.zeros((1, chunk_bucket), np.int32), np.int32(0),
                     np.int32(1),
                     np.full(tbl_bucket, NULL_BLOCK, np.int32),
-                    np.uint32(0), np.float32(0.0), np.int32(0))
+                    np.int32(0), np.uint32(0), np.float32(0.0),
+                    np.int32(0))
             with self._profiler.record("generation.compile"):
                 exe = compile_memoized(self._chunk_fn(), args,
-                                       self._donate)
+                                       self._donate_paged)
             self.metrics.inc("compiles")
             self._prefill_exe[key] = exe
             return exe
@@ -1428,6 +1506,10 @@ class GenerationEngine:
             if self.cache_backend != "paged":
                 raise ClientError("session_id requires the paged cache "
                                   "backend (cache='paged')")
+            if self._stateful:
+                raise ClientError(
+                    "session_id is refused for a model with slot state: "
+                    "a pinned session holds keys and values only")
             if not self.enable_prefix_sharing:
                 raise ClientError(
                     "session_id requires prefix sharing "
@@ -2228,11 +2310,16 @@ class GenerationEngine:
             self.metrics.active_slots = self._slots.active_count
             self._update_block_gauges()
 
-    def _prefill_chunk_step(self):
-        """Run ONE prefill chunk for the oldest mid-prefill request —
-        the scheduler interleaves these with decode steps, so the
-        decode loop's stall per iteration is bounded by one chunk's
-        compute regardless of prompt length."""
+    def _dispatch_chunk(self):
+        """Launch ONE prefill chunk for the oldest mid-prefill request
+        WITHOUT waiting for its result: the scheduler interleaves
+        chunks with decode steps, so the decode loop's stall per
+        iteration is bounded by one chunk's compute regardless of
+        prompt length, and it dispatches the next decode step behind
+        the chunk before it blocks in :meth:`_collect_chunk`, so the
+        device goes from the chunk to that step with no host in
+        between. Returns what ``_collect_chunk`` needs, or None when
+        nothing was launched."""
         st = self._prefilling[0]
         req = st.req
         sched = self._sched
@@ -2242,14 +2329,14 @@ class GenerationEngine:
             if req.abandoned:
                 self._prefilling.popleft()
                 self._release_slot(st.slot)
-                return
+                return None
             if t0 > req.deadline:
                 self._prefilling.popleft()
                 self._release_slot(st.slot)
                 self._fail(req, DeadlineExceededError(
                     "deadline exceeded during chunked prefill "
                     f"({st.done_tokens}/{len(st.seq)} prompt tokens)"))
-                return
+                return None
             # injection seam: BEFORE any mutation — a TransientFault
             # here leaves the chunk state at the deque head, so the
             # retried iteration re-runs this same chunk
@@ -2266,19 +2353,29 @@ class GenerationEngine:
                 self._prefilling.popleft()
                 self._release_slot(st.slot)
                 self._fail(req, e)
-                return
+                return None
             try:
-                first, okd, self._kcs, self._vcs = exe(
-                    self.model._params, self._kcs, self._vcs, tokens,
-                    np.int32(p0), np.int32(clen), table,
-                    np.uint32(req.seed), np.float32(req.temperature),
-                    np.int32(req.top_k))
+                (first, okd, self._kcs, self._vcs, self._state,
+                 counters) = exe(
+                    self.model._params, self._kcs, self._vcs,
+                    self._state, tokens, np.int32(p0), np.int32(clen),
+                    table, np.int32(st.slot), np.uint32(req.seed),
+                    np.float32(req.temperature), np.int32(req.top_k))
             except Exception as e:  # noqa: BLE001
                 self._chunk_call_failed(st, e)
+        return st, first, okd, counters, n_chunk, bucket, clen, c0, t0
+
+    def _collect_chunk(self, launched):
+        """Wait for a chunk :meth:`_dispatch_chunk` launched and do its
+        host bookkeeping."""
+        st, first, okd, counters, n_chunk, bucket, clen, c0, t0 = launched
+        sched = self._sched
         with sched.phase("chunk_wait", chunk=n_chunk):
             try:
                 first = int(np.asarray(first))  # device sync
                 ok = bool(np.asarray(okd))
+                if self._model_account is not None:
+                    self._model_account.chunk(np.asarray(counters))
             except Exception as e:  # noqa: BLE001
                 self._chunk_call_failed(st, e)
         t1 = sched.t        # the stamp that closed chunk_wait
@@ -2464,6 +2561,7 @@ class GenerationEngine:
         self._cache = self._fresh_cache()
         self._kcs = self._cache.ks
         self._vcs = self._cache.vs
+        self._state = self._fresh_state()   # chunks rebuild it
         if self.speculation_k:
             self._reset_draft_cache()
 
@@ -2512,6 +2610,7 @@ class GenerationEngine:
         self._cache = self._fresh_cache()
         self._kcs = self._cache.ks
         self._vcs = self._cache.vs
+        self._state = self._fresh_state()   # chunks rebuild it
         if self.speculation_k:
             # the draft cache may hold donated-away device state too;
             # it replays nothing — each re-admitted lane re-primes at
@@ -2897,6 +2996,13 @@ class GenerationEngine:
                 int((-(-lengths // self.block_size)).sum()),
                 self.num_slots * self._blocks_per_seq)
 
+    def _account_step_counters(self, counters):
+        """The small integer vector a decode step returns beside its
+        tokens (fetched with them: no round-trip of its own) goes to
+        the model's own account."""
+        if self._model_account is not None:
+            self._model_account.decode_step(np.asarray(counters))
+
     def _decode_step(self, skip=frozenset()):
         """One plain decode step. ``skip`` holds slots a speculative
         round already advanced this iteration: they ride the batch as
@@ -2918,10 +3024,12 @@ class GenerationEngine:
         with sched.phase("decode_dispatch", step=n_step,
                          slots=len(active)) as t0:
             self._account_step_blocks(active)
+            counters = ()
             if self.cache_backend == "paged":
-                nxt, okd, dnd, self._kcs, self._vcs = \
-                    self._get_decode_exe()(
+                (nxt, okd, dnd, self._kcs, self._vcs, self._state,
+                 counters) = self._get_decode_exe()(
                         self.model._params, self._kcs, self._vcs,
+                        self._state,
                         st.token.copy(), self._no_dev_tok,
                         self._all_host, st.pos.copy(),
                         self._tables.copy(), st.seed.copy(),
@@ -2941,6 +3049,7 @@ class GenerationEngine:
             nxt = np.asarray(nxt)  # device sync: the step really ran
             ok = np.asarray(okd)
             done = np.asarray(dnd)
+            self._account_step_counters(counters)
         now = sched.t           # the stamp that closed decode_wait
         self._profiler.note("generation.decode_step", now - t0)
         with sched.phase("emit", step=n_step, slots=len(active)):
@@ -3024,10 +3133,12 @@ class GenerationEngine:
             if tok_dev is None:
                 tok_dev = self._no_dev_tok
             use_host = ~self._tok_on_dev
+            counters = ()
             if self.cache_backend == "paged":
-                nxt, okd, dnd, self._kcs, self._vcs = \
-                    self._get_decode_exe()(
+                (nxt, okd, dnd, self._kcs, self._vcs, self._state,
+                 counters) = self._get_decode_exe()(
                         self.model._params, self._kcs, self._vcs,
+                        self._state,
                         st.token.copy(), tok_dev, use_host,
                         st.pos.copy(), self._tables.copy(),
                         st.seed.copy(), st.step.copy(), st.temp.copy(),
@@ -3051,8 +3162,8 @@ class GenerationEngine:
             self.metrics.inc("decode_steps")
             self.metrics.occupancy_hist.record(len(active))
             self._pending.append(
-                (nxt, okd, dnd, [(s, st.requests[s]) for s in active],
-                 t0, c0, n_step))
+                (nxt, okd, dnd, counters,
+                 [(s, st.requests[s]) for s in active], t0, c0, n_step))
         return True
 
     def _collect_decode(self, keep: int = 0):
@@ -3063,12 +3174,13 @@ class GenerationEngine:
         only blocking point; everything after runs off host arrays."""
         sched = self._sched
         while len(self._pending) > keep:
-            nxt_d, okd, dnd, lanes, t0, c0, n_step = \
+            nxt_d, okd, dnd, counters, lanes, t0, c0, n_step = \
                 self._pending.popleft()
             with sched.phase("decode_wait", step=n_step) as t_wait:
                 nxt = np.asarray(nxt_d)  # device sync: the step ran
                 ok = np.asarray(okd)
                 done = np.asarray(dnd)
+                self._account_step_counters(counters)
             now = sched.t       # the stamp that closed decode_wait
             with sched.phase("emit", step=n_step, slots=len(lanes)):
                 self._apply_collected(lanes, nxt, ok, done, now,
@@ -3140,7 +3252,9 @@ class GenerationEngine:
 
     def _loop(self):
         """The supervised scheduler loop. One iteration = admit, one
-        prefill chunk (paged), one decode step. Failure ladder:
+        prefill chunk (paged) dispatched, one decode step dispatched
+        behind it, the step before collected, the chunk collected.
+        Failure ladder:
 
         - :class:`~.faults.TransientFault` (raised before any
           donation): retry the iteration with bounded exponential
@@ -3170,22 +3284,38 @@ class GenerationEngine:
             try:
                 self._hit("latency")  # injected tail latency (sleeps)
                 self._admit()
-                if paged and self._prefilling:
-                    self._prefill_chunk_step()
+                chunk = self._dispatch_chunk() \
+                    if paged and self._prefilling else None
                 if self.decode_pipeline:
                     # dispatch step t+1 FIRST, then collect step t:
                     # the admit/prefill work above and the emit/retire
                     # work inside the collect all overlap the device
-                    # computing the step just dispatched
-                    launched = self._dispatch_decode()
-                    self._collect_decode(keep=1 if launched else 0)
-                elif self._ready_slots():
-                    # speculative round first (no-op at k=0); lanes it
-                    # advanced sit out the plain step that finishes
-                    # everyone else
-                    spun = (self._spec_step() if self.speculation_k
-                            else frozenset())
-                    self._decode_step(skip=spun)
+                    # computing the step just dispatched. A chunk is
+                    # waited for LAST: step t+1 is queued behind it, so
+                    # the device goes from the chunk to the step with
+                    # no host work in between, and step t (ahead of the
+                    # chunk on the device) hands out its tokens
+                    # meanwhile. The chunk's request joins the decode
+                    # batch at the next dispatch.
+                    try:
+                        launched = self._dispatch_decode()
+                        self._collect_decode(keep=1 if launched else 0)
+                    finally:
+                        # also when the step's seam faulted: a chunk
+                        # that ran must land (its rerun would read the
+                        # slot state it has itself written)
+                        if chunk is not None:
+                            self._collect_chunk(chunk)
+                else:
+                    if chunk is not None:
+                        self._collect_chunk(chunk)
+                    if self._ready_slots():
+                        # speculative round first (no-op at k=0);
+                        # lanes it advanced sit out the plain step
+                        # that finishes everyone else
+                        spun = (self._spec_step() if self.speculation_k
+                                else frozenset())
+                        self._decode_step(skip=spun)
             except TransientFault as e:
                 strikes += 1
                 if strikes > self._max_step_retries:

@@ -108,6 +108,34 @@ def test_decode_kernels_compile_for_v5e(v5e, size, dt):
         q, k, v, l, interpret=False), q, cache, cache, lens)
 
 
+def test_grouped_query_paged_kernel_compiles_for_v5e_at_the_cells_widths(v5e):
+    """``lfm2-8b-a1b.decode_backlog``: 32 query heads over 8 KV heads of
+    64, a bf16 pool of 1,025 blocks of 16, 16 slots."""
+    sds = jax.ShapeDtypeStruct
+    pool = sds((1025, 8, 16, 64), jnp.bfloat16)
+    text = v5e(lambda q, k, v, t, l: paged_attention_pallas(
+        q, k, v, t, l, interpret=False),
+        sds((16, 32, 64), jnp.float32), pool, pool,
+        sds((16, 64), jnp.int32), sds((16,), jnp.int32))
+    assert _custom_calls(text) == [KERNEL_NAME]
+
+
+@pytest.mark.parametrize("pairs", [64, 1024], ids=["decode", "chunk"])
+def test_expert_kernel_compiles_for_v5e_at_the_cells_widths(v5e, pairs):
+    """32 experts of 2048 x 1792, bf16; a decode step's 16 x 4 pairs
+    and a 256-token chunk's 1,024. Both of its calls carry the name
+    the trace reads (``custom-call/moe_experts...``)."""
+    from deeplearning4j_tpu.kernels import moe_experts
+    sds = jax.ShapeDtypeStruct
+    up = sds((32, 2048, 1792), jnp.bfloat16)
+    text = v5e(lambda x, w1, w3, w2, g: moe_experts.expert_ffn(
+        x, w1, w3, w2, g, impl="pallas", interpret=False),
+        sds((pairs, 2048), jnp.bfloat16), up, up,
+        sds((32, 1792, 2048), jnp.bfloat16), sds((32,), jnp.int32))
+    assert sorted(_custom_calls(text)) == [
+        moe_experts.KERNEL_NAME + "_down", moe_experts.KERNEL_NAME + "_up"]
+
+
 @pytest.mark.parametrize("size", list(SHAPES))
 def test_flash_attention_fwd_bwd_compile_for_v5e(v5e, size):
     H, D, T = (SHAPES[size][k] for k in ("H", "D", "T"))

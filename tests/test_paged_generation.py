@@ -250,7 +250,9 @@ class TestPagedAttentionKernel:
         ((25, 16, 64), 4, 64, 4),       # the benchmark's cell
         ((25, 16, 64), 2, 64, 8), ((25, 16, 64), 1, 64, 8),
         ((12, 16, 64), 4, 64, 8),       # chip_smoke's GPT-2 small
-        ((4, 8, 16), 4, 24, 16),        # no more than the table holds
+        ((4, 8, 16), 4, 4, 4),          # no more than the table holds
+        ((4, 8, 16), 4, 24, 8),         # nor than the body unrolls (PR 30)
+        ((8, 16, 64), 2, 64, 8),        # lfm2-8b-a1b: reckoned from H_kv
         ((4, 4, 8), 4, 1, 1), ((64, 128, 128), 4, 64, 1)])
     def test_blocks_per_chunk_follows_the_vmem_budget(self, shape,
                                                       itemsize, B, want):
