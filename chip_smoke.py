@@ -120,7 +120,7 @@ def phase_kernels():
         decode_attention_xla
     from deeplearning4j_tpu.kernels.kv_quant import quantize_rows
     from deeplearning4j_tpu.kernels.paged_attention import (
-        gather_blocks, paged_attention_xla)
+        fuse_kv, gather_blocks, paged_attention_xla)
     from deeplearning4j_tpu.parallel.longseq import dot_product_attention
 
     S, H, D, Bs, T = (NUM_SLOTS, LM["n_heads"],
@@ -144,14 +144,13 @@ def phase_kernels():
              "bf16": lambda x: x.astype(jnp.bfloat16),
              "int8": quantize_rows}
     for dt, cast in casts.items():
-        kp, vp = cast(k_pool), cast(v_pool)
+        pool = fuse_kv(cast(k_pool), cast(v_pool))
         _checked(secs, f"paged_attention/{dt}",
-                 lambda q, kp, vp, t, l: paged_attention(q, kp, vp, t, l),
-                 paged_attention_xla, (q, kp, vp, tables, lengths),
+                 lambda q, pool, t, l: paged_attention(q, pool, t, l),
+                 paged_attention_xla, (q, pool, tables, lengths),
                  KERNEL_ATOL)
         # the same prefix as dense per-slot panels: the slot kernel
-        kc = jax.jit(gather_blocks)(kp, tables)
-        vc = jax.jit(gather_blocks)(vp, tables)
+        kc, vc = jax.jit(gather_blocks)(pool, tables)
         _checked(secs, f"decode_attention/{dt}",
                  lambda q, kc, vc, l: decode_attention(q, kc, vc, l),
                  decode_attention_xla, (q, kc, vc, lengths), KERNEL_ATOL)
@@ -230,8 +229,8 @@ def _paged_numerics(lm, ref_lm):
     params = lm._params
     T, B = LM["max_seq_len"], LM["max_seq_len"] // BLOCK_SIZE
     lens = NUMERICS_LENS
-    pool = PagedKVCache(lm.cache_shapes(BLOCK_SIZE), len(lens) * B + 1)
-    kcs, vcs = pool.ks, pool.vs
+    pools = PagedKVCache(lm.cache_shapes(BLOCK_SIZE),
+                         len(lens) * B + 1).pools
     tables = 1 + np.arange(len(lens) * B, dtype=np.int32).reshape(-1, B)
     rs = np.random.RandomState(7)
     seqs = [rs.randint(0, LM["vocab_size"], n + 1).astype(np.int32)
@@ -248,8 +247,8 @@ def _paged_numerics(lm, ref_lm):
             c = min(CHUNK_TOKENS, n - p0)
             toks = np.zeros((1, CHUNK_TOKENS), np.int32)
             toks[0, :c] = seq[p0:p0 + c]
-            logits, kcs, vcs = chunk(params, toks, jnp.int32(p0),
-                                     jnp.int32(c), kcs, vcs, tables[s])
+            logits, pools, _ = chunk(params, toks, jnp.int32(p0),
+                                     jnp.int32(c), pools, tables[s])
             worst_prefill = max(worst_prefill, float(np.abs(
                 np.asarray(logits)[:c] - ref[p0:p0 + c]).max()))
     toks = jnp.asarray([seq[-1] for seq in seqs], jnp.int32)
@@ -257,13 +256,13 @@ def _paged_numerics(lm, ref_lm):
     out = {}
     for impl in ("pallas", "xla"):
         lowered = jax.jit(
-            lambda p, t, ps, k, v, tb, impl=impl:
-            lm.forward_decode_paged(p, t, ps, k, v, tb, impl)[0]
-        ).lower(params, toks, pos, kcs, vcs, tables)
+            lambda p, t, ps, kv, tb, impl=impl:
+            lm.forward_decode_paged(p, t, ps, kv, tb, impl)[0]
+        ).lower(params, toks, pos, pools, tables)
         if impl == "pallas":
             require_mosaic(lowered.as_text(), "forward_decode_paged")
         out[impl] = np.asarray(lowered.compile()(
-            params, toks, pos, kcs, vcs, tables))
+            params, toks, pos, pools, tables))
     require(np.isfinite(out["pallas"]).all(), "non-finite decode logits")
     d_impl = float(np.abs(out["pallas"] - out["xla"]).max())
     d_ref = float(np.abs(out["pallas"] - np.stack(refs)).max())

@@ -11,17 +11,20 @@ Design points:
 
 * **QuantArray is a registered pytree** of ``(q: int8, scale: f32)``
   with ``scale.shape == q.shape[:-1]`` — one scale per (…, position)
-  row over ``head_dim``. For the paged pool that makes the sidecar
-  ``[num_blocks, H, block_size]``, i.e. per-block-per-head scales
-  indexed by block id (the block is the quantization granule ISSUE 15
-  asks for). Because executables thread caches as pytrees, the int8
+  row over ``head_dim``. The paged pool keeps a position's key and
+  value in one row (`kernels/paged_attention.py`), each half under its
+  own scale, so its sidecar is ``[num_blocks, 2, H, block_size]``:
+  still per-block-per-head scales indexed by block id (the block is
+  the quantization granule ISSUE 15 asks for), and the one QuantArray
+  whose ``scale`` is not ``q.shape[:-1]``. Because executables thread caches as pytrees, the int8
   pool slots into every existing prefill/decode/verify signature AND
   the donation tuple with zero signature churn in the engine.
 
 * **Quantize-on-write, dequantize in-kernel.** All scatter sites
   (decode token writes, prefill slab writes, paged chunk writes) go
-  through :func:`kv_set` / :func:`kv_update_slice`, which compute the
-  row abs-max scale and store int8; the attention kernels apply the
+  through :func:`kv_set` / :func:`kv_update_slice` (the paged pool's
+  through ``paged_attention.kv_pool_set``), which compute the row
+  abs-max scale and store int8; the attention kernels apply the
   scale inside their online-softmax loop, so f32 K/V never round-trips
   through HBM.
 

@@ -1,17 +1,36 @@
-"""Paged KV-cache decode attention (Pallas TPU + XLA fallback).
+"""Paged KV-cache decode attention (Pallas TPU + XLA fallback), and
+the one place that knows how a pool block is laid out.
 
 The paged sibling of :mod:`.decode_attention`: one query row per
 sequence attends over a prefix whose K/V lives in POOL BLOCKS
-(``[num_blocks, H, block_size, D]``, `serving/paging.py`) addressed
-through a per-sequence block table, instead of a contiguous per-slot
-panel. The op is HBM-bandwidth bound by its bytes, but a Pallas grid
-step has a cost of its own (~0.35 us with three small operands on a
-v5e), so the kernel's job is to stream the LIVE K/V once in few, large
-steps and keep the online-softmax state in VMEM.
+(`serving/paging.py`) addressed through a per-sequence block table,
+instead of a contiguous per-slot panel.
+
+**The pool's layout.** One array a layer, ``[num_blocks, H_kv, Bs,
+2 * D]``: a position's key in lanes ``0 .. D - 1`` of its row and its
+value in lanes ``D .. 2 * D - 1``. For ``D = 64`` a row is exactly one
+128-lane vector row, so the array's default device layout on a TPU is
+the row-major tiled one (``{3,2,1,0:T(8,128)}``) that a scatter and a
+Pallas call ask for: a program takes the donated pool and hands it back
+with no relayout. (Two arrays ``[N, H, Bs, 64]`` were kept as
+``{0,3,2,1:T(8,128)}`` and copied whole to row-major and back around
+every decode step and prefill chunk: PERF.md section 6, PR 31.) An int8
+pool is a :class:`~.kv_quant.QuantArray` whose values have that shape
+and whose f32 sidecar is ``[num_blocks, 2, H_kv, Bs]``: the keys'
+scales, then the values'. Everything else addresses a pool by its
+leading block axis alone. :func:`kv_pool_zeros`, :func:`fuse_kv`,
+:func:`split_kv`, :func:`kv_pool_set`, :func:`gather_blocks` and
+:func:`gather_span` are the layout's whole surface; the kernel below is
+its other reader.
+
+The op is HBM-bandwidth bound by its bytes, but a Pallas grid step has
+a cost of its own (~0.35 us with three small operands on a v5e), so the
+kernel's job is to stream the LIVE K/V once in few, large steps and
+keep the online-softmax state in VMEM.
 
 The grid is ``(S, ceil(B / G))``: one grid step takes one slot, ALL its
-heads, and ``G`` table entries. A pool block ``[H, Bs, D]`` is
-contiguous in the pool, so it is one DMA; a pool enters the call as
+heads, and ``G`` table entries. A pool block ``[H, Bs, 2 * D]`` is
+contiguous in the pool, so it is one DMA; the pool enters the call as
 ``G`` operands whose index maps read a scalar-prefetched table
 (`pltpu.PrefetchScalarGridSpec`, pallas guide section 12) and aim each
 at ``pool[tbl[s, c * G + g]]`` -- the gather costs no extra pass over
@@ -21,29 +40,29 @@ block's VMEM footprint (:func:`blocks_per_chunk`). Past a slot's last
 live block every operand is aimed at the block it already holds, which
 starts no DMA, and the body is skipped (``pl.when``): a step costs its
 live keys plus ~70 ns an operand for each table entry walked. The
-scores of a block are a ``[H, Bs]`` tile made on the VPU (multiply by
-the head's query row, reduce over lanes) in f32; the MXU has no use
-for one query row a head. (The pools cannot be left in HBM for manual
-DMAs: Mosaic refuses to slice an HBM ref whose minor dimension, 64
-here, is not a multiple of 128.)
+scores of a block are a ``[H, Bs]`` tile made on the VPU (multiply the
+block by the head's query row, zero in the value lanes, and reduce over
+lanes) in f32; the MXU has no use for one query row a head. The
+accumulator is as wide as a row, and its value half is the output.
 
-Layout: q [S, H, D]; pools [N, H, Bs, D] (positions contiguous per
-head inside a block, same reasoning as the slot cache's [S, H, T, D]);
-block_tables [S, B] int32 pool indices (NULL_BLOCK-padded); lengths
-[S]. Key position ``j`` of sequence ``s`` lives at
-``pool[block_tables[s, j // Bs], :, j % Bs]``; positions >= lengths[s]
-are masked, so padded table entries are never READ into the result --
-they only keep the shapes static.
+Layout: q [S, H, D]; pool [N, H_kv, Bs, 2 * D] (positions contiguous
+per head inside a block, same reasoning as the slot cache's
+[S, H, T, D]); block_tables [S, B] int32 pool indices
+(NULL_BLOCK-padded); lengths [S]. Position ``j`` of sequence ``s``
+lives at ``pool[block_tables[s, j // Bs], :, j % Bs]``; positions >=
+lengths[s] are masked, so padded table entries are never READ into the
+result -- they only keep the shapes static.
 
-Elsewhere the fused-XLA path gathers the blocks with ``jnp.take`` and
-reuses :func:`~.decode_attention.decode_attention_xla` -- the gathered
-[S, H, B*Bs, D] view is bit-identical to a slot cache holding the same
-prefix, which is what makes paged-vs-slot token parity testable.
+Elsewhere the fused-XLA path gathers the blocks with ``jnp.take``,
+splits the lanes of what it gathered, and reuses
+:func:`~.decode_attention.decode_attention_xla` -- the gathered
+[S, H, B*Bs, D] panels are bit-identical to a slot cache holding the
+same prefix, which is what makes paged-vs-slot token parity testable.
 """
 from __future__ import annotations
 
 import functools
-from typing import Optional
+from typing import Optional, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -53,44 +72,107 @@ from jax.experimental.pallas import tpu as pltpu
 
 from .decode_attention import decode_attention_xla
 from .flash_attention import _NEG_INF, _cdiv, default_platform
-from .kv_quant import QuantArray, is_quantized
+from .kv_quant import (QuantArray, canonical_kv_dtype, is_quantized,
+                       kv_zeros, quantize_rows)
 
 #: the Pallas kernel's name: its custom call in the HLO, and the
 #: operation a device trace shows inside ``jit_step``
 KERNEL_NAME = "paged_attention_decode"
 
 
-def gather_blocks(pool, block_tables):
-    """[N, H, Bs, D] pool + [S, B] tables -> [S, H, B*Bs, D] dense
-    per-sequence panels (the slot-cache layout), via one fused gather.
-    QuantArray pools gather values and their scale rows together — the
-    gathered view is itself a QuantArray in slot-cache layout."""
+# ---------------------------------------------------------------------------
+# The pool's layout
+# ---------------------------------------------------------------------------
+def kv_pool_zeros(shape: Sequence[int], kv_dtype: str):
+    """One layer's pool for K (== V) blocks of ``shape`` ``[N, H, Bs,
+    D]``: ``[N, H, Bs, 2 * D]`` zeros at ``kv_dtype``, for int8 a
+    QuantArray with the ``[N, 2, H, Bs]`` scale sidecar."""
+    N, H, Bs, D = (int(d) for d in shape)
+    if canonical_kv_dtype(kv_dtype) == "int8":
+        return QuantArray(jnp.zeros((N, H, Bs, 2 * D), jnp.int8),
+                          jnp.zeros((N, 2, H, Bs), jnp.float32))
+    return kv_zeros((N, H, Bs, 2 * D), kv_dtype)
+
+
+def fuse_kv(k, v):
+    """Separate K and V blocks ``[N, H, Bs, D]`` (arrays, or QuantArrays
+    with ``[N, H, Bs]`` scales) as one pool in the stored layout."""
+    if is_quantized(k) != is_quantized(v):
+        raise ValueError("K and V must be quantized together")
+    if is_quantized(k):
+        return QuantArray(jnp.concatenate([k.q, v.q], axis=-1),
+                          jnp.stack([k.scale, v.scale], axis=1))
+    return jnp.concatenate([k, v], axis=-1)
+
+
+def split_kv(rows):
+    """The key lanes and the value lanes of rows ``[..., 2 * D]`` taken
+    out of a pool (plain values: a QuantArray's scales are split by
+    whoever gathered them, :func:`gather_blocks`, :func:`gather_span`)."""
+    D = rows.shape[-1] // 2
+    return rows[..., :D], rows[..., D:]
+
+
+def kv_pool_set(pool, idx, k, v):
+    """Write the rows ``k`` and ``v`` ``[..., D]`` of some positions
+    into ``pool`` at ``idx``, an index tuple ``(block, head, offset)``
+    of arrays that broadcast to the rows' leading shape: ONE scatter of
+    full ``2 * D``-lane rows, quantized on the way into an int8 pool
+    (each half by its own row scale)."""
     if is_quantized(pool):
-        S, B = block_tables.shape
-        N, H, Bs = pool.scale.shape
-        gs = jnp.take(pool.scale, block_tables.reshape(-1), axis=0)
-        gs = gs.reshape(S, B, H, Bs).transpose(0, 2, 1, 3)
-        return QuantArray(gather_blocks(pool.q, block_tables),
-                          gs.reshape(S, H, B * Bs))
+        qk, qv = quantize_rows(k), quantize_rows(v)
+        blk, head, off = (jnp.asarray(i)[..., None] for i in idx)
+        return QuantArray(
+            pool.q.at[idx].set(jnp.concatenate([qk.q, qv.q], axis=-1)),
+            pool.scale.at[blk, jnp.arange(2), head, off].set(
+                jnp.stack([qk.scale, qv.scale], axis=-1)))
+    return pool.at[idx].set(
+        jnp.concatenate([k, v], axis=-1).astype(pool.dtype))
+
+
+def gather_blocks(pool, block_tables):
+    """Pool + [S, B] tables -> the dense per-sequence K and V panels
+    ``[S, H, B*Bs, D]`` (the slot-cache layout), via one gather whose
+    lanes are split afterwards. A QuantArray pool gathers values and
+    scale rows together: each panel is itself a QuantArray in
+    slot-cache layout."""
     S, B = block_tables.shape
-    N, H, Bs, D = pool.shape
-    g = jnp.take(pool, block_tables.reshape(-1), axis=0)   # [S*B,H,Bs,D]
-    g = g.reshape(S, B, H, Bs, D).transpose(0, 2, 1, 3, 4)
-    return g.reshape(S, H, B * Bs, D)
+    flat = block_tables.reshape(-1)
+    vals = pool.q if is_quantized(pool) else pool
+    N, H, Bs, D2 = vals.shape
+    g = jnp.take(vals, flat, axis=0)                     # [S*B,H,Bs,2D]
+    g = g.reshape(S, B, H, Bs, D2).transpose(0, 2, 1, 3, 4)
+    k, v = split_kv(g.reshape(S, H, B * Bs, D2))
+    if not is_quantized(pool):
+        return k, v
+    gs = jnp.take(pool.scale, flat, axis=0)               # [S*B,2,H,Bs]
+    gs = gs.reshape(S, B, 2, H, Bs).transpose(2, 0, 3, 1, 4)
+    gs = gs.reshape(2, S, H, B * Bs)
+    return QuantArray(k, gs[0]), QuantArray(v, gs[1])
 
 
-def paged_attention_xla(q, k_pool, v_pool, block_tables, lengths):
+def gather_span(pool, block_table):
+    """One sequence's table span ``[n_blocks]`` out of a pool as K and V
+    panels ``[H, T, D]`` (T = n_blocks * Bs); a QuantArray pool's come
+    with their ``[H, T]`` scales."""
+    k, v = gather_blocks(pool, block_table[None])
+    if is_quantized(pool):
+        return (QuantArray(k.q[0], k.scale[0]),
+                QuantArray(v.q[0], v.scale[0]))
+    return k[0], v[0]
+
+
+def paged_attention_xla(q, pool, block_tables, lengths):
     """Fused-XLA paged decode attention (CPU/GPU and reference path).
 
-    q: [S, H_q, D]; k_pool/v_pool: [N, H_kv, Bs, D] with
+    q: [S, H_q, D]; pool: [N, H_kv, Bs, 2 * D] with
     ``H_q = g * H_kv`` (query head i reads KV head i // g);
     block_tables: [S, B]; lengths: [S] — positions >= lengths[s] (stale
     block tails, padded table entries) are masked out. Shapes depend
     only on (S, B, Bs), never on live lengths or which blocks a request
     owns.
     """
-    k = gather_blocks(k_pool, block_tables)
-    v = gather_blocks(v_pool, block_tables)
+    k, v = gather_blocks(pool, block_tables)
     S, Hq, D = q.shape
     Hkv = k.shape[1]
     if Hq == Hkv:
@@ -105,30 +187,32 @@ def paged_attention_xla(q, k_pool, v_pool, block_tables, lengths):
 # ---------------------------------------------------------------------------
 # Pallas TPU kernel
 # ---------------------------------------------------------------------------
-#: VMEM the kernel fills with pool blocks in flight (K and V, two
-#: buffers each); the blocks a chunk holds follow from it. On a v5e at
-#: H 25, Bs 16, D 64, f32, 48 calls over 4,400 live keys take 9.5 ms at
-#: 2 blocks a chunk, 8.2 at 4, 8.6 at 8 and 10.5 at 16 (PERF.md, PR 27):
-#: a larger chunk saves grid steps and wastes more of its last tile
-_VMEM_BLOCK_BUDGET = 4 << 20
+#: VMEM the kernel fills with pool blocks in flight (two buffers a
+#: block); the blocks a chunk holds follow from it. On a v5e at H 25,
+#: Bs 16, D 64, f32 (a block of 200 KiB), 48 calls over 4,400 live keys
+#: take 9.4 ms at 2 blocks a chunk, 7.8 at 4, 8.1 at 8 and 10.0 at 16
+#: (PERF.md, PR 31; the same order as PR 27's): a larger chunk saves
+#: grid steps and wastes more of its last tile
+_VMEM_BLOCK_BUDGET = 2 << 20
 #: and no more than this many, however small a block is: the body is
 #: unrolled over a chunk's blocks (and the members of a query group).
 #: Swept where it binds, on a v5e at 32 query heads over H 8, Bs 16,
-#: D 64, bf16, 3 calls over 4,400 live keys: 1.15 ms at 2 blocks a
-#: chunk, 0.87 at 4, 0.79 at 8, 0.80 at 16 (PERF.md, PR 30)
+#: D 64, bf16 (a block of 32 KiB), 3 calls over 4,400 live keys: 0.99
+#: ms at 2 blocks a chunk, 0.71 at 4, 0.64 at 8, 0.66 at 16 (PERF.md,
+#: PR 31)
 _MAX_BLOCKS = 8
 
 
 def blocks_per_chunk(H: int, Bs: int, D: int, itemsize: int, B: int) -> int:
     """Pool blocks one chunk of the kernel attends (``G``): the largest
-    power of two whose K and V double buffers fit the VMEM budget, as
-    Mosaic tiles a ``[H, Bs, D]`` block there (``H`` the KV heads; rows
-    padded to the sublane tile of the item size, ``D`` to 128 lanes),
-    and no more than the table holds or :data:`_MAX_BLOCKS`."""
+    power of two whose double buffers fit the VMEM budget, as Mosaic
+    tiles a ``[H, Bs, 2 * D]`` block there (``H`` the KV heads; rows
+    padded to the sublane tile of the item size, the ``2 * D`` lanes to
+    128), and no more than the table holds or :data:`_MAX_BLOCKS`."""
     sublanes = 8 * 4 // itemsize
-    block = H * _cdiv(Bs, sublanes) * sublanes * _cdiv(D, 128) * 128 \
+    block = H * _cdiv(Bs, sublanes) * sublanes * _cdiv(2 * D, 128) * 128 \
         * itemsize
-    g = max(1, min(_VMEM_BLOCK_BUDGET // (4 * block), B, _MAX_BLOCKS))
+    g = max(1, min(_VMEM_BLOCK_BUDGET // (2 * block), B, _MAX_BLOCKS))
     return 1 << (g.bit_length() - 1)
 
 
@@ -140,29 +224,30 @@ def _paged_kernel(tbl_ref, len_ref, q_ref, *refs, quant: bool, G: int,
 
     Refs (the slot dim squeezed): tbl_ref [S, C * G] (the table entry
     each operand fetches: the index maps alone read it) and len_ref
-    [S], scalar-prefetched; q_ref [H, D]; ``G`` K blocks then ``G`` V
-    blocks [H, Bs, D]; for an int8 pool ``G`` K then ``G`` V scale
-    tiles [H, Bs]; o_ref [H, D]; scratch m, l [H, 1] and acc [H, D].
+    [S], scalar-prefetched; q_ref [H, 2 * D], the query row in the key
+    lanes and zeros in the value lanes; ``G`` pool blocks [H, Bs,
+    2 * D]; for an int8 pool ``G`` K then ``G`` V scale tiles [H, Bs];
+    o_ref [H, D]; scratch m, l [H, 1] and acc [H, 2 * D].
 
     The scores of a block are a [H, Bs] tile: a VPU multiply by the
-    head's query row and a lane reduction, in f32 whatever the pool
-    holds. A chunk whose first position is past the length runs no
-    body (and fetched nothing: the index maps repeat a block they
-    already hold). Inside the last live chunk, what a block holds past
-    the length (a stale tail, or another position's keys where the
-    index map repeated a block) is masked by position with ``where``,
-    never multiplied away: it may be NaN.
+    head's padded query row and a lane reduction, in f32 whatever the
+    pool holds (the value lanes meet zeros). A chunk whose first
+    position is past the length runs no body (and fetched nothing: the
+    index maps repeat a block they already hold). Inside the last live
+    chunk, what a block holds past the length (a stale tail, or another
+    position's rows where the index map repeated a block) is masked by
+    position with ``where``, never multiplied away: it may be NaN. The
+    accumulator takes whole rows; its key half is never read.
 
     Grouped-query heads (``g`` query heads to a KV head, ``H`` the KV
     heads): q, o and the scratch hold ``g * H`` rows, member ``j`` of
     every group in rows ``j * H .. (j + 1) * H`` (the wrapper lays them
-    out so), and each member multiplies the same K and V tiles, loaded
-    once. With ``g == 1`` this is the body it was."""
-    k_refs, v_refs = refs[:G], refs[G:2 * G]
-    ks_refs, vs_refs = (refs[2 * G:3 * G], refs[3 * G:4 * G]) if quant \
+    out so), and each member multiplies the same tiles, loaded once."""
+    kv_refs = refs[:G]
+    ks_refs, vs_refs = (refs[G:2 * G], refs[2 * G:3 * G]) if quant \
         else (None, None)
     o_ref, m_s, l_s, acc_s = refs[-4:]
-    H, Bs, D = k_refs[0].shape
+    H, Bs, D2 = kv_refs[0].shape
     c = pl.program_id(1)
     length = len_ref[pl.program_id(0)]
 
@@ -179,24 +264,26 @@ def _paged_kernel(tbl_ref, len_ref, q_ref, *refs, quant: bool, G: int,
         mask = [p0 + lane < length for p0 in first]
         row = lax.broadcasted_iota(jnp.int32, (H, Bs, 1), 1)
 
-        def k_tile(b):
-            return k_refs[b][...].astype(jnp.float32)
+        def tile(b):
+            # for the scores: a masked row's is replaced below,
+            # whatever it is
+            return kv_refs[b][...].astype(jnp.float32)
 
-        def v_tile(b):
-            # zero masked V rows: 0 * NaN = NaN would leak a stale tail
-            return jnp.where(first[b] + row < length,
-                             v_refs[b][...].astype(jnp.float32), 0.0)
+        def live_tile(b):
+            # for the sum: masked rows zeroed, 0 * NaN = NaN would leak
+            # a stale tail
+            return jnp.where(first[b] + row < length, tile(b), 0.0)
 
         if g > 1:       # the members of a group share the tiles
-            kf, vf = ([k_tile(b) for b in range(G)],
-                      [v_tile(b) for b in range(G)])
-            k_tile, v_tile = kf.__getitem__, vf.__getitem__
+            tf, lf = ([tile(b) for b in range(G)],
+                      [live_tile(b) for b in range(G)])
+            tile, live_tile = tf.__getitem__, lf.__getitem__
         for j in range(g):
             rows = slice(None) if g == 1 else pl.ds(j * H, H)
             q = q_ref[rows, :].astype(jnp.float32)[:, None, :] * scale
             sc = []
             for b in range(G):
-                x = jnp.sum(k_tile(b) * q, axis=-1)
+                x = jnp.sum(tile(b) * q, axis=-1)
                 if quant:
                     x = x * ks_refs[b][...]                   # K dequant
                 sc.append(jnp.where(mask[b], x, _NEG_INF))    # [H, Bs]
@@ -213,47 +300,48 @@ def _paged_kernel(tbl_ref, len_ref, q_ref, *refs, quant: bool, G: int,
                 if quant:
                     # V dequant folds into p; a stale scale may be NaN
                     p = jnp.where(mask[b], p * vs_refs[b][...], 0.0)
-                acc = acc + jnp.sum(p[:, :, None] * v_tile(b), axis=1)
+                acc = acc + jnp.sum(p[:, :, None] * live_tile(b), axis=1)
             m_s[rows, :], l_s[rows, :], acc_s[rows, :] = m_new, l_new, acc
 
     @pl.when(c == pl.num_programs(1) - 1)
     def _finalize():
         # a free lane (length 0) ran no chunk: acc is 0 and so is its row
-        o_ref[...] = (acc_s[...] / jnp.maximum(l_s[...], 1e-30)
+        o_ref[...] = (acc_s[:, D2 // 2:] / jnp.maximum(l_s[...], 1e-30)
                       ).astype(o_ref.dtype)
 
 
-def paged_attention_pallas(q, k_pool, v_pool, block_tables, lengths,
+def paged_attention_pallas(q, pool, block_tables, lengths,
                            interpret: Optional[bool] = None):
     """Pallas paged decode attention. Same contract as
     :func:`paged_attention_xla` (grouped-query heads included: the
-    query heads of a group are more rows against the same K tile).
+    query heads of a group are more rows against the same tile).
     Grid ``(S, ceil(B / G))``: one grid
     step attends all heads of a slot over ``G`` table entries
-    (:func:`blocks_per_chunk`). A pool enters as ``G`` operands, each
-    one whole block ``[H, Bs, D]`` (contiguous in the pool) that the
-    scalar-prefetched table aims at ``pool[tbl[s, c * G + g]]``. Past
-    the slot's last live block an operand is aimed at the block it
+    (:func:`blocks_per_chunk`). The pool enters as ``G`` operands, each
+    one whole block ``[H, Bs, 2 * D]`` (contiguous in the pool) that
+    the scalar-prefetched table aims at ``pool[tbl[s, c * G + g]]``.
+    Past the slot's last live block an operand is aimed at the block it
     fetched last, so the pipeline fetches nothing new, and the body is
     skipped: the cost follows the live length, not the table span. An
     int8 QuantArray pool brings its ``[H, Bs]`` scale tiles the same
-    way and is dequantized in VMEM."""
+    way (one for K, one for V) and is dequantized in VMEM."""
     if interpret is None:
         interpret = default_platform() != "tpu"
-    quant = is_quantized(k_pool)
-    if quant != is_quantized(v_pool):
-        raise ValueError("K and V pools must be quantized together")
+    quant = is_quantized(pool)
     S, H, D = q.shape
-    pools = [k_pool.q, v_pool.q, k_pool.scale, v_pool.scale] if quant \
-        else [k_pool, v_pool]
-    Hkv, Bs = pools[0].shape[1:3]
+    vals = pool.q if quant else pool
+    Hkv, Bs, D2 = vals.shape[1:]
+    if D2 != 2 * D:
+        raise ValueError(f"a pool row of {D2} lanes for heads of {D}")
     g = H // Hkv
     if g * Hkv != H:
         raise ValueError(f"{H} query heads over {Hkv} KV heads")
     if g > 1:       # member j of every group in rows j * Hkv ..
         q = q.reshape(S, Hkv, g, D).swapaxes(1, 2).reshape(S, H, D)
+    # the query row meets whole pool rows: zeros against the value lanes
+    q_pad = jnp.pad(q, ((0, 0), (0, 0), (0, D)))
     B = block_tables.shape[1]
-    G = blocks_per_chunk(Hkv, Bs, D, pools[0].dtype.itemsize, B)
+    G = blocks_per_chunk(Hkv, Bs, D, vals.dtype.itemsize, B)
     C = _cdiv(B, G)
     # The table entry each of a chunk's G operands fetches, [S, C * G]:
     # its own (c * G + g) while that is live, then the last live one
@@ -271,23 +359,30 @@ def paged_attention_pallas(q, k_pool, v_pool, block_tables, lengths,
     def entry(g, tail):
         return lambda s, c, tbl, lens: (tbl[s, c * G + g],) + tail
 
-    q_spec = pl.BlockSpec((None, H, D), lambda s, c, tbl, lens: (s, 0, 0))
-    operands, in_specs = [q], [q_spec]
-    for pool in pools:
-        operands += [pool] * G
-        in_specs += [pl.BlockSpec((None,) + pool.shape[1:],
-                                  entry(g, (0,) * (pool.ndim - 1)))
-                     for g in range(G)]
+    def row_spec(width):
+        return pl.BlockSpec((None, H, width),
+                            lambda s, c, tbl, lens: (s, 0, 0))
+
+    operands, in_specs = [q_pad], [row_spec(D2)]
+    operands += [vals] * G
+    in_specs += [pl.BlockSpec((None, Hkv, Bs, D2), entry(g, (0, 0, 0)))
+                 for g in range(G)]
+    if quant:
+        for half in (0, 1):             # the keys' scales, the values'
+            operands += [pool.scale] * G
+            in_specs += [pl.BlockSpec((None, None, Hkv, Bs),
+                                      entry(g, (half, 0, 0)))
+                         for g in range(G)]
     out = pl.pallas_call(
         functools.partial(_paged_kernel, quant=quant, G=G,
                           scale=1.0 / (D ** 0.5), g=g),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,          # fetched, lengths
             grid=(S, C),
-            in_specs=in_specs, out_specs=q_spec,
+            in_specs=in_specs, out_specs=row_spec(D),
             scratch_shapes=[pltpu.VMEM((H, 1), jnp.float32),   # max
                             pltpu.VMEM((H, 1), jnp.float32),   # sum
-                            pltpu.VMEM((H, D), jnp.float32)]),
+                            pltpu.VMEM((H, D2), jnp.float32)]),
         out_shape=jax.ShapeDtypeStruct((S, H, D), q.dtype),
         interpret=interpret,
         # the custom call's instruction name in the HLO and so in a
@@ -299,8 +394,8 @@ def paged_attention_pallas(q, k_pool, v_pool, block_tables, lengths,
     return out
 
 
-def paged_attention(q, k_pool, v_pool, block_tables, lengths,
-                    impl: str = "auto", **kw):
+def paged_attention(q, pool, block_tables, lengths, impl: str = "auto",
+                    **kw):
     """Dispatch: ``auto`` runs the Pallas kernel on TPU (scalar-
     prefetched block gather bounded by the live lengths, VMEM-resident
     softmax state), fused XLA elsewhere. ``pallas`` / ``xla`` force a
@@ -309,9 +404,7 @@ def paged_attention(q, k_pool, v_pool, block_tables, lengths,
     if impl == "auto":
         impl = "pallas" if default_platform() == "tpu" else "xla"
     if impl == "pallas":
-        return paged_attention_pallas(q, k_pool, v_pool, block_tables,
-                                      lengths, **kw)
+        return paged_attention_pallas(q, pool, block_tables, lengths, **kw)
     if impl == "xla":
-        return paged_attention_xla(q, k_pool, v_pool, block_tables,
-                                   lengths)
+        return paged_attention_xla(q, pool, block_tables, lengths)
     raise ValueError(f"unknown paged attention impl {impl!r}")

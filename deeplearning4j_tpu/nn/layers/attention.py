@@ -39,17 +39,6 @@ def _cache_row(cache, i):
     return cache[i]
 
 
-def _gather_span(pool, block_table, H, Dh):
-    """Gather a sequence's block-table span out of a paged pool into
-    one [H, T, Dh] panel (T = n_blocks * Bs). Quantized pools gather
-    the int8 blocks and the [H, T] scale sidecar with the same table."""
-    if is_quantized(pool):
-        qq = jnp.swapaxes(pool.q[block_table], 0, 1).reshape(H, -1, Dh)
-        ss = jnp.swapaxes(pool.scale[block_table], 0, 1).reshape(H, -1)
-        return QuantArray(qq, ss)
-    return jnp.swapaxes(pool[block_table], 0, 1).reshape(H, -1, Dh)
-
-
 def _span_attend(q, kk, vv, gpos, p0c, out_dtype):
     """Causal span attention over one gathered K/V panel — the shared
     math of :meth:`SelfAttentionLayer.apply_verify` (dense slot panel)
@@ -259,24 +248,26 @@ class SelfAttentionLayer(Layer):
         return self.activation(out), k_cache, v_cache
 
     # -- paged KV cache (serving/paging) --------------------------------
-    def apply_decode_paged(self, params, x, k_pool, v_pool, block_tables,
-                           pos, impl: str = "auto"):
+    def apply_decode_paged(self, params, x, pool, block_tables, pos,
+                           impl: str = "auto"):
         """One cached decode step against the PAGED pool: write the
-        current token's K/V at ``pool[table[pos // Bs], :, pos % Bs]``,
-        attend over the prefix through the block table. Same contract
-        as :meth:`apply_decode` with the per-slot panels replaced by
-        shared pool blocks.
+        current token's K and V, one row side by side, at
+        ``pool[table[pos // Bs], :, pos % Bs]``, attend over the prefix
+        through the block table. Same contract as :meth:`apply_decode`
+        with the per-slot panels replaced by shared pool blocks.
 
-        x: [B, C]; k_pool/v_pool: [N, H, Bs, Dh]; block_tables:
-        [B, n_blocks] int32 (NULL_BLOCK-padded); pos: [B] int32.
-        Inactive rows must carry NULL_BLOCK tables — their writes then
-        land in the reserved null block instead of live memory.
+        x: [B, C]; pool: [N, H, Bs, 2 * Dh] (the layout of
+        `kernels/paged_attention.py`); block_tables: [B, n_blocks]
+        int32 (NULL_BLOCK-padded); pos: [B] int32. Inactive rows must
+        carry NULL_BLOCK tables — their writes then land in the
+        reserved null block instead of live memory.
+        Returns (out [B, n_out], pool).
         """
-        from ...kernels.paged_attention import paged_attention
+        from ...kernels.paged_attention import kv_pool_set, paged_attention
         B = x.shape[0]
         H = self.n_heads
         Dh = self.n_out // H
-        Bs = k_pool.shape[2]
+        Bs = pool.shape[2]
         q = (x @ params["Wq"]).reshape(B, H, Dh)
         k_t = (x @ params["Wk"]).reshape(B, H, Dh)
         v_t = (x @ params["Wv"]).reshape(B, H, Dh)
@@ -284,12 +275,11 @@ class SelfAttentionLayer(Layer):
                                   axis=1)[:, 0]
         off = pos % Bs
         heads = jnp.arange(H)[None, :]
-        k_pool = kv_set(k_pool, (blk[:, None], heads, off[:, None]), k_t)
-        v_pool = kv_set(v_pool, (blk[:, None], heads, off[:, None]), v_t)
-        att = paged_attention(q, k_pool, v_pool, block_tables, pos + 1,
-                              impl=impl)
+        pool = kv_pool_set(pool, (blk[:, None], heads, off[:, None]),
+                           k_t, v_t)
+        att = paged_attention(q, pool, block_tables, pos + 1, impl=impl)
         out = att.reshape(B, self.n_out) @ params["Wo"] + params["b"]
-        return self.activation(out), k_pool, v_pool
+        return self.activation(out), pool
 
     def apply_verify(self, params, x, k_cache, v_cache, slot, p0,
                      chunk_len):
@@ -331,36 +321,37 @@ class SelfAttentionLayer(Layer):
         out = att.reshape(C, self.n_out) @ params["Wo"] + params["b"]
         return self.activation(out)[None], k_cache, v_cache
 
-    def apply_prefill_paged(self, params, x, k_pool, v_pool, block_table,
-                            p0, chunk_len):
+    def apply_prefill_paged(self, params, x, pool, block_table, p0,
+                            chunk_len):
         """One prefill CHUNK against the paged pool: project the chunk,
-        scatter its K/V into the owning blocks, and attend each chunk
-        query causally over the gathered prefix (earlier chunks + this
-        one). Chunked prefill is what keeps a long prompt from
+        scatter its K/V rows into the owning blocks, and attend each
+        chunk query causally over the gathered prefix (earlier chunks +
+        this one). Chunked prefill is what keeps a long prompt from
         monopolizing the decode loop — the scheduler interleaves these
         with decode steps (Sarathi-Serve, OSDI '24; PAPERS.md).
 
         x: [1, C, Cin] chunk activations (C is the chunk bucket);
-        block_table: [n_blocks] int32, sized by the CALLER so that
-        ``n_blocks * Bs >= p0 + C``; p0: scalar int32 global start;
-        chunk_len: scalar int32 valid rows. Padded rows (>= chunk_len)
-        write junk K/V, harmlessly: rows inside the sequence's
-        allocation land at positions beyond its live length — masked
-        by every reader, and overwritten by the decode step's write at
-        ``pos`` before that position is ever unmasked — and rows past
-        the allocation land on NULL-padded table entries, i.e. the
-        reserved null block. An UNDERSIZED table is the one fatal
-        case: position ``p0 + C - 1`` would alias into another
+        pool: [N, H, Bs, 2 * Dh]; block_table: [n_blocks] int32, sized
+        by the CALLER so that ``n_blocks * Bs >= p0 + C``; p0: scalar
+        int32 global start; chunk_len: scalar int32 valid rows. Padded
+        rows (>= chunk_len) write junk K/V, harmlessly: rows inside the
+        sequence's allocation land at positions beyond its live length
+        — masked by every reader, and overwritten by the decode step's
+        write at ``pos`` before that position is ever unmasked — and
+        rows past the allocation land on NULL-padded table entries,
+        i.e. the reserved null block. An UNDERSIZED table is the one
+        fatal case: position ``p0 + C - 1`` would alias into another
         sequence's block, which is why the size contract above is the
         caller's to uphold.
-        Returns (out [1, C, n_out], k_pool, v_pool).
+        Returns (out [1, C, n_out], pool).
         """
+        from ...kernels.paged_attention import gather_span, kv_pool_set
         if not self.causal:
             raise ValueError("cached decode needs causal=True attention")
         C = x.shape[1]
         H = self.n_heads
         Dh = self.n_out // H
-        Bs = k_pool.shape[2]
+        Bs = pool.shape[2]
         xx = x[0]
         q = (xx @ params["Wq"]).reshape(C, H, Dh)
         k_t = (xx @ params["Wk"]).reshape(C, H, Dh)
@@ -369,17 +360,16 @@ class SelfAttentionLayer(Layer):
         blk = block_table[gpos // Bs]
         off = gpos % Bs
         heads = jnp.arange(H)[None, :]
-        k_pool = kv_set(k_pool, (blk[:, None], heads, off[:, None]), k_t)
-        v_pool = kv_set(v_pool, (blk[:, None], heads, off[:, None]), v_t)
+        pool = kv_pool_set(pool, (blk[:, None], heads, off[:, None]),
+                           k_t, v_t)
         # gather the sequence's whole table span and attend causally:
         # chunk query c (global position p0+c) sees keys j <= p0+c —
         # earlier chunks' K/V comes back out of the pool it went into
         # (quantized on write, scales gathered alongside)
-        kk = _gather_span(k_pool, block_table, H, Dh)
-        vv = _gather_span(v_pool, block_table, H, Dh)
+        kk, vv = gather_span(pool, block_table)
         att = _span_attend(q, kk, vv, gpos, p0 + C, x.dtype)
         out = att.reshape(C, self.n_out) @ params["Wo"] + params["b"]
-        return self.activation(out)[None], k_pool, v_pool
+        return self.activation(out)[None], pool
 
     def init_carry(self, batch, dtype=jnp.float32):
         return ()
@@ -511,27 +501,26 @@ class TransformerEncoderLayer(Layer):
         return self._mlp(params, x + att), k_cache, v_cache
 
     # -- paged KV cache (serving/paging) --------------------------------
-    def apply_decode_paged(self, params, x, k_pool, v_pool, block_tables,
-                           pos, impl: str = "auto"):
+    def apply_decode_paged(self, params, x, pool, block_tables, pos,
+                           impl: str = "auto"):
         """One cached decode step through the full block against the
         paged pool (see :meth:`SelfAttentionLayer.apply_decode_paged`)."""
         from ..functional import layer_norm as _ln
         h = _ln(x, params["ln1_g"], params["ln1_b"])
-        att, k_pool, v_pool = self.attn.apply_decode_paged(
-            self._attn_params(params), h, k_pool, v_pool, block_tables,
-            pos, impl)
-        return self._mlp(params, x + att), k_pool, v_pool
+        att, pool = self.attn.apply_decode_paged(
+            self._attn_params(params), h, pool, block_tables, pos, impl)
+        return self._mlp(params, x + att), pool
 
-    def apply_prefill_paged(self, params, x, k_pool, v_pool, block_table,
-                            p0, chunk_len):
+    def apply_prefill_paged(self, params, x, pool, block_table, p0,
+                            chunk_len):
         """One prefill chunk through the full block against the paged
         pool (see :meth:`SelfAttentionLayer.apply_prefill_paged`)."""
         from ..functional import layer_norm as _ln
         h = _ln(x, params["ln1_g"], params["ln1_b"])
-        att, k_pool, v_pool = self.attn.apply_prefill_paged(
-            self._attn_params(params), h, k_pool, v_pool, block_table,
-            p0, chunk_len)
-        return self._mlp(params, x + att), k_pool, v_pool
+        att, pool = self.attn.apply_prefill_paged(
+            self._attn_params(params), h, pool, block_table, p0,
+            chunk_len)
+        return self._mlp(params, x + att), pool
 
     def apply_verify(self, params, x, k_cache, v_cache, slot, p0,
                      chunk_len):

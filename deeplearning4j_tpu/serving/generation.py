@@ -59,8 +59,8 @@ from .faults import (CorruptedStateFault, PoisonRequestError,
                      TransientFault, poll_until_idle)
 from ..kernels.kv_quant import (canonical_kv_dtype, kv_bytes_per_token,
                                 kv_copy_row, kv_pack_host,
-                                kv_unpack_host, kv_update_slice,
-                                kv_zeros)
+                                kv_unpack_host, kv_update_slice)
+from ..kernels.paged_attention import kv_pool_zeros
 from .kvcache import KVCache, SlotTable
 from .metrics import GenerationMetrics
 from .offload import (DiskRing, HostBlockStore, HostRun,
@@ -367,7 +367,7 @@ class GenerationEngine:
       ``[num_slots, H, max_seq_len, Dh]``: memory scales with the
       WORST-CASE sequence length per slot.
     - ``"paged"`` — a shared block pool
-      ``[num_blocks, H, block_size, Dh]`` (`serving/paging.py`): a
+      ``[num_blocks, H, block_size, 2 * Dh]`` (`serving/paging.py`): a
       request claims ``ceil((prompt + max_tokens) / block_size)``
       blocks at admission (all-or-nothing — when blocks run out the
       request WAITS at the queue head instead of over-committing), so
@@ -614,8 +614,7 @@ class GenerationEngine:
         self.metrics.kv_bytes_per_token = kv_bytes_per_token(
             self._cache.layer_shapes, self.kv_dtype)
         self.metrics.quant_scale_bytes = self._cache.scale_nbytes()
-        self._kcs = self._cache.ks
-        self._vcs = self._cache.vs
+        self._bind_cache()
         self._state = self._fresh_state()
         self.metrics.slot_state_bytes = int(sum(
             a.nbytes for a in self._state))
@@ -688,11 +687,10 @@ class GenerationEngine:
         # the per-step cost scales with num_slots and continuous
         # batching loses its amortization (measured 0.5x vs sequential
         # on CPU with copies; 4x+ with donation)
+        # Slots: the K and the V panels. Paged: the pools (one array a
+        # layer) and the slot state: an empty list for a model that
+        # declares none, which adds nothing to its programs
         self._donate = (1, 2)
-        # the paged programs also take (and donate) the slot state: an
-        # empty list for a model that declares none, which adds nothing
-        # to its programs
-        self._donate_paged = (1, 2, 3)
         # -- pipelined decode (ISSUE 14) ----------------------------
         # With the pipeline on (default; speculation forces it off —
         # verify rounds are inherently synchronous), the scheduler
@@ -778,6 +776,15 @@ class GenerationEngine:
                                 kv_dtype=self.kv_dtype)
         return KVCache(self.model.cache_shapes(self.max_seq_len),
                        self.num_slots, kv_dtype=self.kv_dtype)
+
+    def _bind_cache(self):
+        """The cache's arrays as the programs thread them: ``_pools``
+        (paged: one array a layer) or ``_kcs`` / ``_vcs`` (slots)."""
+        if self.cache_backend == "paged":
+            self._pools = self._cache.pools
+        else:
+            self._kcs = self._cache.ks
+            self._vcs = self._cache.vs
 
     def _fresh_state(self):
         """The arrays a model keeps a slot (``slot_state_shapes``),
@@ -873,9 +880,9 @@ class GenerationEngine:
         if self.cache_backend == "paged":
             stateful = self._stateful
 
-            def step(params, kcs, vcs, state, tok_host, tok_dev,
-                     use_host, pos, tables, seeds, steps, temps, top_ks,
-                     eos, max_steps):
+            def step(params, pools, state, tok_host, tok_dev, use_host,
+                     pos, tables, seeds, steps, temps, top_ks, eos,
+                     max_steps):
                 tokens = jnp.where(use_host, tok_host, tok_dev)
                 counters = ()
                 if stateful:
@@ -885,18 +892,19 @@ class GenerationEngine:
                     # end write no state and count nowhere
                     live = (tables[:, 0] != NULL_BLOCK) \
                         & (steps < max_steps)
-                    logits, kcs, vcs, state, counters = \
+                    logits, pools, state, counters = \
                         model.forward_decode_paged(
-                            params, tokens, pos, kcs, vcs, tables, impl,
+                            params, tokens, pos, pools, tables, impl,
                             state=state, live=live)
                 else:
-                    logits, kcs, vcs = model.forward_decode_paged(
-                        params, tokens, pos, kcs, vcs, tables, impl)
+                    logits, pools, state = model.forward_decode_paged(
+                        params, tokens, pos, pools, tables, impl,
+                        state=state)
                 ok = jnp.all(jnp.isfinite(logits), axis=-1)  # per lane
                 nxt = _sample_batch(logits, temps, top_ks, seeds, steps)
                 done = ((nxt == eos) & (eos >= 0)) \
                     | (steps + 1 >= max_steps)
-                return nxt, ok, done, kcs, vcs, state, counters
+                return nxt, ok, done, pools, state, counters
             return step
 
         def step(params, kcs, vcs, tok_host, tok_dev, use_host, pos,
@@ -915,17 +923,18 @@ class GenerationEngine:
 
         stateful = self._stateful
 
-        def chunk(params, kcs, vcs, state, tokens, p0, chunk_len, table,
+        def chunk(params, pools, state, tokens, p0, chunk_len, table,
                   slot, seed, temp, top_k):
             counters = ()
             if stateful:
-                logits, kcs, vcs, state, counters = \
+                logits, pools, state, counters = \
                     model.forward_prefill_chunk(
-                        params, tokens, p0, chunk_len, kcs, vcs, table,
+                        params, tokens, p0, chunk_len, pools, table,
                         state=state, slot=slot)
             else:
-                logits, kcs, vcs = model.forward_prefill_chunk(
-                    params, tokens, p0, chunk_len, kcs, vcs, table)
+                logits, pools, state = model.forward_prefill_chunk(
+                    params, tokens, p0, chunk_len, pools, table,
+                    state=state)
             # guard only rows < chunk_len: padded tail rows attend
             # positions past the live length — stale block junk that
             # is allowed to be anything (no-zeroing invariant)
@@ -938,7 +947,7 @@ class GenerationEngine:
             # sample is bit-identical across backends
             key = jax.random.fold_in(jax.random.PRNGKey(seed), 0)
             first = _sample_one(last, temp, top_k, key)
-            return first, ok, kcs, vcs, state, counters
+            return first, ok, pools, state, counters
         return chunk
 
     def _prefill_fn(self):
@@ -976,8 +985,7 @@ class GenerationEngine:
                 return self._decode_exe
             S = self.num_slots
             if self.cache_backend == "paged":
-                args = (self.model._params, self._kcs, self._vcs,
-                        self._state,
+                args = (self.model._params, self._pools, self._state,
                         np.zeros(S, np.int32), np.zeros(S, np.int32),
                         np.ones(S, bool), np.zeros(S, np.int32),
                         np.full((S, self._blocks_per_seq), NULL_BLOCK,
@@ -993,10 +1001,8 @@ class GenerationEngine:
                         np.zeros(S, np.float32), np.zeros(S, np.int32),
                         np.full(S, -1, np.int32), np.zeros(S, np.int32))
             with self._profiler.record("generation.compile"):
-                exe = compile_memoized(
-                    self._decode_fn(), args,
-                    self._donate_paged if self.cache_backend == "paged"
-                    else self._donate)
+                exe = compile_memoized(self._decode_fn(), args,
+                                       self._donate)
             self.metrics.inc("compiles")
             self._decode_exe = exe
             return exe
@@ -1013,8 +1019,7 @@ class GenerationEngine:
             exe = self._prefill_exe.get(key)
             if exe is not None:
                 return exe
-            args = (self.model._params, self._kcs, self._vcs,
-                    self._state,
+            args = (self.model._params, self._pools, self._state,
                     np.zeros((1, chunk_bucket), np.int32), np.int32(0),
                     np.int32(1),
                     np.full(tbl_bucket, NULL_BLOCK, np.int32),
@@ -1022,19 +1027,17 @@ class GenerationEngine:
                     np.int32(0))
             with self._profiler.record("generation.compile"):
                 exe = compile_memoized(self._chunk_fn(), args,
-                                       self._donate_paged)
+                                       self._donate)
             self.metrics.inc("compiles")
             self._prefill_exe[key] = exe
             return exe
 
     def _cow_fn(self):
-        def cow(kcs, vcs, src, dst):
-            # kv_copy_row copies the int8 block AND its scale row
+        def cow(pools, src, dst):
+            # kv_copy_row copies the int8 block AND its scale rows
             # together — a scale-less copy would silently rescale the
             # shared prefix (tests/test_kv_quant.py::TestCOWScales)
-            kcs = [kv_copy_row(kc, src, dst) for kc in kcs]
-            vcs = [kv_copy_row(vc, src, dst) for vc in vcs]
-            return kcs, vcs
+            return [kv_copy_row(p, src, dst) for p in pools]
         return cow
 
     def _get_cow_exe(self):
@@ -1047,9 +1050,9 @@ class GenerationEngine:
         with self._exe_lock:
             if self._cow_exe is not None:
                 return self._cow_exe
-            args = (self._kcs, self._vcs, np.int32(0), np.int32(0))
+            args = (self._pools, np.int32(0), np.int32(0))
             with self._profiler.record("generation.compile"):
-                exe = compile_memoized(self._cow_fn(), args, (0, 1))
+                exe = compile_memoized(self._cow_fn(), args, (0,))
             self.metrics.inc("compiles")
             self._cow_exe = exe
             return exe
@@ -1061,9 +1064,9 @@ class GenerationEngine:
         the caller maps a failure here to recompute-recovery exactly
         like a failed prefill/decode call."""
         with self._profiler.record("generation.cow"):
-            self._kcs, self._vcs = self._get_cow_exe()(
-                self._kcs, self._vcs, np.int32(src), np.int32(dst))
-            jax.block_until_ready(self._kcs[0])  # surface device faults
+            self._pools = self._get_cow_exe()(
+                self._pools, np.int32(src), np.int32(dst))
+            jax.block_until_ready(self._pools[0])  # surface device faults
 
     # -- hierarchical KV tier (PR 16; serving/offload.py) --------------
     # Demotion gathers a block run device->host; restore scatters it
@@ -1082,8 +1085,7 @@ class GenerationEngine:
             exe = self._offload_save_exe.get(bucket)
             if exe is not None:
                 return exe
-            args = (self._kcs, self._vcs,
-                    np.full(bucket, NULL_BLOCK, np.int32))
+            args = (self._pools, np.full(bucket, NULL_BLOCK, np.int32))
             with self._profiler.record("generation.compile"):
                 exe = compile_memoized(export_block_run, args, ())
             self.metrics.inc("compiles")
@@ -1103,14 +1105,12 @@ class GenerationEngine:
             exe = self._offload_load_exe.get(bucket)
             if exe is not None:
                 return exe
-            rows_k = [kv_zeros((bucket,) + s, self.kv_dtype)
-                      for s in self._cache.layer_shapes]
-            rows_v = [kv_zeros((bucket,) + s, self.kv_dtype)
-                      for s in self._cache.layer_shapes]
-            args = (self._kcs, self._vcs, rows_k, rows_v,
+            rows = [kv_pool_zeros((bucket,) + s, self.kv_dtype)
+                    for s in self._cache.layer_shapes]
+            args = (self._pools, rows,
                     np.full(bucket, NULL_BLOCK, np.int32))
             with self._profiler.record("generation.compile"):
-                exe = compile_memoized(import_block_run, args, (0, 1))
+                exe = compile_memoized(import_block_run, args, (0,))
             self.metrics.inc("compiles")
             self._offload_load_exe[bucket] = exe
             return exe
@@ -1125,18 +1125,15 @@ class GenerationEngine:
         idx = np.full(bucket, NULL_BLOCK, np.int32)
         idx[:len(blocks)] = blocks
         with self._profiler.record("generation.offload_demote"):
-            k_rows, v_rows = self._get_offload_save_exe(bucket)(
-                self._kcs, self._vcs, idx)
-            ks = [kv_pack_host(r, len(blocks)) for r in k_rows]
-            vs = [kv_pack_host(r, len(blocks)) for r in v_rows]
-        return HostRun(tokens, ks, vs, self.kv_dtype)
+            rows = self._get_offload_save_exe(bucket)(self._pools, idx)
+            layers = [kv_pack_host(r, len(blocks)) for r in rows]
+        return HostRun(tokens, layers, self.kv_dtype)
 
     def _build_restore_ops(self, run: HostRun, bucket: int):
         """Zero-pad a HostRun's packed layers up to ``bucket`` rows —
         the scatter executable's operands. Pure host/h2d work: this is
         the half a prefetch overlaps with admission."""
-        return ([kv_unpack_host(layer, bucket) for layer in run.ks],
-                [kv_unpack_host(layer, bucket) for layer in run.vs])
+        return [kv_unpack_host(layer, bucket) for layer in run.layers]
 
     def _import_run(self, run: HostRun, blocks: List[int], ops=None):
         """Device half of a restore: scatter the packed run into the
@@ -1148,11 +1145,10 @@ class GenerationEngine:
         idx[:len(blocks)] = blocks
         if ops is None:
             ops = self._build_restore_ops(run, bucket)
-        k_rows, v_rows = ops
         with self._profiler.record("generation.offload_restore"):
-            self._kcs, self._vcs = self._get_offload_load_exe(bucket)(
-                self._kcs, self._vcs, k_rows, v_rows, idx)
-            jax.block_until_ready(self._kcs[0])  # surface device faults
+            self._pools = self._get_offload_load_exe(bucket)(
+                self._pools, ops, idx)
+            jax.block_until_ready(self._pools[0])  # surface device faults
 
     def _demote_session(self, sess) -> bool:
         """Copy an evicted session's block run to the host tier (the
@@ -1416,7 +1412,7 @@ class GenerationEngine:
             vb = self._vbucket
             if self.cache_backend == "paged":
                 fn = make_verify_paged_fn(self.model)
-                args = (self.model._params, self._kcs, self._vcs,
+                args = (self.model._params, self._pools,
                         np.zeros((1, vb), np.int32), np.int32(0),
                         np.int32(1),
                         np.full(tbl_bucket, NULL_BLOCK, np.int32),
@@ -1429,7 +1425,10 @@ class GenerationEngine:
                         np.int32(1), np.int32(0), np.uint32(0),
                         np.int32(0), np.float32(0.0), np.int32(0))
             with self._profiler.record("generation.compile"):
-                exe = compile_memoized(fn, args, self._donate)
+                exe = compile_memoized(
+                    fn, args,
+                    (1,) if self.cache_backend == "paged"
+                    else self._donate)
             self.metrics.inc("compiles")
             self._verify_exe[key] = exe
             return exe
@@ -2355,9 +2354,9 @@ class GenerationEngine:
                 self._fail(req, e)
                 return None
             try:
-                (first, okd, self._kcs, self._vcs, self._state,
+                (first, okd, self._pools, self._state,
                  counters) = exe(
-                    self.model._params, self._kcs, self._vcs,
+                    self.model._params, self._pools,
                     self._state, tokens, np.int32(p0), np.int32(clen),
                     table, np.int32(st.slot), np.uint32(req.seed),
                     np.float32(req.temperature), np.int32(req.top_k))
@@ -2559,8 +2558,7 @@ class GenerationEngine:
             # after the rebuild
             self._update_block_gauges()
         self._cache = self._fresh_cache()
-        self._kcs = self._cache.ks
-        self._vcs = self._cache.vs
+        self._bind_cache()
         self._state = self._fresh_state()   # chunks rebuild it
         if self.speculation_k:
             self._reset_draft_cache()
@@ -2608,8 +2606,7 @@ class GenerationEngine:
             self._prefix_index.clear()
             self._sessions.clear()
         self._cache = self._fresh_cache()
-        self._kcs = self._cache.ks
-        self._vcs = self._cache.vs
+        self._bind_cache()
         self._state = self._fresh_state()   # chunks rebuild it
         if self.speculation_k:
             # the draft cache may hold donated-away device state too;
@@ -2901,14 +2898,19 @@ class GenerationEngine:
             try:
                 with self._profiler.record("generation.spec_verify"), \
                         self._sched.phase("decode_wait", spec="verify"):
-                    tgt, n_acc, vok, self._kcs, self._vcs = \
-                        self._get_verify_exe(tv if paged else None)(
-                            self.model._params, self._kcs, self._vcs,
-                            tokens, np.int32(p0), np.int32(k + 1),
+                    exe = self._get_verify_exe(tv if paged else None)
+                    span = (tokens, np.int32(p0), np.int32(k + 1),
                             *extra, np.uint32(req.seed),
                             np.int32(st.step[s]),
                             np.float32(req.temperature),
                             np.int32(req.top_k))
+                    if paged:
+                        tgt, n_acc, vok, self._pools = exe(
+                            self.model._params, self._pools, *span)
+                    else:
+                        tgt, n_acc, vok, self._kcs, self._vcs = exe(
+                            self.model._params, self._kcs, self._vcs,
+                            *span)
                     tgt = np.asarray(tgt)
                     n_acc = int(np.asarray(n_acc))
                     vok = bool(np.asarray(vok))
@@ -3026,10 +3028,9 @@ class GenerationEngine:
             self._account_step_blocks(active)
             counters = ()
             if self.cache_backend == "paged":
-                (nxt, okd, dnd, self._kcs, self._vcs, self._state,
+                (nxt, okd, dnd, self._pools, self._state,
                  counters) = self._get_decode_exe()(
-                        self.model._params, self._kcs, self._vcs,
-                        self._state,
+                        self.model._params, self._pools, self._state,
                         st.token.copy(), self._no_dev_tok,
                         self._all_host, st.pos.copy(),
                         self._tables.copy(), st.seed.copy(),
@@ -3135,10 +3136,9 @@ class GenerationEngine:
             use_host = ~self._tok_on_dev
             counters = ()
             if self.cache_backend == "paged":
-                (nxt, okd, dnd, self._kcs, self._vcs, self._state,
+                (nxt, okd, dnd, self._pools, self._state,
                  counters) = self._get_decode_exe()(
-                        self.model._params, self._kcs, self._vcs,
-                        self._state,
+                        self.model._params, self._pools, self._state,
                         st.token.copy(), tok_dev, use_host,
                         st.pos.copy(), self._tables.copy(),
                         st.seed.copy(), st.step.copy(), st.temp.copy(),

@@ -9,8 +9,8 @@ on demand (SURVEY §L0 host/device workspaces + ``memcpyAsync`` in
 ``NativeOps.h``), not be discarded. This module is that cheaper tier:
 
 - :class:`HostRun` — one demoted block run: the token history plus
-  per-layer contiguous numpy copies of the K and V pool rows AT THE
-  POOL DTYPE (int8 values + f32 scale sidecars ride together, so the
+  per-layer contiguous numpy copies of the pool rows (K and V side by
+  side, as the pool stores them) AT THE POOL DTYPE (int8 values + f32 scale sidecars ride together, so the
   PR 15 4× byte saving carries straight into host GB and PCIe
   traffic).
 - :class:`DiskRing` — optional third tier: a fixed-size mmap'd ring
@@ -52,27 +52,24 @@ import numpy as np
 
 class HostRun:
     """One demoted block run: ``tokens`` (the K/V-valid token history,
-    int32 copy) plus per-layer packed K and V rows as produced by
+    int32 copy) plus per-layer packed pool rows as produced by
     :func:`~deeplearning4j_tpu.kernels.kv_quant.kv_pack_host` — each
     layer a tuple of contiguous numpy arrays (``(values,)`` for
-    f32/bf16 pools, ``(q, scale)`` for int8). ``nbytes`` is the host
-    footprint the byte budget charges."""
+    f32/bf16 pools, ``(q, scale)`` for int8), a row holding its K and
+    its V as the pool does. ``nbytes`` is the host footprint the byte
+    budget charges."""
 
-    __slots__ = ("tokens", "ks", "vs", "n_blocks", "kv_dtype", "nbytes")
+    __slots__ = ("tokens", "layers", "n_blocks", "kv_dtype", "nbytes")
 
     def __init__(self, tokens: np.ndarray,
-                 ks: Sequence[Tuple[np.ndarray, ...]],
-                 vs: Sequence[Tuple[np.ndarray, ...]],
+                 layers: Sequence[Tuple[np.ndarray, ...]],
                  kv_dtype: str):
         self.tokens = np.ascontiguousarray(np.asarray(tokens, np.int32))
-        self.ks = tuple(tuple(p for p in layer) for layer in ks)
-        self.vs = tuple(tuple(p for p in layer) for layer in vs)
-        self.n_blocks = int(self.ks[0][0].shape[0])
+        self.layers = tuple(tuple(p for p in layer) for layer in layers)
+        self.n_blocks = int(self.layers[0][0].shape[0])
         self.kv_dtype = str(kv_dtype)
         self.nbytes = int(self.tokens.nbytes
-                          + sum(p.nbytes for layer in self.ks
-                                for p in layer)
-                          + sum(p.nbytes for layer in self.vs
+                          + sum(p.nbytes for layer in self.layers
                                 for p in layer))
 
     # ---------------------------------------------- disk serialization
@@ -82,17 +79,13 @@ class HostRun:
         Meta holds every shape/dtype so :meth:`unpack` needs no pickle
         — plain concatenated buffers, self-describing and compact."""
         parts: List[np.ndarray] = [self.tokens]
-        for layer in self.ks:
-            parts.extend(layer)
-        for layer in self.vs:
+        for layer in self.layers:
             parts.extend(layer)
         meta = {
             "kv_dtype": self.kv_dtype,
             "n_blocks": self.n_blocks,
-            "k_layers": [[(p.shape, str(p.dtype)) for p in layer]
-                         for layer in self.ks],
-            "v_layers": [[(p.shape, str(p.dtype)) for p in layer]
-                         for layer in self.vs],
+            "layers": [[(p.shape, str(p.dtype)) for p in layer]
+                       for layer in self.layers],
             "n_tokens": int(self.tokens.shape[0]),
         }
         return b"".join(np.ascontiguousarray(p).tobytes()
@@ -111,11 +104,9 @@ class HostRun:
             return arr
 
         tokens = take((meta["n_tokens"],), np.int32)
-        ks = [tuple(take(s, d) for s, d in layer)
-              for layer in meta["k_layers"]]
-        vs = [tuple(take(s, d) for s, d in layer)
-              for layer in meta["v_layers"]]
-        return cls(tokens, ks, vs, meta["kv_dtype"])
+        layers = [tuple(take(s, d) for s, d in layer)
+                  for layer in meta["layers"]]
+        return cls(tokens, layers, meta["kv_dtype"])
 
 
 class DiskRing:

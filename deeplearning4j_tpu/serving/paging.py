@@ -5,9 +5,10 @@ The slot cache (:mod:`.kvcache`) preallocates ``max_seq_len`` tokens of
 K/V per slot, so memory scales with the WORST-CASE sequence length:
 a slot serving an 8-token completion pins the same bytes as one serving
 a 500-token one. Here the unit of allocation is a BLOCK of
-``block_size`` token positions inside one shared pool per layer::
+``block_size`` token positions inside one shared pool per layer, a
+position's key and value side by side in one row::
 
-    K, V : [num_blocks, n_heads, block_size, head_dim]
+    K | V : [num_blocks, n_heads, block_size, 2 * head_dim]
 
 A sequence owns ceil((prompt + max_tokens) / block_size) blocks — its
 ACTUAL worst case, not the engine's — and a block table maps its
@@ -59,7 +60,8 @@ import numpy as np
 
 from ..kernels.kv_quant import (canonical_kv_dtype, kv_bytes_per_token,
                                 kv_gather_rows, kv_nbytes,
-                                kv_scatter_rows, kv_zeros)
+                                kv_scatter_rows)
+from ..kernels.paged_attention import kv_pool_zeros
 
 #: Block index reserved as the write/read target for padded table
 #: entries. Never handed out by the allocator.
@@ -224,18 +226,29 @@ class PagedKVCache:
     by every sequence instead of per-sequence slots.
 
     ``layer_shapes`` are per-layer ``(n_heads, block_size, head_dim)``
-    — i.e. ``model.cache_shapes(block_size)``.
+    — i.e. ``model.cache_shapes(block_size)``. ``pools`` holds ONE
+    array a layer, ``[num_blocks, n_heads, block_size, 2 * head_dim]``:
+    a position's key in the first ``head_dim`` lanes of its row and its
+    value in the rest (`kernels/paged_attention.py` owns the layout and
+    says why: for a head of 64 a row is one 128-lane vector row, the
+    default device layout is the row-major one every program asks for,
+    and no program relays a pool). At block 16 a f32 or bf16 pool is
+    stored without padding (8- and 16-row tiles of 128 lanes); an int8
+    pool's values are tiled 32 rows deep, so a block of 16 positions
+    takes the room of 32 on the device (:meth:`nbytes` counts the
+    bytes held, not the padding), and block 32 is the size that wastes
+    nothing there.
 
     ``kv_dtype`` selects the storage precision (ROADMAP item 3):
-    ``"f32"`` (exact, default), ``"bf16"``, or ``"int8"`` — per-layer
-    pools become
-    :class:`~deeplearning4j_tpu.kernels.kv_quant.QuantArray` pytrees
-    with a ``[num_blocks, H, block_size]`` f32 scale sidecar, i.e.
-    per-block-per-head scales indexed by block id (the block is the
-    quantization granule). Copy-on-write and the no-zeroing-on-reuse
-    contract carry over unchanged: a block copy copies its scale row,
-    a recycled block's stale (quantized) tail stays masked by the next
-    owner's length."""
+    ``"f32"`` (exact, default), ``"bf16"``, or ``"int8"`` — a layer's
+    pool becomes a
+    :class:`~deeplearning4j_tpu.kernels.kv_quant.QuantArray` pytree
+    with a ``[num_blocks, 2, H, block_size]`` f32 scale sidecar (the
+    keys' scales, then the values'), i.e. per-block-per-head scales
+    indexed by block id (the block is the quantization granule).
+    Copy-on-write and the no-zeroing-on-reuse contract carry over
+    unchanged: a block copy copies its scale rows, a recycled block's
+    stale (quantized) tail stays masked by the next owner's length."""
 
     def __init__(self, layer_shapes: Sequence[Tuple[int, int, int]],
                  num_blocks: int, kv_dtype: str = "f32"):
@@ -243,11 +256,8 @@ class PagedKVCache:
         self.layer_shapes = [tuple(s) for s in layer_shapes]
         self.block_size = int(self.layer_shapes[0][1])
         self.kv_dtype = canonical_kv_dtype(kv_dtype)
-        self.ks: List = [
-            kv_zeros((self.num_blocks,) + s, self.kv_dtype)
-            for s in self.layer_shapes]
-        self.vs: List = [
-            kv_zeros((self.num_blocks,) + s, self.kv_dtype)
+        self.pools: List = [
+            kv_pool_zeros((self.num_blocks,) + s, self.kv_dtype)
             for s in self.layer_shapes]
 
     def nbytes(self) -> int:
@@ -280,24 +290,22 @@ class PagedKVCache:
         return kv_bytes_per_token(self.layer_shapes, self.kv_dtype)
 
 
-def export_block_run(kcs, vcs, idx):
-    """Pure fn: gather pool rows ``idx`` out of every layer's K and V
-    pool — the device half of a demotion. Traced into one executable
+def export_block_run(pools, idx):
+    """Pure fn: gather pool rows ``idx`` out of every layer's pool —
+    the device half of a demotion. Traced into one executable
     per pow2 idx bucket by the engine (pools NOT donated: a failed
     demotion must leave the device tier untouched)."""
-    return ([kv_gather_rows(k, idx) for k in kcs],
-            [kv_gather_rows(v, idx) for v in vcs])
+    return [kv_gather_rows(p, idx) for p in pools]
 
 
-def import_block_run(kcs, vcs, k_rows, v_rows, idx):
+def import_block_run(pools, rows, idx):
     """Pure fn: scatter gathered runs back into pool rows ``idx`` —
     the device half of a restore. Padded idx entries point at
     :data:`NULL_BLOCK` so junk writes land where nothing is ever read.
     The engine compiles this with pools DONATED (a restore writes in
     place), so a real failure here is a
     :class:`~deeplearning4j_tpu.faults.CorruptedStateFault`."""
-    return ([kv_scatter_rows(k, r, idx) for k, r in zip(kcs, k_rows)],
-            [kv_scatter_rows(v, r, idx) for v, r in zip(vcs, v_rows)])
+    return [kv_scatter_rows(p, r, idx) for p, r in zip(pools, rows)]
 
 
 def chain_hashes(tokens: Sequence[int], block_size: int) -> List[bytes]:

@@ -152,17 +152,17 @@ def _verify_tail(logits, tokens, vlen, seed, step0, temp, top_k):
 def make_verify_paged_fn(model):
     """Paged verification: ``forward_prefill_chunk`` — the warmed
     runtime-offset chunk kernel, unchanged — plus the shared
-    accept/sample tail. Returns ``verify(params, kcs, vcs,
+    accept/sample tail. Returns ``verify(params, pools,
     tokens [1, C], p0, vlen, table, seed, step0, temp, top_k) ->
-    (tgt [C], n_accepted, ok, kcs, vcs)``."""
+    (tgt [C], n_accepted, ok, pools)``."""
 
-    def verify(params, kcs, vcs, tokens, p0, vlen, table, seed, step0,
+    def verify(params, pools, tokens, p0, vlen, table, seed, step0,
                temp, top_k):
-        logits, kcs, vcs = model.forward_prefill_chunk(
-            params, tokens, p0, vlen, kcs, vcs, table)
+        logits, pools, _ = model.forward_prefill_chunk(
+            params, tokens, p0, vlen, pools, table)
         tgt, n_acc, ok = _verify_tail(logits, tokens, vlen, seed,
                                       step0, temp, top_k)
-        return tgt, n_acc, ok, kcs, vcs
+        return tgt, n_acc, ok, pools
     return verify
 
 

@@ -41,9 +41,9 @@ from typing import Dict, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 
-from ..kernels.kv_quant import kv_set
-from ..kernels.paged_attention import paged_attention
-from ..nn.layers.attention import _gather_span, _span_attend
+from ..kernels.paged_attention import (gather_span, kv_pool_set,
+                                       paged_attention)
+from ..nn.layers.attention import _span_attend
 from ..nn.layers.moe import moe_ffn
 
 #: layout of the int32 vector both forwards return beside the logits:
@@ -247,20 +247,21 @@ class Lfm2MoeLM:
                                    preferred_element_type=jnp.float32)
 
     # -- the two served forwards -----------------------------------------
-    def forward_decode_paged(self, params, tokens, pos, k_pools, v_pools,
+    def forward_decode_paged(self, params, tokens, pos, pools,
                              block_tables, impl: str = "auto", *,
                              state, live):
         """One decode step for the slot batch. tokens, pos [S]; pools
-        [N, H_kv, Bs, D] an attention layer; block_tables [S, B];
+        [N, H_kv, Bs, 2 * D] an attention layer (key and value side by
+        side: `kernels/paged_attention.py`); block_tables [S, B];
         ``state`` as :meth:`slot_state_shapes` declares; ``live`` [S]
         bool: a lane that is not live writes no state, routes to no
         expert and counts nowhere (its K/V write lands in the null
-        block, as its table says). Returns (logits [S, V], k_pools,
-        v_pools, state, counters)."""
+        block, as its table says). Returns (logits [S, V], pools,
+        state, counters)."""
         S = tokens.shape[0]
-        Bs = k_pools[0].shape[2] if k_pools else 1
+        Bs = pools[0].shape[2] if pools else 1
         x = params["embed"][tokens].astype(jnp.float32)
-        k_pools, v_pools, state = list(k_pools), list(v_pools), list(state)
+        pools, state = list(pools), list(state)
         counts: List[Dict] = []
         ai = ci = 0
         for i, w in enumerate(params["layers"]):
@@ -279,35 +280,33 @@ class Lfm2MoeLM:
                     at = (blk[:, None],
                           jnp.arange(self.n_kv_heads)[None, :],
                           (pos % Bs)[:, None])
-                    k_pools[ai] = kv_set(k_pools[ai], at, k)
-                    v_pools[ai] = kv_set(v_pools[ai], at, v)
-                    att = paged_attention(q, k_pools[ai], v_pools[ai],
-                                          block_tables, pos + 1, impl=impl)
+                    pools[ai] = kv_pool_set(pools[ai], at, k, v)
+                    att = paged_attention(q, pools[ai], block_tables,
+                                          pos + 1, impl=impl)
                     op = self._mm(att.reshape(S, self.d_model), w["Wo"])
                 ai += 1
             x = x + op
             x = x + self._ff(i, w, rms_norm(x, w["ffn_norm"], self.norm_eps),
                              live, counts)
-        return (self._logits(params, x), k_pools, v_pools, state,
+        return (self._logits(params, x), pools, state,
                 self._counters(counts))
 
-    def forward_prefill_chunk(self, params, tokens, p0, chunk_len,
-                              k_pools, v_pools, block_table, *, state,
-                              slot):
+    def forward_prefill_chunk(self, params, tokens, p0, chunk_len, pools,
+                              block_table, *, state, slot):
         """One prefill chunk of the request in ``slot``. tokens [1, C];
         p0, chunk_len scalars; block_table [n_blocks]. The chunk reads
         row ``slot`` of every state array (zeros instead where
         ``p0 == 0``: a request never inherits its slot's last occupant)
         and writes back the state after its last valid row. Rows past
         ``chunk_len`` route to no expert. Returns (logits [C, V],
-        k_pools, v_pools, state, counters)."""
+        pools, state, counters)."""
         C = tokens.shape[1]
-        Bs = k_pools[0].shape[2] if k_pools else 1
+        Bs = pools[0].shape[2] if pools else 1
         gpos = p0 + jnp.arange(C)
         live = jnp.arange(C) < chunk_len
         x = params["embed"][tokens[0]].astype(jnp.float32)
         x = jnp.where(live[:, None], x, 0.0)
-        k_pools, v_pools, state = list(k_pools), list(v_pools), list(state)
+        pools, state = list(pools), list(state)
         counts: List[Dict] = []
         keep = self.conv_taps - 1
         ai = ci = 0
@@ -332,12 +331,8 @@ class Lfm2MoeLM:
                     at = (block_table[gpos // Bs][:, None],
                           jnp.arange(self.n_kv_heads)[None, :],
                           (gpos % Bs)[:, None])
-                    k_pools[ai] = kv_set(k_pools[ai], at, k)
-                    v_pools[ai] = kv_set(v_pools[ai], at, v)
-                    kk = _gather_span(k_pools[ai], block_table,
-                                      self.n_kv_heads, self.head_dim)
-                    vv = _gather_span(v_pools[ai], block_table,
-                                      self.n_kv_heads, self.head_dim)
+                    pools[ai] = kv_pool_set(pools[ai], at, k, v)
+                    kk, vv = gather_span(pools[ai], block_table)
                     att = _span_attend(q, kk, vv, gpos, p0 + C,
                                        jnp.float32)
                     op = self._mm(att.reshape(C, self.d_model), w["Wo"])
@@ -345,5 +340,5 @@ class Lfm2MoeLM:
             x = x + op
             x = x + self._ff(i, w, rms_norm(x, w["ffn_norm"], self.norm_eps),
                              live, counts)
-        return (self._logits(params, x), k_pools, v_pools, state,
+        return (self._logits(params, x), pools, state,
                 self._counters(counts))

@@ -129,27 +129,29 @@ class CausalTransformerLM:
         return x @ params["head"], new_k, new_v
 
     # -- paged KV cache (serving/paging) --------------------------------
-    def forward_decode_paged(self, params, tokens, pos, k_pools, v_pools,
-                             block_tables, impl: str = "auto"):
+    def forward_decode_paged(self, params, tokens, pos, pools,
+                             block_tables, impl: str = "auto", state=()):
         """One cached decode step against the PAGED pools. Same
-        contract as :meth:`forward_decode` with per-layer pools
-        [num_blocks, H, block_size, Dh] addressed through
+        contract as :meth:`forward_decode` with one pool a layer,
+        [num_blocks, H, block_size, 2 * Dh] (a position's key and value
+        side by side: `kernels/paged_attention.py`), addressed through
         ``block_tables`` [S, n_blocks] (NULL_BLOCK-padded; inactive
-        rows must be all-NULL so their writes land in the null
-        block)."""
+        rows must be all-NULL so their writes land in the null block).
+        ``state`` is what the engine threads through every paged
+        program for a model that keeps arrays a slot; this one keeps
+        none and hands it back as it came.
+        Returns (logits [S, V], pools, state)."""
         x = params["tok"][tokens] + params["pos"][pos]
-        new_k, new_v = [], []
-        for blk, bp, kc, vc in zip(self.blocks, params["blocks"],
-                                   k_pools, v_pools):
-            x, kc, vc = blk.apply_decode_paged(bp, x, kc, vc,
-                                               block_tables, pos, impl)
-            new_k.append(kc)
-            new_v.append(vc)
+        new_pools = []
+        for blk, bp, pool in zip(self.blocks, params["blocks"], pools):
+            x, pool = blk.apply_decode_paged(bp, x, pool, block_tables,
+                                             pos, impl)
+            new_pools.append(pool)
         x = layer_norm(x, params["lnf_g"], params["lnf_b"])
-        return x @ params["head"], new_k, new_v
+        return x @ params["head"], new_pools, state
 
     def forward_prefill_chunk(self, params, tokens, p0, chunk_len,
-                              k_pools, v_pools, block_table):
+                              pools, block_table, state=()):
         """One prefill CHUNK against the paged pools: embed the chunk
         at its global positions, run every block's
         ``apply_prefill_paged`` (scatter K/V into the owning blocks,
@@ -161,8 +163,9 @@ class CausalTransformerLM:
         tokens: [1, C] int32 (C = chunk bucket); p0: scalar int32
         chunk start; chunk_len: scalar int32 valid tokens in this
         chunk; block_table: [n_blocks] int32 covering at least
-        ``p0 + C`` positions. Returns (logits [C, V], k_pools,
-        v_pools)."""
+        ``p0 + C`` positions; ``state`` as in
+        :meth:`forward_decode_paged`.
+        Returns (logits [C, V], pools, state)."""
         C = tokens.shape[1]
         gpos = p0 + jnp.arange(C)
         # padded tail rows can run past the position table; clamp the
@@ -172,16 +175,13 @@ class CausalTransformerLM:
              + params["pos"][jnp.clip(gpos, 0, self.max_seq_len - 1)])
         row_mask = (jnp.arange(C) < chunk_len).astype(x.dtype)
         x = (x * row_mask[:, None])[None]
-        new_k, new_v = [], []
-        for blk, bp, kc, vc in zip(self.blocks, params["blocks"],
-                                   k_pools, v_pools):
-            x, kc, vc = blk.apply_prefill_paged(bp, x, kc, vc,
-                                                block_table, p0,
-                                                chunk_len)
-            new_k.append(kc)
-            new_v.append(vc)
+        new_pools = []
+        for blk, bp, pool in zip(self.blocks, params["blocks"], pools):
+            x, pool = blk.apply_prefill_paged(bp, x, pool, block_table,
+                                              p0, chunk_len)
+            new_pools.append(pool)
         x = layer_norm(x[0], params["lnf_g"], params["lnf_b"])
-        return x @ params["head"], new_k, new_v
+        return x @ params["head"], new_pools, state
 
     def forward_verify(self, params, tokens, p0, chunk_len, k_caches,
                        v_caches, slot):

@@ -10,6 +10,7 @@
 - `chip_smoke.py` refuses to run without a TPU.
 """
 import os
+import re
 import subprocess
 import sys
 
@@ -22,7 +23,7 @@ from deeplearning4j_tpu.kernels.decode_attention import \
 from deeplearning4j_tpu.kernels.flash_attention import flash_attention
 from deeplearning4j_tpu.kernels.kv_quant import QuantArray
 from deeplearning4j_tpu.kernels.paged_attention import (
-    KERNEL_NAME, paged_attention_pallas)
+    KERNEL_NAME, gather_span, kv_pool_set, paged_attention_pallas)
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -49,14 +50,19 @@ def v5e():
     assert topo.devices[0].device_kind == "TPU v5 lite"
     sharding = SingleDeviceSharding(topo.devices[0])
 
-    def compile_for(fn, *args):
+    def compile_for(fn, *args, donate=(), kernel=True):
         args = jax.tree_util.tree_map(
             lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
                                            sharding=sharding), args)
-        text = jax.jit(fn).lower(*args).compile().as_text()
-        assert "tpu_custom_call" in text, "no Mosaic kernel in the program"
+        text = jax.jit(fn, donate_argnums=donate).lower(
+            *args).compile().as_text()
+        assert ("tpu_custom_call" in text) == kernel, \
+            "no Mosaic kernel in the program" if kernel else "a kernel"
         return text
     return compile_for
+
+
+_DT = {"f32": jnp.float32, "bf16": jnp.bfloat16}
 
 
 def _kv(shape, dt):
@@ -65,7 +71,17 @@ def _kv(shape, dt):
     if dt == "int8":
         return QuantArray(sds(shape, jnp.int8),
                           sds(shape[:-1], jnp.float32))
-    return sds(shape, {"f32": jnp.float32, "bf16": jnp.bfloat16}[dt])
+    return sds(shape, _DT[dt])
+
+
+def _pool(N, H, Bs, D, dt):
+    """Abstract paged pool for K (== V) blocks [N, H, Bs, D] at ``dt``:
+    one array, K and V side by side on the lanes."""
+    sds = jax.ShapeDtypeStruct
+    if dt == "int8":
+        return QuantArray(sds((N, H, Bs, 2 * D), jnp.int8),
+                          sds((N, 2, H, Bs), jnp.float32))
+    return sds((N, H, Bs, 2 * D), _DT[dt])
 
 
 def _custom_calls(text):
@@ -81,11 +97,11 @@ def test_paged_kernels_custom_call_is_named_after_the_kernel(v5e):
     benchmark's kernel metrics take any named custom call)."""
     S, H, D, Bs, T = (SHAPES["served"][k] for k in ("S", "H", "D", "Bs", "T"))
     sds = jax.ShapeDtypeStruct
-    pool = sds((S * (T // Bs) + 1, H, Bs, D), jnp.float32)
+    pool = _pool(S * (T // Bs) + 1, H, Bs, D, "f32")
 
-    def step(q, k, v, t, l):
-        return paged_attention_pallas(q, k, v, t, l, interpret=False)
-    text = v5e(step, sds((S, H, D), jnp.float32), pool, pool,
+    def step(q, kv, t, l):
+        return paged_attention_pallas(q, kv, t, l, interpret=False)
+    text = v5e(step, sds((S, H, D), jnp.float32), pool,
                sds((S, T // Bs), jnp.int32), sds((S,), jnp.int32))
     assert _custom_calls(text) == [KERNEL_NAME]
 
@@ -97,10 +113,10 @@ def test_decode_kernels_compile_for_v5e(v5e, size, dt):
     B = T // Bs
     sds = jax.ShapeDtypeStruct
     q, lens = sds((S, H, D), jnp.float32), sds((S,), jnp.int32)
-    pool = _kv((SHAPES[size].get("N", S * B + 1), H, Bs, D), dt)
-    text = v5e(lambda q, k, v, t, l: paged_attention_pallas(
-        q, k, v, t, l, interpret=False),
-        q, pool, pool, sds((S, B), jnp.int32), lens)
+    pool = _pool(SHAPES[size].get("N", S * B + 1), H, Bs, D, dt)
+    text = v5e(lambda q, kv, t, l: paged_attention_pallas(
+        q, kv, t, l, interpret=False),
+        q, pool, sds((S, B), jnp.int32), lens)
     # one kernel for the whole call, under the name the trace reads
     assert _custom_calls(text) == [KERNEL_NAME]
     cache = _kv((S, H, T, D), dt)
@@ -112,12 +128,72 @@ def test_grouped_query_paged_kernel_compiles_for_v5e_at_the_cells_widths(v5e):
     """``lfm2-8b-a1b.decode_backlog``: 32 query heads over 8 KV heads of
     64, a bf16 pool of 1,025 blocks of 16, 16 slots."""
     sds = jax.ShapeDtypeStruct
-    pool = sds((1025, 8, 16, 64), jnp.bfloat16)
-    text = v5e(lambda q, k, v, t, l: paged_attention_pallas(
-        q, k, v, t, l, interpret=False),
-        sds((16, 32, 64), jnp.float32), pool, pool,
+    text = v5e(lambda q, kv, t, l: paged_attention_pallas(
+        q, kv, t, l, interpret=False),
+        sds((16, 32, 64), jnp.float32), _pool(1025, 8, 16, 64, "bf16"),
         sds((16, 64), jnp.int32), sds((16,), jnp.int32))
     assert _custom_calls(text) == [KERNEL_NAME]
+
+
+# -- the pool's layout: no program relays a pool (ISSUE 31) -------------------
+#: the two cells' attention: slots, query heads, KV heads, pool blocks, dtype
+CELLS = {"gpt2-xl": (16, 25, 25, 321, "f32"),
+         "lfm2-8b-a1b": (16, 32, 8, 1025, "bf16")}
+
+
+def _decode_layer(pool, q, k, v, tables, pos):
+    """What one layer of ``jit_step`` does to its pool: the step's rows
+    written, then the kernel over the table."""
+    Bs = pool.shape[2]
+    blk = jnp.take_along_axis(tables, (pos // Bs)[:, None], axis=1)[:, 0]
+    pool = kv_pool_set(pool, (blk[:, None], jnp.arange(k.shape[1])[None],
+                              (pos % Bs)[:, None]), k, v)
+    return paged_attention_pallas(q, pool, tables, pos + 1,
+                                  interpret=False), pool
+
+
+def _chunk_layer(pool, k, v, table, p0):
+    """What one layer of ``jit_chunk`` does to its pool: the chunk's
+    rows written, then the sequence's span gathered out."""
+    Bs = pool.shape[2]
+    gpos = p0 + jnp.arange(k.shape[0])
+    pool = kv_pool_set(pool, (table[gpos // Bs][:, None],
+                              jnp.arange(k.shape[1])[None],
+                              (gpos % Bs)[:, None]), k, v)
+    kk, vv = gather_span(pool, table)
+    return kk.astype(jnp.float32).sum() + vv.astype(jnp.float32).sum(), pool
+
+
+@pytest.mark.parametrize("program", ["step", "chunk"])
+@pytest.mark.parametrize("cell", list(CELLS))
+def test_a_donated_pool_goes_through_a_layer_with_no_relayout(
+        v5e, cell, program):
+    """At both cells' shapes the compiled layer holds no ``copy`` whose
+    result has the pool's shape, takes the pool in the default row-major
+    tiled layout and aliases its output to it; the step has exactly the
+    one kernel. (Two arrays ``[N, H, Bs, 64]`` compiled to four and six
+    pool-sized copies here: 39 of ``gpt2-xl``'s 62 ms a step.)"""
+    S, Hq, Hkv, N, dt = CELLS[cell]
+    D, Bs, B, C = 64, 16, 64, 256
+    sds = jax.ShapeDtypeStruct
+    pool = _pool(N, Hkv, Bs, D, dt)
+    rows = lambda n: sds((n, Hkv, D), jnp.float32)        # noqa: E731
+    if program == "step":
+        text = v5e(_decode_layer, pool, sds((S, Hq, D), jnp.float32),
+                   rows(S), rows(S), sds((S, B), jnp.int32),
+                   sds((S,), jnp.int32), donate=(0,))
+        assert _custom_calls(text) == [KERNEL_NAME]
+    else:
+        text = v5e(_chunk_layer, pool, rows(C), rows(C),
+                   sds((B,), jnp.int32), sds((), jnp.int32), donate=(0,),
+                   kernel=False)
+    shape = "%s[%d,%d,%d,%d]" % (dt, N, Hkv, Bs, 2 * D)
+    copies = [ln.strip()[:120] for ln in text.splitlines()
+              if re.search(r"= " + re.escape(shape) + r"\S* copy\(", ln)]
+    assert copies == []
+    (entry,) = re.findall(r"entry_computation_layout=\{\((\S+)", text)
+    assert entry.startswith(shape + "{3,2,1,0:T(8,128)")
+    assert re.search(r"input_output_alias=\{ \{1\}: \(0, \{\}", text)
 
 
 @pytest.mark.parametrize("pairs", [64, 1024], ids=["decode", "chunk"])

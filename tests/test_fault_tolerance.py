@@ -89,30 +89,27 @@ class _PoisonLM(CausalTransformerLM):
                for v in vcs]
         return jnp.where(bad[:, None], jnp.nan, logits), kcs, vcs
 
-    def forward_decode_paged(self, params, tokens, pos, k_pools,
-                             v_pools, block_tables, impl="auto"):
-        logits, kcs, vcs = super().forward_decode_paged(
-            params, tokens, pos, k_pools, v_pools, block_tables, impl)
+    def forward_decode_paged(self, params, tokens, pos, pools,
+                             block_tables, impl="auto", state=()):
+        logits, pools, state = super().forward_decode_paged(
+            params, tokens, pos, pools, block_tables, impl, state)
         logits = self._rig(logits)
         bad = (tokens == POISON)
-        # poison the pool position this step wrote for the bad rows
-        Bs = kcs[0].shape[2]
+        # poison the pool row (K and V) this step wrote for the bad rows
+        Bs = pools[0].shape[2]
         blk = jnp.take_along_axis(block_tables, (pos // Bs)[:, None],
                                   axis=1)[:, 0]
         off = pos % Bs
         nan3 = jnp.where(bad[:, None, None], jnp.nan, 0.0)
-        kcs = [k.at[blk, :, off].set(k[blk, :, off] + nan3)
-               for k in kcs]
-        vcs = [v.at[blk, :, off].set(v[blk, :, off] + nan3)
-               for v in vcs]
-        return jnp.where(bad[:, None], jnp.nan, logits), kcs, vcs
+        pools = [p.at[blk, :, off].set(p[blk, :, off] + nan3)
+                 for p in pools]
+        return jnp.where(bad[:, None], jnp.nan, logits), pools, state
 
     def forward_prefill_chunk(self, params, tokens, p0, chunk_len,
-                              k_pools, v_pools, block_table):
+                              pools, block_table, state=()):
         # same rig for the paged chunked-prefill path: logits [C, V]
-        logits, kcs, vcs = super().forward_prefill_chunk(
-            params, tokens, p0, chunk_len, k_pools, v_pools,
-            block_table)
+        logits, pools, state = super().forward_prefill_chunk(
+            params, tokens, p0, chunk_len, pools, block_table, state)
         logits = self._rig(logits)
         trig = jnp.any(tokens == TRIGGER)
         hot = jnp.where(jnp.arange(self.vocab_size) == POISON,
@@ -122,16 +119,14 @@ class _PoisonLM(CausalTransformerLM):
         logits = jnp.where(nan_trig, jnp.nan, logits)
         # poison every pool position this chunk wrote (its own blocks)
         C = tokens.shape[1]
-        Bs = kcs[0].shape[2]
+        Bs = pools[0].shape[2]
         gpos = p0 + jnp.arange(C)
         blk = block_table[gpos // Bs]
         off = gpos % Bs
         nan3 = jnp.where(nan_trig, jnp.nan, 0.0)
-        kcs = [k.at[blk, :, off].set(k[blk, :, off] + nan3)
-               for k in kcs]
-        vcs = [v.at[blk, :, off].set(v[blk, :, off] + nan3)
-               for v in vcs]
-        return logits, kcs, vcs
+        pools = [p.at[blk, :, off].set(p[blk, :, off] + nan3)
+                 for p in pools]
+        return logits, pools, state
 
 
 #: mixed-length workload; prompts avoid the poison-rig token ids

@@ -87,18 +87,16 @@ def _offsnap(eng):
 # ---------------------------------------------------------------------------
 def _run_f32(ntok=12, nblk=3, seed=0):
     rng = np.random.RandomState(seed)
-    mk = lambda: (rng.randn(nblk, 2, 8, 16).astype(np.float32),)  # noqa: E731
-    return HostRun(np.arange(ntok, dtype=np.int32),
-                   [mk(), mk()], [mk(), mk()], "f32")
+    mk = lambda: (rng.randn(nblk, 2, 8, 32).astype(np.float32),)  # noqa: E731
+    return HostRun(np.arange(ntok, dtype=np.int32), [mk(), mk()], "f32")
 
 
 def _run_int8(ntok=12, nblk=3, seed=0):
     rng = np.random.RandomState(seed)
-    mk = lambda: (rng.randint(-128, 128, (nblk, 2, 8, 16),  # noqa: E731
+    mk = lambda: (rng.randint(-128, 128, (nblk, 2, 8, 32),  # noqa: E731
                               dtype=np.int8),
-                  rng.rand(nblk, 2, 8).astype(np.float32))
-    return HostRun(np.arange(ntok, dtype=np.int32),
-                   [mk(), mk()], [mk(), mk()], "int8")
+                  rng.rand(nblk, 2, 2, 8).astype(np.float32))
+    return HostRun(np.arange(ntok, dtype=np.int32), [mk(), mk()], "int8")
 
 
 class TestHostRun:
@@ -111,7 +109,7 @@ class TestHostRun:
         np.testing.assert_array_equal(back.tokens, run.tokens)
         assert back.kv_dtype == run.kv_dtype
         assert back.n_blocks == run.n_blocks
-        for a, b in zip(run.ks + run.vs, back.ks + back.vs):
+        for a, b in zip(run.layers, back.layers):
             assert len(a) == len(b)
             for pa, pb in zip(a, b):
                 np.testing.assert_array_equal(pa, pb)
@@ -119,7 +117,7 @@ class TestHostRun:
     def test_nbytes_counts_every_part(self):
         run = _run_int8()
         want = run.tokens.nbytes + sum(
-            p.nbytes for layer in run.ks + run.vs for p in layer)
+            p.nbytes for layer in run.layers for p in layer)
         assert run.nbytes == want
         payload, _ = run.pack()
         assert len(payload) == want
@@ -135,7 +133,8 @@ class TestDiskRing:
             run = _run_f32()
             assert ring.put("a", *run.pack())
             back = ring.get("a")
-            np.testing.assert_array_equal(back.ks[0][0], run.ks[0][0])
+            np.testing.assert_array_equal(back.layers[0][0],
+                                          run.layers[0][0])
             assert ring.get("nope") is None
         finally:
             ring.close()
@@ -217,8 +216,8 @@ class TestHostBlockStore:
             assert st["disk_bytes"] > 0
             # disk hit rebuilds the run bit-exactly, without promotion
             back = store.get("a")
-            np.testing.assert_array_equal(back.ks[0][0],
-                                          runs["a"].ks[0][0])
+            np.testing.assert_array_equal(back.layers[0][0],
+                                          runs["a"].layers[0][0])
             assert store.peek("a") is None     # still on disk only
             assert sorted(store.keys()) == ["a", "b", "c"]
         finally:

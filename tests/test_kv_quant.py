@@ -26,7 +26,7 @@ from deeplearning4j_tpu.kernels.kv_quant import (QuantArray, QuantWeight,
                                                  quantize_rows,
                                                  quantize_weight)
 from deeplearning4j_tpu.kernels.paged_attention import (
-    gather_blocks, paged_attention_pallas, paged_attention_xla)
+    fuse_kv, gather_blocks, paged_attention_pallas, paged_attention_xla)
 from deeplearning4j_tpu.serving import (FaultInjector, GenerationEngine,
                                         InferenceServer,
                                         PoisonRequestError)
@@ -62,6 +62,17 @@ def _quant_cache(x, kv_dtype):
     if kv_dtype == "bf16":
         return x.astype(jnp.bfloat16)
     return x
+
+
+def _quant_pool(kp, vp, kv_dtype):
+    """f32 K and V blocks [N, H, Bs, D] -> the paged pool that stores
+    them at ``kv_dtype``: side by side, each half by its own scales."""
+    return fuse_kv(_quant_cache(kp, kv_dtype), _quant_cache(vp, kv_dtype))
+
+
+def _cache_arrays(eng):
+    """A layer's cache arrays, either backend."""
+    return getattr(eng._cache, "pools", None) or eng._cache.ks
 
 
 def _run_all(eng, reqs, seed0=0):
@@ -245,23 +256,27 @@ class TestPagedKernelQuant:
     @pytest.mark.parametrize("dt", ["bf16", "int8"])
     def test_pallas_matches_xla_quantized(self, dt):
         q, kp, vp, tables, lens = self._inputs()
-        kq, vq = _quant_cache(kp, dt), _quant_cache(vp, dt)
-        a = np.asarray(paged_attention_xla(q, kq, vq, tables, lens))
-        b = np.asarray(paged_attention_pallas(q, kq, vq, tables, lens,
+        pool = _quant_pool(kp, vp, dt)
+        a = np.asarray(paged_attention_xla(q, pool, tables, lens))
+        b = np.asarray(paged_attention_pallas(q, pool, tables, lens,
                                               interpret=True))
         np.testing.assert_allclose(a, b, rtol=2e-2, atol=2e-2)
-        ref = np.asarray(paged_attention_xla(q, kp, vp, tables, lens))
+        ref = np.asarray(paged_attention_xla(q, fuse_kv(kp, vp), tables,
+                                             lens))
         np.testing.assert_allclose(a, ref, rtol=6e-2, atol=6e-2)
 
     def test_gather_blocks_carries_scales(self):
         q, kp, vp, tables, lens = self._inputs()
-        g = gather_blocks(quantize_rows(kp), tables)
-        assert is_quantized(g)
-        assert g.scale.shape == g.q.shape[:-1]
-        np.testing.assert_allclose(
-            np.asarray(dequantize(g)),
-            np.asarray(gather_blocks(np.asarray(dequantize(
-                quantize_rows(kp))), tables)), rtol=1e-6)
+        kq, vq = quantize_rows(kp), quantize_rows(vp)
+        pool = fuse_kv(kq, vq)
+        assert pool.scale.shape == (8, 2, 4, 4)     # K's, then V's
+        plain = gather_blocks(fuse_kv(dequantize(kq), dequantize(vq)),
+                              tables)
+        for g, want in zip(gather_blocks(pool, tables), plain):
+            assert is_quantized(g)
+            assert g.scale.shape == g.q.shape[:-1]
+            np.testing.assert_allclose(np.asarray(dequantize(g)),
+                                       np.asarray(want), rtol=1e-6)
 
     @pytest.mark.parametrize("dt", ["bf16", "int8"])
     def test_stale_block_poison_ignored_quantized(self, dt):
@@ -269,19 +284,21 @@ class TestPagedKernelQuant:
         the live length (or padded as NULL) — successors must not see
         it."""
         q, kp, vp, tables, lens = self._inputs()
-        base_k, base_v = _quant_cache(kp, dt), _quant_cache(vp, dt)
         kp2 = kp.at[2].set(jnp.nan)    # seq 0 reads block 2 past len 3
         vp2 = vp.at[2].set(jnp.nan)
         lens2 = jnp.array([3, 8, 8], jnp.int32)   # nobody reads blk 2 live
-        poi_k, poi_v = _quant_cache(kp2, dt), _quant_cache(vp2, dt)
         for impl in (paged_attention_xla,
                      lambda *a: paged_attention_pallas(*a,
                                                        interpret=True)):
-            base = np.asarray(impl(q, base_k, base_v, tables, lens2))
-            poisoned = np.asarray(impl(q, poi_k, poi_v, tables, lens2))
-            assert np.isfinite(poisoned).all()
-            np.testing.assert_allclose(base, poisoned, rtol=1e-5,
-                                       atol=1e-6)
+            base = np.asarray(impl(q, _quant_pool(kp, vp, dt), tables,
+                                   lens2))
+            # the keys' half of the block poisoned, the values', both
+            for k2, v2 in ((kp2, vp2), (kp2, vp), (kp, vp2)):
+                poisoned = np.asarray(impl(q, _quant_pool(k2, v2, dt),
+                                           tables, lens2))
+                assert np.isfinite(poisoned).all()
+                np.testing.assert_allclose(base, poisoned, rtol=1e-5,
+                                           atol=1e-6)
 
     @pytest.mark.parametrize("H,Bs,G", [(4, 4, 2), (4, 8, 2), (25, 16, 4)])
     @pytest.mark.parametrize("dt", ["bf16", "int8"])
@@ -293,9 +310,9 @@ class TestPagedKernelQuant:
         from test_paged_generation import chunks_of, ragged_paged_case
         chunks_of(monkeypatch, G)
         q, kp, vp, tables, lens = ragged_paged_case(H, Bs, 8, G, seed=3)
-        kq, vq = _quant_cache(kp, dt), _quant_cache(vp, dt)
-        a = np.asarray(paged_attention_xla(q, kq, vq, tables, lens))
-        b = np.asarray(paged_attention_pallas(q, kq, vq, tables, lens,
+        pool = _quant_pool(kp, vp, dt)
+        a = np.asarray(paged_attention_xla(q, pool, tables, lens))
+        b = np.asarray(paged_attention_pallas(q, pool, tables, lens,
                                               interpret=True))
         assert np.isfinite(b).all()
         assert np.abs(b[0]).max() == 0.0            # the empty lane
@@ -304,8 +321,7 @@ class TestPagedKernelQuant:
     def test_mixed_quant_raises(self):
         q, kp, vp, tables, lens = self._inputs()
         with pytest.raises(ValueError, match="quantized together"):
-            paged_attention_pallas(q, quantize_rows(kp), vp, tables,
-                                   lens, interpret=True)
+            fuse_kv(quantize_rows(kp), vp)
 
 
 # ---------------------------------------------------------------------------
@@ -346,11 +362,11 @@ class TestEngineKVDtypes:
             st = eng.stats()
             assert st["kv_dtype"] == dt
             assert st["kv_bits"] == {"f32": 32, "bf16": 16, "int8": 8}[dt]
-            T_or_Bs = eng._cache.ks[0].shape[2]
+            T_or_Bs = _cache_arrays(eng)[0].shape[2]
             assert st["kv_bytes_per_token"] == kv_bytes_per_token(
                 lm.cache_shapes(T_or_Bs), dt)
             if dt == "int8":
-                assert is_quantized(eng._cache.ks[0])
+                assert is_quantized(_cache_arrays(eng)[0])
                 assert st["quant"]["scale_bytes"] > 0
             else:
                 assert st["quant"]["scale_bytes"] == 0
@@ -394,12 +410,12 @@ class _CachePoisonLM(CausalTransformerLM):
         return logits, ks, vs
 
     def forward_prefill_chunk(self, params, tokens, p0, chunk_len,
-                              k_pools, v_pools, block_table):
-        logits, kcs, vcs = super().forward_prefill_chunk(
-            params, tokens, p0, chunk_len, k_pools, v_pools, block_table)
+                              pools, block_table, state=()):
+        logits, pools, state = super().forward_prefill_chunk(
+            params, tokens, p0, chunk_len, pools, block_table, state)
         bad = jnp.any(tokens == NAN_TRIGGER)
         C = tokens.shape[1] if tokens.ndim > 1 else tokens.shape[0]
-        Bs = (kcs[0].q if is_quantized(kcs[0]) else kcs[0]).shape[2]
+        Bs = pools[0].shape[2]
         gpos = p0 + jnp.arange(C)
         blk = block_table[gpos // Bs]
         off = gpos % Bs
@@ -408,12 +424,13 @@ class _CachePoisonLM(CausalTransformerLM):
         def poison(pool):
             if is_quantized(pool):
                 # int8 pools carry poison in the f32 scale sidecar
+                # ([N, 2, H, Bs]: the keys' scales and the values')
                 s = pool.scale
-                s = s.at[blk, :, off].set(s[blk, :, off] + add)
+                s = s.at[blk, :, :, off].set(s[blk, :, :, off] + add)
                 return QuantArray(pool.q, s)
             return pool.at[blk, :, off].set(pool[blk, :, off] + add)
 
-        return logits, [poison(k) for k in kcs], [poison(v) for v in vcs]
+        return logits, [poison(p) for p in pools], state
 
 
 class TestQuarantine:
@@ -586,7 +603,7 @@ class TestRecoveryQuantized:
             assert eng.metrics.compiles - c0 == 0    # same exe, new pool
             # the rebuilt pool is still an int8 QuantArray (type check
             # only — the buffers themselves are donated every step)
-            assert is_quantized(eng._cache.ks[0])
+            assert is_quantized(_cache_arrays(eng)[0])
         finally:
             eng.stop()
 

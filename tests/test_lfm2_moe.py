@@ -40,9 +40,9 @@ from benchmark import run  # noqa: E402
 from deeplearning4j_tpu.faults import FaultInjector, TransientFault  # noqa: E402
 from deeplearning4j_tpu.kernels.moe_experts import expert_ffn  # noqa: E402
 from deeplearning4j_tpu.kernels.paged_attention import (  # noqa: E402
-    paged_attention_pallas, paged_attention_xla)
+    fuse_kv, paged_attention_pallas, paged_attention_xla)
 from deeplearning4j_tpu.nn.layers.moe import NORM_EPS, moe_ffn, route  # noqa: E402
-from deeplearning4j_tpu.serving import InferenceServer  # noqa: E402
+from deeplearning4j_tpu.serving import InferenceServer, PagedKVCache  # noqa: E402
 from deeplearning4j_tpu.serving.generation import GenerationEngine  # noqa: E402
 from _obs_util import assert_exposition_parity, parse_prometheus  # noqa: E402
 
@@ -114,8 +114,7 @@ def serve_by_hand(lm, seq, chunks, slot=1, slots=3, poison=True):
     paged pools and the slot state, as the engine's programs call the
     two forwards."""
     Bs, N = 8, 20
-    kcs = [jnp.zeros((N,) + s) for s in lm.cache_shapes(Bs)]
-    vcs = [jnp.zeros((N,) + s) for s in lm.cache_shapes(Bs)]
+    pools = PagedKVCache(lm.cache_shapes(Bs), N).pools
     fill = jnp.nan if poison else 0.0       # the slot's last occupant
     state = [jnp.full(shape, fill, dt)
              for shape, dt in lm.slot_state_shapes(slots)]
@@ -125,10 +124,9 @@ def serve_by_hand(lm, seq, chunks, slot=1, slots=3, poison=True):
     for clen, bucket in chunks:
         toks = np.zeros((1, bucket), np.int32)
         toks[0, :clen] = seq[p0:p0 + clen]
-        lg, kcs, vcs, state, cnt = lm.forward_prefill_chunk(
+        lg, pools, state, cnt = lm.forward_prefill_chunk(
             lm._params, jnp.asarray(toks), jnp.int32(p0), jnp.int32(clen),
-            kcs, vcs, jnp.asarray(table), state=state,
-            slot=jnp.int32(slot))
+            pools, jnp.asarray(table), state=state, slot=jnp.int32(slot))
         got.append(np.asarray(lg)[:clen])
         counters.append(np.asarray(cnt))
         p0 += clen
@@ -140,8 +138,8 @@ def serve_by_hand(lm, seq, chunks, slot=1, slots=3, poison=True):
         toks = np.zeros(slots, np.int32)
         pos = np.zeros(slots, np.int32)
         toks[slot], pos[slot] = seq[t], t
-        lg, kcs, vcs, state, cnt = lm.forward_decode_paged(
-            lm._params, jnp.asarray(toks), jnp.asarray(pos), kcs, vcs,
+        lg, pools, state, cnt = lm.forward_decode_paged(
+            lm._params, jnp.asarray(toks), jnp.asarray(pos), pools,
             jnp.asarray(tables), "xla", state=state, live=jnp.asarray(live))
         got.append(np.asarray(lg)[slot][None])
         counters.append(np.asarray(cnt))
@@ -272,8 +270,8 @@ def test_paged_kernel_with_one_query_head_a_kv_head_is_bit_equal_to_pr27s():
                    np.int32)
     lens = np.array([19, 40, 0], np.int32)
     out = np.asarray(paged_attention_pallas(
-        jnp.asarray(q), jnp.asarray(k), jnp.asarray(v), jnp.asarray(tbl),
-        jnp.asarray(lens), interpret=True))
+        jnp.asarray(q), fuse_kv(jnp.asarray(k), jnp.asarray(v)),
+        jnp.asarray(tbl), jnp.asarray(lens), interpret=True))
     want = np.load(os.path.join(HERE, "fixtures",
                                 "paged_kernel_pr27_output.npy"))
     assert np.array_equal(out, want)
@@ -290,15 +288,15 @@ def test_grouped_query_paged_kernel_matches_xla(hq, hkv, dt, tol):
     vp = jnp.asarray(rng.normal(size=(N, hkv, Bs, D)), dt)
     tbl = jnp.asarray(rng.integers(1, N, (S, B)), jnp.int32)
     lens = jnp.asarray([0, 1, 17, 64, 96], jnp.int32)
-    got = paged_attention_pallas(q, kp, vp, tbl, lens, interpret=True)
-    want = paged_attention_xla(q, kp, vp, tbl, lens)
+    pool = fuse_kv(kp, vp)
+    got = paged_attention_pallas(q, pool, tbl, lens, interpret=True)
+    want = paged_attention_xla(q, pool, tbl, lens)
     assert float(jnp.abs(got - want).max()) <= tol
     # query head i reads KV head i // g: the same call with the KV
     # heads repeated and no grouping gives the same numbers (not the
     # same bits: the blocks a chunk holds follow from the KV heads)
     g = hq // hkv
-    flat = paged_attention_pallas(q, jnp.repeat(kp, g, 1),
-                                  jnp.repeat(vp, g, 1), tbl, lens,
+    flat = paged_attention_pallas(q, jnp.repeat(pool, g, 1), tbl, lens,
                                   interpret=True)
     assert float(jnp.abs(got - flat).max()) <= 2e-6
 

@@ -17,13 +17,20 @@ import numpy as np
 import pytest
 
 from deeplearning4j_tpu.kernels.decode_attention import decode_attention_xla
+from deeplearning4j_tpu.kernels.kv_quant import (is_quantized, kv_copy_row,
+                                                 kv_pack_host,
+                                                 kv_unpack_host,
+                                                 quantize_rows)
 from deeplearning4j_tpu.kernels.paged_attention import (
-    gather_blocks, paged_attention_pallas, paged_attention_xla)
+    fuse_kv, gather_blocks, kv_pool_set, paged_attention_pallas,
+    paged_attention_xla, split_kv)
 from deeplearning4j_tpu.nn.layers.attention import TransformerEncoderLayer
 from deeplearning4j_tpu.serving import (BlockAllocator, BlockTable,
                                         ClientError, GenerationEngine,
                                         InferenceServer, PagedKVCache)
 from deeplearning4j_tpu.serving.paging import (NULL_BLOCK, blocks_for,
+                                               export_block_run,
+                                               import_block_run,
                                                pow2_bucket)
 from deeplearning4j_tpu.zoo.transformer_lm import CausalTransformerLM
 
@@ -58,7 +65,9 @@ def ragged_paged_case(H, Bs, D, G, seed=0):
     not in pool order; and NaN wherever no key lives: in a pool block
     no table names, in the block every table entry past the length
     names, and in the tail of each lane's last live block. Returns
-    (q, k_pool, v_pool, tables, lengths), the pools f32."""
+    (q, k_pool, v_pool, tables, lengths), the pools f32 and apart
+    ([N, H, Bs, D] each): ``fuse_kv`` lays them side by side as the
+    kernel reads them."""
     B = 2 * G + 1
     lens = sorted({0, 1, Bs - 1, Bs, Bs + 1, G * Bs - 1, G * Bs,
                    G * Bs + 1, 2 * G * Bs, B * Bs})
@@ -168,6 +177,93 @@ class TestBlockAllocator:
         # 2 layers * K+V * 10 blocks * 2*8*4 f32
         assert pool.nbytes() == 2 * 2 * 10 * 2 * 8 * 4 * 4
         assert pool.block_nbytes() * 10 == pool.nbytes()
+        # one array a layer, K and V side by side on the lanes: the
+        # bytes counted are the bytes held
+        assert [p.shape for p in pool.pools] == [(10, 2, 8, 8)] * 2
+        assert sum(p.nbytes for p in pool.pools) == pool.nbytes()
+
+
+# ---------------------------------------------------------------------------
+# the pool's layout: K and V side by side in one row
+# ---------------------------------------------------------------------------
+def _halves(pool):
+    """The f32 K and V blocks [N, H, Bs, D] a pool of any dtype holds."""
+    if is_quantized(pool):
+        k, v = split_kv(pool.q.astype(jnp.float32))
+        return (np.asarray(k * pool.scale[:, 0, ..., None]),
+                np.asarray(v * pool.scale[:, 1, ..., None]))
+    return tuple(np.asarray(x, np.float32) for x in split_kv(pool))
+
+
+class TestPoolLayout:
+    TOL = {"f32": 0.0, "bf16": 2e-2, "int8": 3e-2}
+
+    @pytest.mark.parametrize("dt", ["f32", "bf16", "int8"])
+    @pytest.mark.parametrize("rows", ["step", "chunk"])
+    def test_written_rows_land_in_their_lanes(self, rows, dt):
+        """A decode step's rows (one a lane, at its position) and a
+        chunk's (consecutive positions of one table): the key in lanes
+        0..D-1 of (block, head, offset), the value in D..2D-1, and
+        nothing else touched."""
+        N, H, Bs, D = 7, 3, 4, 8
+        (pool,) = PagedKVCache([(H, Bs, D)], N, kv_dtype=dt).pools
+        if rows == "step":
+            tables = np.array([[5, 2], [3, 0], [6, 1]], np.int32)
+            pos = np.array([6, 1, 3], np.int32)
+            blk, off = tables[np.arange(3), pos // Bs], pos % Bs
+        else:
+            table = np.array([4, 1, 6], np.int32)
+            gpos = 3 + np.arange(6)             # positions 3..8
+            blk, off = table[gpos // Bs], gpos % Bs
+        ks = jax.random.split(jax.random.PRNGKey(1), 2)
+        k = jax.random.normal(ks[0], (len(blk), H, D))
+        v = jax.random.normal(ks[1], (len(blk), H, D))
+        pool = kv_pool_set(pool, (jnp.asarray(blk)[:, None],
+                                  jnp.arange(H)[None, :],
+                                  jnp.asarray(off)[:, None]), k, v)
+        assert pool.shape == (N, H, Bs, 2 * D)
+        got_k, got_v = _halves(pool)
+        np.testing.assert_allclose(got_k[blk, :, off], np.asarray(k),
+                                   atol=self.TOL[dt])
+        np.testing.assert_allclose(got_v[blk, :, off], np.asarray(v),
+                                   atol=self.TOL[dt])
+        dark = np.ones((N, Bs), bool)
+        dark[blk, off] = False
+        assert not got_k.transpose(0, 2, 1, 3)[dark].any()
+        assert not got_v.transpose(0, 2, 1, 3)[dark].any()
+
+    @pytest.mark.parametrize("dt", ["f32", "bf16", "int8"])
+    def test_block_copy_and_host_round_trip_carry_both_halves(self, dt):
+        """Copy-on-write's block copy, and a demotion's gather ->
+        packed host arrays -> a restore's scatter into other blocks:
+        keys, values and (int8) both halves' scales move together."""
+        N, H, Bs, D = 9, 2, 4, 8
+        ks = jax.random.split(jax.random.PRNGKey(2), 2)
+        k = jax.random.normal(ks[0], (N, H, Bs, D))
+        v = jax.random.normal(ks[1], (N, H, Bs, D))
+        cast = {"f32": lambda x: x, "bf16": lambda x: x.astype(
+            jnp.bfloat16), "int8": quantize_rows}[dt]
+        pool = fuse_kv(cast(k), cast(v))
+        want_k, want_v = _halves(pool)
+        got_k, got_v = _halves(kv_copy_row(pool, 3, 7))
+        np.testing.assert_array_equal(got_k[7], want_k[3])
+        np.testing.assert_array_equal(got_v[7], want_v[3])
+        np.testing.assert_array_equal(got_k[:7], want_k[:7])
+        # blocks 5, 2, 8 out (a bucket of 4: one NULL-padded entry) ...
+        src = jnp.asarray([5, 2, 8, NULL_BLOCK], jnp.int32)
+        (rows,) = export_block_run([pool], src)
+        host = kv_pack_host(rows, 3)
+        assert host[0].shape == (3, H, Bs, 2 * D)
+        assert len(host) == (2 if dt == "int8" else 1)
+        # ... and back into blocks 1, 6, 4 of an empty pool
+        (fresh,) = PagedKVCache([(H, Bs, D)], N, kv_dtype=dt).pools
+        dst = jnp.asarray([1, 6, 4, NULL_BLOCK], jnp.int32)
+        (fresh,) = import_block_run([fresh], [kv_unpack_host(host, 4)],
+                                    dst)
+        got_k, got_v = _halves(fresh)
+        np.testing.assert_array_equal(got_k[[1, 6, 4]], want_k[[5, 2, 8]])
+        np.testing.assert_array_equal(got_v[[1, 6, 4]], want_v[[5, 2, 8]])
+        assert not got_k[[2, 3, 5, 7, 8]].any()
 
 
 # ---------------------------------------------------------------------------
@@ -186,8 +282,9 @@ class TestPagedAttentionKernel:
 
     def test_pallas_matches_xla(self):
         q, kp, vp, tbl, lens = self._setup()
-        a = np.asarray(paged_attention_xla(q, kp, vp, tbl, lens))
-        b = np.asarray(paged_attention_pallas(q, kp, vp, tbl, lens,
+        pool = fuse_kv(kp, vp)
+        a = np.asarray(paged_attention_xla(q, pool, tbl, lens))
+        b = np.asarray(paged_attention_pallas(q, pool, tbl, lens,
                                               interpret=True))
         np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-6)
 
@@ -196,10 +293,20 @@ class TestPagedAttentionKernel:
         must agree exactly (this equivalence is what makes
         paged-vs-slot token identity hold at the engine level)."""
         q, kp, vp, tbl, lens = self._setup()
-        a = np.asarray(paged_attention_xla(q, kp, vp, tbl, lens))
-        dense = np.asarray(decode_attention_xla(
-            q, gather_blocks(kp, tbl), gather_blocks(vp, tbl), lens))
+        pool = fuse_kv(kp, vp)
+        a = np.asarray(paged_attention_xla(q, pool, tbl, lens))
+        kd, vd = gather_blocks(pool, tbl)
+        dense = np.asarray(decode_attention_xla(q, kd, vd, lens))
         np.testing.assert_allclose(a, dense, rtol=0, atol=0)
+        # and the panels are the blocks' own rows, in table order
+        np.testing.assert_array_equal(
+            np.asarray(kd[1, :, :12]),
+            np.asarray(kp)[[2, 5, 7]].transpose(1, 0, 2, 3).reshape(
+                4, 12, 8))
+        np.testing.assert_array_equal(
+            np.asarray(vd[2]),
+            np.asarray(vp)[[9, 8, 6, 4]].transpose(1, 0, 2, 3).reshape(
+                4, 16, 8))
 
     def test_empty_lane_is_zero_not_nan(self):
         q, kp, vp, tbl, lens = self._setup()
@@ -207,7 +314,7 @@ class TestPagedAttentionKernel:
         for impl in (paged_attention_xla,
                      lambda *a: paged_attention_pallas(*a,
                                                       interpret=True)):
-            out = np.asarray(impl(q, kp, vp, tbl, lens))
+            out = np.asarray(impl(q, fuse_kv(kp, vp), tbl, lens))
             assert np.isfinite(out).all()
             assert np.abs(out[0]).max() == 0.0
 
@@ -221,16 +328,20 @@ class TestPagedAttentionKernel:
         # table holds positions 4..7 -> offsets 1..3 are dead); NaN is
         # the hard case — a quarantined request's freed blocks keep
         # their non-finite K/V, and 0 * NaN = NaN would leak through
+        # (either half of a row alone, and both: the halves share a
+        # row, and neither may reach the other's sum)
         for tail in (99.0, jnp.nan):
             for impl in (paged_attention_xla,
                          lambda *a: paged_attention_pallas(
                              *a, interpret=True)):
-                base = np.asarray(impl(q, kp, vp, tbl, lens))
+                base = np.asarray(impl(q, fuse_kv(kp, vp), tbl, lens))
                 kp2 = kp.at[1, :, 2:].set(tail)
                 vp2 = vp.at[1, :, 2:].set(-tail)
-                poisoned = np.asarray(impl(q, kp2, vp2, tbl, lens))
-                np.testing.assert_allclose(base[0], poisoned[0],
-                                           rtol=1e-6)
+                for pool in (fuse_kv(kp2, vp2), fuse_kv(kp2, vp),
+                             fuse_kv(kp, vp2)):
+                    poisoned = np.asarray(impl(q, pool, tbl, lens))
+                    np.testing.assert_allclose(base[0], poisoned[0],
+                                               rtol=1e-6)
 
     @pytest.mark.parametrize("H,Bs,D,G", [
         (4, 4, 8, 2), (4, 8, 8, 2), (4, 16, 8, 4),
@@ -239,8 +350,9 @@ class TestPagedAttentionKernel:
             self, monkeypatch, H, Bs, D, G):
         chunks_of(monkeypatch, G)
         q, kp, vp, tbl, lens = ragged_paged_case(H, Bs, D, G)
-        a = np.asarray(paged_attention_xla(q, kp, vp, tbl, lens))
-        b = np.asarray(paged_attention_pallas(q, kp, vp, tbl, lens,
+        pool = fuse_kv(kp, vp)
+        a = np.asarray(paged_attention_xla(q, pool, tbl, lens))
+        b = np.asarray(paged_attention_pallas(q, pool, tbl, lens,
                                               interpret=True))
         assert np.isfinite(b).all()
         assert np.abs(b[0]).max() == 0.0            # the empty lane
@@ -276,22 +388,20 @@ class TestPagedLayerParity:
         p = lay.init_params(jax.random.PRNGKey(0))
         x = jax.random.normal(jax.random.PRNGKey(1), (B, T, C))
         y_full, _, _ = lay.apply_seq(p, x, None, False, None, (), None)
-        pool_shape = (6,) + lay.cache_shape(Bs)
-        kp = jnp.zeros(pool_shape)
-        vp = jnp.zeros(pool_shape)
+        (pool,) = PagedKVCache([lay.cache_shape(Bs)], 6).pools
         tbl = jnp.asarray(BlockTable([2, 4, 1], Bs).padded(4))
         # prefill positions 0..3 in two chunks of 2
         for p0 in (0, 2):
-            y_c, kp, vp = lay.apply_prefill_paged(
-                p, x[:, p0:p0 + 2], kp, vp, tbl, np.int32(p0),
+            y_c, pool = lay.apply_prefill_paged(
+                p, x[:, p0:p0 + 2], pool, tbl, np.int32(p0),
                 np.int32(2))
             np.testing.assert_allclose(np.asarray(y_c[0]),
                                        np.asarray(y_full[0, p0:p0 + 2]),
                                        atol=1e-5)
         # decode positions 4..7 one at a time
         for t in range(4, T):
-            o, kp, vp = lay.apply_decode_paged(
-                p, x[:, t], kp, vp, tbl[None], jnp.array([t], jnp.int32))
+            o, pool = lay.apply_decode_paged(
+                p, x[:, t], pool, tbl[None], jnp.array([t], jnp.int32))
             np.testing.assert_allclose(np.asarray(o),
                                        np.asarray(y_full[:, t]),
                                        atol=1e-5)
@@ -305,16 +415,15 @@ class TestPagedLayerParity:
         mask = (jnp.arange(bucket)[None] < L).astype(jnp.float32)
         logits_d, _, _ = lm.forward_prefill(lm._params, toks, mask)
         last_dense = np.asarray(logits_d[0, L - 1])
-        pool = PagedKVCache(lm.cache_shapes(Bs), num_blocks=8)
-        kp, vp = pool.ks, pool.vs
+        pools = PagedKVCache(lm.cache_shapes(Bs), num_blocks=8).pools
         tbl = jnp.asarray(BlockTable([3, 1, 5], Bs).padded(4))
         last_chunk = None
         for p0 in range(0, L, C):
             clen = min(C, L - p0)
             ct = np.zeros((1, C), np.int32)
             ct[0, :clen] = prompt[p0:p0 + clen]
-            logits_c, kp, vp = lm.forward_prefill_chunk(
-                lm._params, ct, np.int32(p0), np.int32(clen), kp, vp,
+            logits_c, pools, _ = lm.forward_prefill_chunk(
+                lm._params, ct, np.int32(p0), np.int32(clen), pools,
                 tbl)
             last_chunk = np.asarray(logits_c[clen - 1])
         np.testing.assert_allclose(last_chunk, last_dense, atol=1e-5)

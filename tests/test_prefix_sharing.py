@@ -302,12 +302,12 @@ class TestSharingUnderFaults:
                                 timeout_ms=60_000)["tokens"]
             shared_blocks = sorted(eng._prefix_index.blocks())
             assert shared_blocks
-            before = [np.asarray(k)[shared_blocks] for k in eng._kcs]
+            before = [np.asarray(k)[shared_blocks] for k in eng._pools]
             with pytest.raises(PoisonRequestError):
                 eng.generate(_P16 + [NAN_TRIGGER], max_tokens=4,
                              timeout_ms=60_000)
             assert eng.metrics.quarantined == 1
-            after = [np.asarray(k)[shared_blocks] for k in eng._kcs]
+            after = [np.asarray(k)[shared_blocks] for k in eng._pools]
             for b, a in zip(before, after):
                 np.testing.assert_array_equal(b, a)
             again = eng.generate(_P16, max_tokens=4,

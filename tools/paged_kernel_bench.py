@@ -1,8 +1,9 @@
 #!/usr/bin/env python3
 """The paged decode kernel alone, on the chip, at the benchmark cell's
-shape (S 16, H 25, D 64, Bs 16, a table of 64, a pool of 321 blocks):
-48 calls in one program, which is what one decode step of GPT-2 XL
-makes, over three sets of lengths.
+shape (S 16, H 25, D 64, Bs 16, a table of 64, a pool of 321 blocks
+``[321, 25, 16, 128]``, K and V side by side): 48 calls in one program,
+which is what one decode step of GPT-2 XL makes, over three sets of
+lengths.
 
     chiprun -- python3 tools/paged_kernel_bench.py [--kv f32|bf16|int8]
         [--chunk-blocks 2,4,8,16] [--heads 25] [--kv-heads 25]
@@ -20,8 +21,8 @@ precision, and writes them to ``chiprun_out/paged_kernel_bench_<kv>.json``.
 keys), ``full`` 16 lanes of 320 (the pool holds no more), ``ones``
 every lane at length 1: the walk over the table and nothing else.
 A scratch tool of PR 27 (PERF.md sections 5 and 6: how ``G`` was
-chosen), kept for the PRs that change the pool's layout. It measures
-nothing off a TPU.
+chosen; swept again in PR 31, when the pool's layout changed). It
+measures nothing off a TPU.
 """
 from __future__ import annotations
 
@@ -72,8 +73,9 @@ def main(argv=None) -> int:
     q = jax.random.normal(ks[0], (S, H, D), jnp.float32)
     cast = {"f32": lambda x: x, "bf16": lambda x: x.astype(jnp.bfloat16),
             "int8": quantize_rows}[a.kv]
-    kp = cast(jax.random.normal(ks[1], (N, HKV, BS, D), jnp.float32))
-    vp = cast(jax.random.normal(ks[2], (N, HKV, BS, D), jnp.float32))
+    pool = pa.fuse_kv(
+        cast(jax.random.normal(ks[1], (N, HKV, BS, D), jnp.float32)),
+        cast(jax.random.normal(ks[2], (N, HKV, BS, D), jnp.float32)))
     itemsize = {"f32": 4, "bf16": 2, "int8": 1}[a.kv]
     rs = np.random.RandomState(0)
 
@@ -89,10 +91,10 @@ def main(argv=None) -> int:
     def step_of(fn):
         """``--layers`` calls in one program, each fed by the one
         before."""
-        def run(q, kp, vp, tbl, lens):
+        def run(q, pool, tbl, lens):
             return lax.fori_loop(
                 0, LAYERS,
-                lambda i, x: q + 1e-3 * fn(x, kp, vp, tbl, lens), q)
+                lambda i, x: q + 1e-3 * fn(x, pool, tbl, lens), q)
         return jax.jit(run)
 
     def ms(f, *args, n=10):
@@ -118,11 +120,11 @@ def main(argv=None) -> int:
     for case, lens in CASES.items():
         tbl, ln = tables(lens)
         with jax.default_matmul_precision("highest"):
-            ref = jax.jit(pa.paged_attention_xla)(q, kp, vp, tbl, ln)
+            ref = jax.jit(pa.paged_attention_xla)(q, pool, tbl, ln)
         for name, fn in variants.items():
             err = float(jnp.max(jnp.abs(
-                jax.jit(fn)(q, kp, vp, tbl, ln) - ref)))
-            t = ms(step_of(fn), q, kp, vp, tbl, ln)
+                jax.jit(fn)(q, pool, tbl, ln) - ref)))
+            t = ms(step_of(fn), q, pool, tbl, ln)
             res[f"{case}.{name}"] = {f"ms_per_{LAYERS}_calls": t,
                                      "max_err": err}
             print(f"{case:7s} {name:11s} {t:9.3f} ms / {LAYERS} calls   "

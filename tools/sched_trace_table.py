@@ -148,8 +148,8 @@ def trace_cell(workload: str, seed: int, seconds: float, out_dir: str,
     # keep every iteration of the account, not the eight longest
     build = kind.build_server
 
-    def build_and_lift(config, seed):
-        srv, gen = build(config, seed)
+    def build_and_lift(ctx):
+        srv, gen = build(ctx)
         gen.engine.metrics.scheduler.keep_slowest = 1 << 20
         return srv, gen
 
