@@ -3,8 +3,9 @@
 chip.
 
 One process (the only one that touches JAX) drives the two main paths
-through the entry points a user calls, at the full width of the one LM
-the runtime supports (GPT-2-small shape, random weights from a seed):
+through the entry points a user calls, at GPT-2-small's published shape
+(random weights from a seed; the configurations that are measured are
+the benchmark's, ``BENCHMARK.json``):
 
 1. kernels — every Pallas kernel ``impl="auto"`` reaches on a TPU, at
    the served shapes: the lowering holds a Mosaic custom call (so it is
@@ -32,7 +33,7 @@ import sys
 import threading
 import time
 
-# GPT-2-small: the one LM shape the runtime serves at a published width
+# GPT-2-small at its published width
 LM = dict(vocab_size=50257, d_model=768, n_layers=12, n_heads=12,
           d_ff=3072, max_seq_len=1024)
 NUM_SLOTS = 8

@@ -10,7 +10,7 @@ up, so a temp name, a pid or a timestamp in it never hits.
 The rule: whoever launches the program places the cache with
 ``JAX_COMPILATION_CACHE_DIR`` and the code then sets nothing; otherwise
 it is ``<checkout>/.jax_cache`` (git-ignored). Entry points
-(`chip_smoke.py`, `bench.py`'s legs) call :func:`place_compile_cache`
+(`chip_smoke.py`, `benchmark/run.py`) call :func:`place_compile_cache`
 before their first compile; no other code sets a cache directory.
 """
 from __future__ import annotations

@@ -349,8 +349,8 @@ class MnistDataSetIterator(ArrayDataSetIterator):
                 n = num_examples or (10000 if train else 2000)
                 imgs, labels = _synthetic_mnist(n, seed=1 if train else 2)
                 self.source = "synthetic"
-        # real data (either provenance) clears the synthetic flag BENCH
-        # and tests report
+        # real data (either provenance) clears the synthetic flag
+        # tests report
         self.synthetic = self.source == "synthetic"
         if num_examples:
             imgs, labels = imgs[:num_examples], labels[:num_examples]

@@ -7,9 +7,9 @@ re-handshake replays missed updates with exactly-once IDs
 (SURVEY §5.3, `MeshOrganizer.markNodeOffline/remapNode`) — and it
 proves that story with chaos-style tests that kill workers mid-run.
 This module is the one injector both runtimes consult: a seeded,
-scriptable :class:`FaultInjector` fired at named SEAMS so tests and
-the bench chaos probes can make serving *and* training fail in exactly
-the ways real deployments do, deterministically.
+scriptable :class:`FaultInjector` fired at named SEAMS so tests can
+make serving *and* training fail in exactly the ways real deployments
+do, deterministically.
 
 Serving seams (PR 4; fired by the engines in :mod:`.serving`):
 
@@ -47,8 +47,8 @@ Training seams (this PR; fired by
 - ``preempt``       — once per completed step; a fire raises
   :class:`PreemptionFault`, modelling the platform's SIGTERM: the
   supervised loop flushes a step-granular checkpoint and re-raises so
-  the caller can restart-and-resume (the bench chaos probe scripts
-  exactly this)
+  the caller can restart-and-resume
+  (tests/test_resilient_training.py scripts exactly this)
 
 Fault types injected at the raising seams:
 
@@ -262,8 +262,8 @@ class FaultInjector:
             f"injected transient fault at {seam!r} (call #{n})")
 
     def snapshot(self) -> Dict:
-        """Per-seam call/fire counters (for tests and the bench chaos
-        probes' reports). ``by_worker`` appears once any worker-scoped
+        """Per-seam call/fire counters (for tests and reports).
+        ``by_worker`` appears once any worker-scoped
         call happened: ``{seam: {worker: {"calls": n, "fired": m}}}``."""
         with self._lock:
             out = {"calls": dict(self._calls),

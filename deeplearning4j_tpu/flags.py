@@ -108,8 +108,8 @@ flags.declare(
 flags.declare(
     "flash_min_seq", "DL4J_TPU_FLASH_MIN_SEQ", 1024, int,
     "Minimum sequence length at which implementation='auto' selects the "
-    "Pallas flash kernel on TPU (tuned from measured crossover, see "
-    "BENCH extras attention_flash_vs_xla).")
+    "Pallas flash kernel on TPU (not yet read on the chip: PERF.md, "
+    "section 7, row 9).")
 
 # -- profiler / debugging (ref: OpExecutioner.ProfilingMode) --------------
 flags.declare(
@@ -134,13 +134,3 @@ flags.declare(
     "ui_port", "DL4J_TPU_UI_PORT", 9000, int,
     "Default port for the training UI stats server (ref: PlayUIServer "
     "org.deeplearning4j.ui.port).")
-
-# -- benchmarking ---------------------------------------------------------
-flags.declare(
-    "bench_iters", "DL4J_TPU_BENCH_ITERS", 0, int,
-    "Override the timed iteration count in bench.py (0 = per-model "
-    "default). Used to shorten smoke runs.")
-flags.declare(
-    "bench_skip_secondary", "DL4J_TPU_BENCH_SKIP_SECONDARY", False, _as_bool,
-    "Skip the secondary bench models (b128 / BERT / attention sweep / "
-    "word2vec) and report only the headline.")

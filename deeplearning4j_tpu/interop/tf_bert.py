@@ -11,8 +11,8 @@ expectations. Architecture matches the BERT encoder stack: learned
 token/position/segment embeddings, post-LN transformer blocks with
 erf-GELU, tanh pooler over [CLS], classifier head.
 
-Used by tests/fixtures/gen_tfgraphs.py (corpus case `bert_mini`), the
-BERT fine-tune test, and bench.py's BERT samples/sec line.
+Used by tests/fixtures/gen_tfgraphs.py (corpus case `bert_mini`) and
+the BERT fine-tune test.
 """
 from __future__ import annotations
 

@@ -41,8 +41,8 @@ from .kv_quant import is_quantized, kv_operands
 def _quantized_leg(x) -> bool:
     """True when a cache operand is int8 (QuantArray) or bf16 — the
     legs whose dots must run on bf16 operands so no f32 cache read
-    round-trips through HBM (checkable in StableHLO: the audit scans
-    dot OPERAND dtypes, tools/perf_audit.py::audit_kv_quant)."""
+    round-trips through HBM (checked in StableHLO, by dot OPERAND
+    dtypes: tests/test_kv_quant.py::TestDotOperandAudit)."""
     return is_quantized(x) or x.dtype == jnp.bfloat16
 
 

@@ -40,8 +40,8 @@ Design points:
   with bf16 operands and ``preferred_element_type=f32`` (int8 values
   in [-127, 127] cast to bf16 exactly, and MXU natively accumulates
   bf16xbf16 into f32). That makes "zero unintended f32 dots" a
-  checkable property of the lowered StableHLO
-  (tools/perf_audit.py::audit_kv_quant) instead of a hope.
+  checked property of the lowered StableHLO
+  (tests/test_kv_quant.py::TestDotOperandAudit) instead of a hope.
 """
 from __future__ import annotations
 

@@ -55,8 +55,8 @@ def _span_attend(q, kk, vv, gpos, p0c, out_dtype):
     (0 * NaN = NaN). Quantized legs run bf16-operand dots with f32
     accumulation, K scales applied post-dot and V scales folded into
     the probabilities — the same scale placement as the decode kernels
-    (kernels/decode_attention.py), checkable in StableHLO
-    (tools/perf_audit.py::audit_kv_quant)."""
+    (kernels/decode_attention.py), checked in StableHLO
+    (tests/test_kv_quant.py::TestDotOperandAudit)."""
     H, T, Dh = kk.shape
     C, Hq = q.shape[:2]
     if Hq != H:

@@ -250,8 +250,7 @@ class TrainingSupervisor:
         return True
 
     def snapshot(self) -> Dict:
-        """Counters for tests / GET-stats-style reporting / the bench
-        training_chaos probe."""
+        """Counters for tests and GET-stats-style reporting."""
         return {
             "retries": self.retries.value(),
             "anomalies_skipped": self.anomalies_skipped.value(),

@@ -78,7 +78,7 @@ batch-class work is shed first (503) so interactive p99 holds, and
 deadline-aware admission sheds requests whose budget is already blown
 before they burn a device step.
 
-Fault tolerance (:mod:`.faults`, docs/serving.md "Operating the
+Fault tolerance (:mod:`..faults`, docs/serving.md "Operating the
 server"): supervised engine loops retry transient step faults with
 bounded backoff and rebuild cache-corrupting failures by
 recompute-recovery (no accepted request is ever lost); poison requests
@@ -114,25 +114,23 @@ import os
 import signal
 import sys
 import threading
-import time
-from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 from typing import Any, Dict, Optional, Sequence
-from urllib.parse import parse_qs
 
 import jax
 import numpy as np
 
-from ..tracing import Tracer, new_request_id
+from ..faults import (CorruptedStateFault, FaultInjector,
+                      PoisonRequestError, TransientFault)
+from ..tracing import Tracer
+from .aio import AioReplicaFrontend
 from .batcher import (DeadlineExceededError, DrainingError, MicroBatcher,
                       QueueFullError)
 from .engine import ClientError, InferenceEngine, ServingError, next_bucket
-from .faults import (CorruptedStateFault, FaultInjector,
-                     PoisonRequestError, TransientFault)
 from .fleet import (FleetError, FleetMetrics, FleetRouter,
                     NoReplicasError, Replica, ReplicaFleet)
 from .generation import GenerationEngine
 from .kvcache import KVCache, SlotTable
-from .metrics import (HTTP_WRITE_SPAN, GenerationMetrics, ServingMetrics,
+from .metrics import (GenerationMetrics, ServingMetrics,
                       profiler_sections, prometheus_text)
 from .offload import DiskRing, HostBlockStore, HostRun
 from .paging import BlockAllocator, BlockTable, PagedKVCache
@@ -177,26 +175,6 @@ def export_stablehlo(fn_or_samediff, example_args=None,
     return lowered.as_text()
 
 
-class _HTTPServer(ThreadingHTTPServer):
-    # the stdlib default backlog of 5 drops SYNs under concurrent-client
-    # load (clients then stall ~1s in retransmit — a fake p99); size it
-    # for the serving queue instead
-    request_queue_size = 128
-    daemon_threads = True
-
-
-def _status_for(exc: BaseException) -> int:
-    if isinstance(exc, ModelNotFound):
-        return 404
-    if isinstance(exc, QueueFullError):
-        return 503
-    if isinstance(exc, DeadlineExceededError):
-        return 504
-    if isinstance(exc, ClientError):
-        return 400
-    return 500
-
-
 class InferenceServer:
     """HTTP JSON inference front-end over registry + batcher (ref role:
     GraphServer.cpp).
@@ -214,16 +192,13 @@ class InferenceServer:
     ``host`` defaults to loopback; pass ``host="0.0.0.0"`` to bind
     externally for multi-host deployments.
 
-    ``http_backend`` selects the socket tier (docs/serving.md
-    "Front-end architecture"): ``"aio"`` (default) serves every
-    connection off one event loop — open connections cost a socket
-    buffer, not a thread, so thousands of idle keep-alive or
-    streaming clients don't breed thousands of blocked threads — with
-    engine-blocking work on a bounded daemon pool and a
-    ``http_header_timeout_s`` slow-loris cap the thread tier never
-    had. ``"thread"`` is the original thread-per-connection
-    ``ThreadingHTTPServer``. Routes, status codes, streaming framing,
-    headers and the access log are identical across backends.
+    The listener (:mod:`.aio`, docs/serving.md "Front-end
+    architecture") serves every connection off one event loop — open
+    connections cost a socket buffer, not a thread, so thousands of
+    idle keep-alive or streaming clients don't breed thousands of
+    blocked threads — with engine-blocking work on a bounded daemon
+    pool; a request head that does not complete within
+    ``http_header_timeout_s`` is dropped (the slow-loris cap).
     """
 
     DEFAULT_MODEL = "default"
@@ -244,7 +219,6 @@ class InferenceServer:
                  trace_ring: int = 256,
                  trace_slow_ms: float = 1000.0,
                  log_requests=False,
-                 http_backend: str = "aio",
                  http_header_timeout_s: float = 10.0):
         self.max_body_bytes = int(max_body_bytes)
         self.registry = registry or ModelRegistry()
@@ -277,314 +251,10 @@ class InferenceServer:
                                    default_outputs=default_outputs)
             if warmup_buckets:
                 served.warmup(warmup_buckets, example=warmup_example)
-        server = self
-
-        class Handler(BaseHTTPRequestHandler):
-            # keep-alive: serving clients send many small requests, and
-            # per-request TCP setup would dominate the batched path
-            # (every response carries Content-Length, so 1.1 is safe)
-            protocol_version = "HTTP/1.1"
-
-            def log_message(self, *a):
-                pass
-
-            def log_request(self, code="-", size="-"):
-                # send_response() calls this once per response — the
-                # single choke point every success/error/stream path
-                # goes through, so the access log is one line per
-                # request with no per-branch bookkeeping
-                if server._log_stream is None:
-                    return
-                try:
-                    status = int(code)
-                except (TypeError, ValueError):
-                    status = str(code)
-                t0 = getattr(self, "_t0", None)
-                entry = {"ts": round(time.time(), 6),
-                         "method": self.command,
-                         "path": self.path,
-                         "status": status,
-                         "latency_ms": round(
-                             (time.perf_counter() - t0) * 1e3, 3)
-                         if t0 is not None else None,
-                         "request_id": getattr(self, "_rid", None),
-                         "priority": getattr(self, "_prio", None)}
-                shed = getattr(self, "_shed", None)
-                if shed is not None:
-                    entry["shed_reason"] = shed
-                server._access_log(entry)
-
-            def _json(self, obj, code=200, headers=None):
-                body = json.dumps(obj).encode()
-                self.send_response(code)
-                self.send_header("Content-Type", "application/json")
-                self.send_header("Content-Length", str(len(body)))
-                rid = getattr(self, "_rid", None)
-                if rid:
-                    self.send_header("X-Request-Id", rid)
-                for k, v in (headers or {}).items():
-                    self.send_header(k, v)
-                self.end_headers()
-                self.wfile.write(body)
-
-            def _text(self, body: str, code=200):
-                data = body.encode()
-                self.send_response(code)
-                self.send_header("Content-Type", "text/plain; "
-                                 "version=0.0.4; charset=utf-8")
-                self.send_header("Content-Length", str(len(data)))
-                self.end_headers()
-                self.wfile.write(data)
-
-            def do_GET(self):
-                self._t0 = time.perf_counter()
-                self._rid = self.headers.get("X-Request-Id")
-                path, _, query = self.path.partition("?")
-                try:
-                    if path == "/health":
-                        self._json(server._health())
-                    elif path == "/healthz":
-                        code, body = server._healthz()
-                        self._json(body, code)
-                    elif path == "/readyz":
-                        if server.ready():
-                            self._json({"ready": True})
-                        else:
-                            self._json({"ready": False,
-                                        "reason": "draining"}, 503,
-                                       headers={"Retry-After": "1"})
-                    elif path == "/stats":
-                        self._json(server.stats())
-                    elif path == "/metrics":
-                        self._text(prometheus_text(server.stats()))
-                    elif path == "/debug/traces":
-                        q = parse_qs(query)
-                        rid = (q.get("request_id") or q.get("id")
-                               or [None])[0]
-                        limit = int((q.get("limit") or [50])[0])
-                        self._json({
-                            "traces": server.tracer.dump(
-                                request_id=rid, limit=limit),
-                            "tracer": server.tracer.snapshot()})
-                    elif path in ("/v1/models", "/v1/models/"):
-                        self._json(server.registry.describe())
-                    else:
-                        self._json({"error": "not found"}, 404)
-                except Exception as e:  # noqa: BLE001
-                    self._json({"error": str(e)}, 500)
-
-            def do_POST(self):
-                self._t0 = time.perf_counter()
-                # mint a request id unless the caller (router, client)
-                # already tagged one — the id is the trace id, echoed
-                # back as X-Request-Id and stitched across tiers
-                self._rid = (self.headers.get("X-Request-Id")
-                             or new_request_id())
-                self._prio = self.headers.get("X-Priority")
-                self._shed = None
-                # drain the body first: on a keep-alive (1.1) connection
-                # an unread body would be parsed as the next request
-                # line, desyncing the socket. Bad/negative lengths are a
-                # 400, never an unhandled exception or an
-                # until-EOF read (a hung handler thread).
-                if self.headers.get("Transfer-Encoding"):
-                    # chunked framing isn't parsed here; without the
-                    # body drained the keep-alive socket would desync
-                    self._json({"error": "Transfer-Encoding not "
-                                "supported; send Content-Length"}, 501)
-                    self.close_connection = True
-                    return
-                try:
-                    n = int(self.headers.get("Content-Length", 0))
-                except (TypeError, ValueError):
-                    n = -1
-                if n < 0:
-                    self._json({"error": "bad Content-Length"}, 400)
-                    self.close_connection = True  # body length unknown
-                    return
-                if n > server.max_body_bytes:
-                    # one oversized request must not OOM the process —
-                    # the queue bounds count rows, this bounds bytes
-                    self._json({"error": "request body too large "
-                                f"(limit {server.max_body_bytes} "
-                                "bytes)"}, 413)
-                    self.close_connection = True  # body left unread
-                    return
-                raw = self.rfile.read(n)
-                path, _, query = self.path.partition("?")
-                route = server._route(path)
-                if route is None:
-                    self._json({"error": "not found"}, 404)
-                    return
-                name, action = route
-                if not server.ready():
-                    # draining: shed BEFORE touching the registry so
-                    # half-drained engines never see new work; clients
-                    # retry against another replica after Retry-After
-                    self._shed = "draining"
-                    self._json({"error": "server is draining"}, 503,
-                               headers={"Retry-After": "1"})
-                    return
-                req = None
-                result = None
-                trace = None
-                span = None
-                try:
-                    try:
-                        req = json.loads(raw)
-                    except json.JSONDecodeError as e:
-                        raise ClientError(f"malformed JSON: {e}")
-                    # the X-Priority header maps to the "priority"
-                    # field (routers/gateways tag traffic classes
-                    # without rewriting bodies); the body field wins
-                    prio_hdr = self.headers.get("X-Priority")
-                    if prio_hdr and isinstance(req, dict) \
-                            and "priority" not in req:
-                        req["priority"] = prio_hdr
-                    if isinstance(req, dict):
-                        self._prio = req.get("priority", self._prio)
-                    # ?trace=1 (or "trace": 1 in the body) forces a
-                    # per-request trace even when the tracer is off;
-                    # the field is popped so validators never see it
-                    want_trace = bool(
-                        (query and "trace=1" in query.split("&"))
-                        or (isinstance(req, dict)
-                            and req.pop("trace", None)))
-                    trace = server.tracer.begin(self._rid,
-                                                force=want_trace)
-                    if trace is not None:
-                        span = trace.span("http", path=path,
-                                          model=name, action=action)
-                    if action == "generate":
-                        if isinstance(req, dict) and req.get("stream"):
-                            # admission errors raise HERE (before any
-                            # header goes out), so they still map to
-                            # real status codes; mid-stream failures
-                            # become a terminal error chunk instead
-                            it = server._generate_stream(name, req,
-                                                         trace=trace)
-                            self._stream_ndjson(it)
-                            if trace is not None:
-                                span.end(status=200, stream=True)
-                                server.tracer.finish(trace)
-                            return
-                        result = server._generate(name, req,
-                                                  trace=trace)
-                    else:
-                        result = server._predict(name, req,
-                                                 trace=trace)
-                except Exception as e:  # noqa: BLE001
-                    code = _status_for(e)
-                    if code in (503, 504):
-                        self._shed = str(e)
-                    version = (req.get("version")
-                               if isinstance(req, dict) else None)
-                    server._count_error(name, code, version)
-                    if trace is not None:
-                        span.end(status=code, error=str(e))
-                        server.tracer.finish(trace,
-                                             error=code >= 500)
-                    try:
-                        self._json({"error": str(e)}, code,
-                                   headers=({"Retry-After": "1"}
-                                            if code == 503 else None))
-                    except OSError:
-                        server._count_disconnect()
-                        self.close_connection = True
-                    return
-                if trace is not None:
-                    span.end(status=200)
-                    server.tracer.finish(trace)
-                    if want_trace and isinstance(result, dict):
-                        result = dict(result)
-                        result["trace"] = trace.to_dict()
-                try:
-                    self._json(result)
-                except OSError:
-                    # the client hung up while the (possibly slow)
-                    # request computed — routine once routers time out
-                    # and abandon sockets, not a server error; a
-                    # traceback per occurrence would spam stderr
-                    server._count_disconnect()
-                    self.close_connection = True
-
-            def _stream_ndjson(self, it):
-                """Chunked transfer-encoded newline-delimited JSON: one
-                object per generated token as the scheduler emits it,
-                a terminal ``{"done": true, ...}`` object, then the
-                zero chunk. The keep-alive socket stays in sync —
-                chunked framing is self-delimiting."""
-                try:
-                    self.send_response(200)
-                    self.send_header("Content-Type",
-                                     "application/x-ndjson")
-                    self.send_header("Transfer-Encoding", "chunked")
-                    self.end_headers()
-                except OSError:
-                    # client vanished before the headers went out:
-                    # abandon the generation (frees its slot) and do
-                    # NOT fall through to a second response attempt
-                    if hasattr(it, "close"):
-                        it.close()
-                    server._count_disconnect()
-                    self.close_connection = True
-                    return
-
-                def chunk(obj):
-                    data = (json.dumps(obj) + "\n").encode()
-                    with jax.profiler.TraceAnnotation(HTTP_WRITE_SPAN):
-                        self.wfile.write(f"{len(data):X}\r\n".encode()
-                                         + data + b"\r\n")
-                        self.wfile.flush()
-                wrote = getattr(it, "wrote", None)
-                try:
-                    try:
-                        for item in it:
-                            chunk(item)
-                            if wrote is not None:
-                                wrote()  # emit stamp -> on the socket
-                    except OSError:
-                        # client went away mid-stream: routine, not a
-                        # server error — close the iterator NOW (its
-                        # cleanup abandons the request, freeing its
-                        # cache slot) and drop the connection quietly
-                        if hasattr(it, "close"):
-                            it.close()
-                        server._count_disconnect()
-                        self.close_connection = True
-                        return
-                    except Exception as e:  # noqa: BLE001 — headers
-                        # are already on the wire; deliver in-band
-                        chunk({"error": str(e),
-                               "status": _status_for(e), "done": True})
-                    self.wfile.write(b"0\r\n\r\n")
-                except OSError:
-                    # the error/terminal chunk hit the dead socket too;
-                    # never fall through to a second HTTP response
-                    server._count_disconnect()
-                    self.close_connection = True
-
-        self.http_backend = http_backend
-        self._aio = None
-        self.httpd = None
-        self._thread = None
-        if http_backend == "thread":
-            self.httpd = _HTTPServer((host, port), Handler)
-            self.host = self.httpd.server_address[0]
-            self.port = self.httpd.server_address[1]
-            self._thread = threading.Thread(
-                target=self.httpd.serve_forever, daemon=True)
-            self._thread.start()
-        elif http_backend == "aio":
-            from .aio import AioReplicaFrontend
-            self._aio = AioReplicaFrontend(
-                self, host, port,
-                header_timeout_s=http_header_timeout_s)
-            self.host = self._aio.host
-            self.port = self._aio.port
-        else:
-            raise ValueError(f"unknown http_backend {http_backend!r} "
-                             "(use 'aio' or 'thread')")
+        self._aio = AioReplicaFrontend(
+            self, host, port, header_timeout_s=http_header_timeout_s)
+        self.host = self._aio.host
+        self.port = self._aio.port
 
     # -- model management ----------------------------------------------
     def register(self, name: str, model, **opts) -> ServedModel:
@@ -862,10 +532,6 @@ class InferenceServer:
         # through as terminal. Shedding 503 + Retry-After instead keeps
         # even a hard (drain-less) stop retryable upstream.
         self._ready = False
-        if self.httpd is not None:
-            self.httpd.shutdown()
-            self.httpd.server_close()
-        if self._aio is not None:
-            self._aio.stop()
+        self._aio.stop()
         if self._owns_registry:
             self.registry.stop()

@@ -1,22 +1,18 @@
 """Event-loop HTTP front-end for the serving plane (docs/serving.md
 "Front-end architecture").
 
-The thread-per-connection front-end (``http.server.ThreadingHTTPServer``)
-spends one OS thread per OPEN connection — not per in-flight request.
-A fleet front-end holding thousands of mostly-idle keep-alive and
-streaming connections therefore burns thousands of threads that exist
-only to block in ``readline()``, and the scheduler/stack cost of that
-idle army is what collapses first under connection scale (the bench's
-``connscale`` leg measures exactly this). This module rebuilds both
-HTTP tiers on one ``asyncio`` selector loop:
+A thread-per-connection listener spends one OS thread per OPEN
+connection — not per in-flight request — so a front-end holding
+thousands of mostly-idle keep-alive and streaming connections would
+burn thousands of threads that exist only to block in ``readline()``.
+Both HTTP tiers therefore run on one ``asyncio`` selector loop:
 
 - :class:`AioReplicaFrontend`: the :class:`~.InferenceServer` listener.
   Routing, body discipline (411/400/413 + close), keep-alive, chunked
   ndjson streaming, ``X-Request-Id`` / ``X-Priority`` propagation,
-  ``?trace=1``, the access log and the probe routes are byte-compatible
-  with the thread backend — the server-level methods (``_route``,
-  ``_predict``, ``_generate_stream``, ``_healthz`` …) are shared, only
-  the socket tier differs.
+  ``?trace=1``, the access log and the probe routes live here; what a
+  request means (``_route``, ``_predict``, ``_generate_stream``,
+  ``_healthz`` …) stays on the server object.
 - :class:`AioRouterFrontend`: the :class:`~.fleet.FleetRouter`
   listener. Streaming proxies are NATIVELY async end to end — one open
   proxied stream is two socket buffers and a coroutine, not a thread —
@@ -28,10 +24,10 @@ on the engine (predict/generate admission, pulling the next token of a
 stream, the router's retry/hedge dispatch) runs on a bounded
 daemon-thread pool — so the THREAD cost of the process scales with
 in-flight *blocking work* (bounded by engine slots + queue), never with
-open connections. Slow-loris protection the thread backend never had
-falls out of the same structure: request heads that do not complete
-within ``header_timeout_s`` are dropped without a thread ever having
-been committed to them.
+open connections. Slow-loris protection falls out of the same
+structure: request heads that do not complete within
+``header_timeout_s`` are dropped without a thread ever having been
+committed to them.
 """
 from __future__ import annotations
 
@@ -47,8 +43,10 @@ from urllib.parse import parse_qs
 from jax.profiler import TraceAnnotation
 
 from ..tracing import new_request_id
-from .batcher import DeadlineExceededError
-from .metrics import HTTP_WRITE_SPAN
+from .batcher import DeadlineExceededError, QueueFullError
+from .engine import ClientError
+from .metrics import HTTP_WRITE_SPAN, prometheus_text
+from .registry import ModelNotFound
 
 _REASONS = {
     200: "OK", 400: "Bad Request", 404: "Not Found",
@@ -66,8 +64,15 @@ _END = object()          # stream-iterator exhaustion sentinel
 
 
 def _status_for(exc: BaseException) -> int:
-    from . import _status_for as impl     # parent package, post-init
-    return impl(exc)
+    if isinstance(exc, ModelNotFound):
+        return 404
+    if isinstance(exc, QueueFullError):
+        return 503
+    if isinstance(exc, DeadlineExceededError):
+        return 504
+    if isinstance(exc, ClientError):
+        return 400
+    return 500
 
 
 class _DaemonExecutor:
@@ -191,9 +196,8 @@ class _Resp:
         head = ("\r\n".join(lines) + "\r\n\r\n").encode("latin-1")
         self.status = code
         self.sent = True
-        # access log fires at header-send time (like the thread
-        # backend's send_response hook), so by the time a client can
-        # read the response its log line is already written
+        # access log fires at header-send time, so by the time a
+        # client can read the response its log line is already written
         if self.log_cb is not None:
             cb, self.log_cb = self.log_cb, None
             cb()
@@ -389,9 +393,9 @@ class _AioFrontend:
 
     async def _read_body(self, req: _Request,
                          resp: _Resp) -> Tuple[bool, bytes]:
-        """Same keep-alive body discipline as the thread backend: an
-        unread/unframed body would desync the next request on the
-        socket, so every reject also closes the connection."""
+        """Keep-alive body discipline: an unread/unframed body would
+        desync the next request on the socket, so every reject also
+        closes the connection."""
         if req.headers.get("Transfer-Encoding"):
             await resp.json({"error": "Transfer-Encoding not "
                              "supported; send Content-Length"}, 501)
@@ -473,8 +477,8 @@ class _AioFrontend:
 
 class AioReplicaFrontend(_AioFrontend):
     """Event-loop listener for one :class:`~.InferenceServer` replica.
-    Route table and semantics mirror the thread backend's handler; the
-    server-level request methods are shared verbatim."""
+    The route table lives here; what each route does stays on the
+    server object."""
 
     def __init__(self, server, host: str, port: int,
                  header_timeout_s: float = 10.0, workers: int = 128):
@@ -496,7 +500,6 @@ class AioReplicaFrontend(_AioFrontend):
         self._srv._count_disconnect()
 
     async def handle_get(self, req: _Request, resp: _Resp):
-        from .metrics import prometheus_text
         server = self._srv
         path, query = req.path, req.query
         try:
@@ -536,7 +539,6 @@ class AioReplicaFrontend(_AioFrontend):
             await resp.json({"error": str(e)}, 500)
 
     async def handle_post(self, req: _Request, resp: _Resp, raw: bytes):
-        from .engine import ClientError
         server = self._srv
         path, query = req.path, req.query
         route = server._route(path)
@@ -635,8 +637,7 @@ class AioReplicaFrontend(_AioFrontend):
         buffers and a parked coroutine, never a pool worker. (The
         executor-pump fallback below exists only for iterators without
         the ``_TokenStream`` queue shape.) That zero-thread idle cost
-        is what lets one replica hold thousands of open streams — the
-        bench's ``connscale`` leg."""
+        is what lets one replica hold thousands of open streams."""
         server = self._srv
         req = getattr(it, "_req", None)
         wrote = getattr(it, "wrote", None)
@@ -927,7 +928,6 @@ class AioRouterFrontend(_AioFrontend):
 
     async def handle_get(self, req: _Request, resp: _Resp):
         from .fleet import _get_json
-        from .metrics import prometheus_text
         router = self._router
         path, query = req.path, req.query
         try:

@@ -35,10 +35,10 @@ import time
 from collections import deque
 from typing import Any, Optional, Sequence
 
+from ..faults import TransientFault, poll_until_idle
 from ..profiler import OpProfiler
 from .engine import (ClientError, InferenceEngine, ServingError,
                      _concat_results, _slice)
-from .faults import TransientFault, poll_until_idle
 
 
 class QueueFullError(ServingError):

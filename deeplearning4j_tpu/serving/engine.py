@@ -114,7 +114,7 @@ class InferenceEngine:
                  metrics: Optional[ServingMetrics] = None,
                  fault_injector=None):
         self.model = model
-        # serving/faults.py FaultInjector (or None — the default; the
+        # a faults.FaultInjector (or None — the default; the
         # hot path then pays exactly one attribute load per call)
         self._faults = fault_injector
         self.default_outputs = list(default_outputs or [])

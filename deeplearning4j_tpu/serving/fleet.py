@@ -75,15 +75,12 @@ import queue
 import sys
 import threading
 import time
-from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 from typing import Any, Callable, Dict, List, Optional, Set, Tuple
-from urllib.parse import parse_qs
 
 from ..faults import poll_until_idle
 from ..profiler import Reservoir
-from ..tracing import Tracer, new_request_id
+from ..tracing import Tracer
 from .engine import ServingError
-from .metrics import prometheus_text
 
 #: transport-level failures that justify trying another replica — the
 #: predict path is stateless and generation is seed-deterministic, so
@@ -830,10 +827,8 @@ class FleetRouter:
             collections.OrderedDict()
         self._affinity_cap = 4096
         self._affinity_lock = threading.Lock()
-        self.httpd = None
         self.host: Optional[str] = None
         self.port: Optional[int] = None
-        self._http_thread: Optional[threading.Thread] = None
         self._aio = None
 
     # -- replica selection --------------------------------------------
@@ -1360,337 +1355,34 @@ class FleetRouter:
     # -- HTTP front-end ------------------------------------------------
     def serve(self, host: str = "127.0.0.1", port: int = 0,
               max_body_bytes: int = 256 * 1024 * 1024,
-              log_requests=False, backend: str = "aio",
-              header_timeout_s: float = 10.0):
+              log_requests=False, header_timeout_s: float = 10.0):
         """Start the fleet's own HTTP listener (same route table as a
         replica, fleet-level probes/stats) and return (host, port).
         ``log_requests`` (off by default) enables a structured JSON
         access log — ``True`` logs to stderr, any file-like object
         logs there (same format as the replica's).
 
-        ``backend="aio"`` (default) serves off one event loop with a
-        NATIVELY async streaming proxy: an open proxied stream is two
-        socket buffers and a coroutine, so connection count — the
-        router's actual scaling axis — no longer breeds blocked
-        threads, and upstream keep-alives ride an async checkout pool
-        (docs/serving.md "Front-end architecture").
-        ``backend="thread"`` is the original thread-per-connection
-        listener. Routes and proxy semantics are identical."""
-        router = self
+        The listener is one event loop with a NATIVELY async streaming
+        proxy: an open proxied stream is two socket buffers and a
+        coroutine, so connection count — the router's actual scaling
+        axis — breeds no blocked threads, and upstream keep-alives
+        ride an async checkout pool (docs/serving.md "Front-end
+        architecture")."""
         self._log_stream = (sys.stderr if log_requests is True
                             else (log_requests or None))
-        if backend == "aio":
-            from .aio import AioRouterFrontend
-            self._aio = AioRouterFrontend(
-                self, host, port, max_body_bytes=max_body_bytes,
-                header_timeout_s=header_timeout_s)
-            self.host = self._aio.host
-            self.port = self._aio.port
-            return self.host, self.port
-        if backend != "thread":
-            raise ValueError(f"unknown backend {backend!r} "
-                             "(use 'aio' or 'thread')")
-
-        class _Server(ThreadingHTTPServer):
-            request_queue_size = 128
-            daemon_threads = True
-
-        class Handler(BaseHTTPRequestHandler):
-            protocol_version = "HTTP/1.1"
-
-            def log_message(self, *a):
-                pass
-
-            def log_request(self, code="-", size="-"):
-                # one line per response — see InferenceServer's
-                # identically-shaped override
-                if router._log_stream is None:
-                    return
-                try:
-                    status = int(code)
-                except (TypeError, ValueError):
-                    status = str(code)
-                t0 = getattr(self, "_t0", None)
-                entry = {"ts": round(time.time(), 6),
-                         "method": self.command,
-                         "path": self.path,
-                         "status": status,
-                         "latency_ms": round(
-                             (time.perf_counter() - t0) * 1e3, 3)
-                         if t0 is not None else None,
-                         "request_id": getattr(self, "_rid", None),
-                         "priority": getattr(self, "_prio", None)}
-                shed = getattr(self, "_shed", None)
-                if shed is not None:
-                    entry["shed_reason"] = shed
-                router._access_log(entry)
-
-            def _json(self, obj, code=200, headers=None):
-                body = (obj if isinstance(obj, bytes)
-                        else json.dumps(obj).encode())
-                try:
-                    self.send_response(code)
-                    self.send_header("Content-Type", "application/json")
-                    self.send_header("Content-Length", str(len(body)))
-                    rid = getattr(self, "_rid", None)
-                    if rid:
-                        self.send_header("X-Request-Id", rid)
-                    for k, v in (headers or {}).items():
-                        self.send_header(k, v)
-                    self.end_headers()
-                    self.wfile.write(body)
-                except OSError:
-                    # the client gave up (its own timeout) while the
-                    # dispatch ran — routine, not a router error, and
-                    # must not traceback-spam stderr per occurrence
-                    self.close_connection = True
-
-            def _text(self, body: str, code=200):
-                data = body.encode()
-                try:
-                    self.send_response(code)
-                    self.send_header("Content-Type", "text/plain; "
-                                     "version=0.0.4; charset=utf-8")
-                    self.send_header("Content-Length", str(len(data)))
-                    self.end_headers()
-                    self.wfile.write(data)
-                except OSError:
-                    self.close_connection = True
-
-            def do_GET(self):
-                self._t0 = time.perf_counter()
-                self._rid = self.headers.get("X-Request-Id")
-                path, _, query = self.path.partition("?")
-                try:
-                    if path == "/stats":
-                        self._json(router.stats())
-                    elif path == "/metrics":
-                        self._text(prometheus_text(router.stats()))
-                    elif path == "/debug/traces":
-                        q = parse_qs(query)
-                        rid = (q.get("request_id") or q.get("id")
-                               or [None])[0]
-                        limit = int((q.get("limit") or [50])[0])
-                        self._json({
-                            "traces": router.tracer.dump(
-                                request_id=rid, limit=limit),
-                            "tracer": router.tracer.snapshot()})
-                    elif path == "/healthz":
-                        ok = router.healthy()
-                        self._json({"status": "ok" if ok else
-                                    "no replicas"}, 200 if ok else 503)
-                    elif path == "/readyz":
-                        if router.ready():
-                            self._json({"ready": True})
-                        else:
-                            self._json({"ready": False,
-                                        "reason": "no eligible replica"},
-                                       503, headers={"Retry-After": "1"})
-                    elif path in ("/v1/models", "/v1/models/"):
-                        rep = router._pick(set())
-                        if rep is None:
-                            self._json({"error": "no replica available"},
-                                       503, headers={"Retry-After": "1"})
-                        else:
-                            st, body = _get_json(
-                                rep.host, rep.port, "/v1/models",
-                                router.timeout_s)
-                            self._json(body, st)
-                    else:
-                        self._json({"error": "not found"}, 404)
-                except Exception as e:   # noqa: BLE001
-                    self._json({"error": str(e)}, 500)
-
-            def do_POST(self):
-                self._t0 = time.perf_counter()
-                # the front-end is where a request id is born (unless
-                # the client brought one): the SAME id is forwarded to
-                # whichever replicas this request touches, so the
-                # router's spans and the winning replica's spans land
-                # under one trace id
-                self._rid = (self.headers.get("X-Request-Id")
-                             or new_request_id())
-                self._prio = self.headers.get("X-Priority")
-                self._shed = None
-                # same keep-alive body discipline as InferenceServer:
-                # bad/oversized bodies must not desync or OOM
-                if self.headers.get("Transfer-Encoding"):
-                    self._json({"error": "Transfer-Encoding not "
-                                "supported; send Content-Length"}, 501)
-                    self.close_connection = True
-                    return
-                try:
-                    n = int(self.headers.get("Content-Length", 0))
-                except (TypeError, ValueError):
-                    n = -1
-                if n < 0:
-                    self._json({"error": "bad Content-Length"}, 400)
-                    self.close_connection = True
-                    return
-                if n > max_body_bytes:
-                    self._json({"error": "request body too large"}, 413)
-                    self.close_connection = True
-                    return
-                raw = self.rfile.read(n)
-                path, _, query = self.path.partition("?")
-                # X-Priority carries the request's shed class — the
-                # one client header with routing semantics; it must
-                # survive the proxy hop or every fronted request
-                # silently becomes interactive
-                fwd = {"X-Request-Id": self._rid}
-                prio = self.headers.get("X-Priority")
-                if prio is not None:
-                    fwd["X-Priority"] = prio
-                # ?trace=1 on the QUERY (not the body — the router
-                # must not pay a parse of predict bodies) forces a
-                # trace even when the router tracer is off; the query
-                # is NOT forwarded, so each tier opts in separately
-                want_trace = bool(query
-                                  and "trace=1" in query.split("&"))
-                trace = router.tracer.begin(self._rid,
-                                            force=want_trace)
-                fspan = (trace.span("frontend", path=path)
-                         if trace is not None else None)
-                streaming = False
-                session = None
-                # only generate routes can stream — don't pay a json
-                # parse of (possibly huge) predict bodies just to
-                # sniff a flag they can't carry.  the same sniff pulls
-                # session_id so the router can steer the turn to the
-                # replica that pinned the session's KV blocks
-                if path == "/generate" or \
-                        path.rstrip("/").endswith("/generate"):
-                    try:
-                        req = json.loads(raw)
-                        streaming = bool(isinstance(req, dict)
-                                         and req.get("stream"))
-                        if isinstance(req, dict):
-                            sid = req.get("session_id")
-                            if isinstance(sid, str) and sid:
-                                session = sid
-                    except ValueError:
-                        pass   # replica answers 400; just forward
-                if streaming:
-                    self._proxy_stream(path, raw, fwd, trace, fspan,
-                                       session=session)
-                    return
-                status, hdrs, data = router.post_raw(path, raw, fwd,
-                                                     trace=trace,
-                                                     session=session)
-                if status in (503, 504):
-                    self._shed = "overload"
-                extra = {}
-                if "Retry-After" in hdrs:
-                    extra["Retry-After"] = hdrs["Retry-After"]
-                if trace is not None:
-                    fspan.end(status=status)
-                    router.tracer.finish(trace, error=status >= 500)
-                    if want_trace and status == 200:
-                        # splice the router's spans into the replica's
-                        # ?trace=1 timeline (or create one): the
-                        # response carries the full cross-tier view
-                        try:
-                            body = json.loads(data)
-                            if isinstance(body, dict):
-                                body["router_trace"] = trace.to_dict()
-                                data = json.dumps(body).encode()
-                        except ValueError:
-                            pass
-                self._json(data, status, headers=extra)
-
-            def _proxy_stream(self, path: str, raw: bytes,
-                              fwd: Dict = None, trace=None,
-                              fspan=None, session=None):
-                opened = router.open_stream(path, raw, fwd,
-                                            trace=trace,
-                                            session=session)
-                if trace is not None:
-                    fspan.end(status=(opened[1]
-                                      if opened[0] == "response"
-                                      else 200), stream=True)
-                    router.tracer.finish(
-                        trace, error=(opened[0] == "response"
-                                      and opened[1] >= 500))
-                if opened[0] == "response":
-                    _, status, hdrs, data = opened
-                    extra = {}
-                    if "Retry-After" in hdrs:
-                        extra["Retry-After"] = hdrs["Retry-After"]
-                    self._json(data, status, headers=extra)
-                    return
-                _, rep, conn, resp = opened
-                try:
-                    try:
-                        self.send_response(200)
-                        self.send_header("Content-Type",
-                                         "application/x-ndjson")
-                        self.send_header("Transfer-Encoding", "chunked")
-                        self.end_headers()
-                    except OSError:
-                        self.close_connection = True
-                        return
-                    # upstream READ and downstream WRITE failures are
-                    # different events and must not be conflated: a
-                    # dying replica (IncompleteRead — an HTTPException,
-                    # NOT an OSError — or a read timeout) leaves a LIVE
-                    # client that is owed the same in-band error chunk
-                    # the replica-direct path delivers; a vanished
-                    # client just needs the upstream closed (which
-                    # aborts the generation and frees its slot/blocks)
-                    err = None
-                    while True:
-                        try:
-                            line = resp.readline()
-                        except _RETRYABLE_EXC as e:
-                            err = {"error": "replica stream failed: "
-                                            f"{type(e).__name__}: {e}",
-                                   "status": 500, "done": True}
-                            break
-                        if not line:
-                            break
-                        if not line.strip():
-                            continue
-                        try:
-                            self.wfile.write(
-                                f"{len(line):X}\r\n".encode()
-                                + line + b"\r\n")
-                            self.wfile.flush()
-                        except OSError:
-                            # downstream client vanished mid-stream
-                            self.close_connection = True
-                            return
-                    try:
-                        if err is not None:
-                            data = (json.dumps(err) + "\n").encode()
-                            self.wfile.write(
-                                f"{len(data):X}\r\n".encode()
-                                + data + b"\r\n")
-                        self.wfile.write(b"0\r\n\r\n")
-                    except OSError:
-                        self.close_connection = True
-                finally:
-                    conn.close()
-                    rep.end()
-
-        self.httpd = _Server((host, port), Handler)
-        self.host = self.httpd.server_address[0]
-        self.port = self.httpd.server_address[1]
-        self._http_thread = threading.Thread(
-            target=self.httpd.serve_forever, daemon=True,
-            name="fleet-http")
-        self._http_thread.start()
+        from .aio import AioRouterFrontend
+        self._aio = AioRouterFrontend(
+            self, host, port, max_body_bytes=max_body_bytes,
+            header_timeout_s=header_timeout_s)
+        self.host = self._aio.host
+        self.port = self._aio.port
         return self.host, self.port
 
     def stop(self):
         """Stop the router's HTTP listener (if started) and drop
         pooled connections. Replicas and the fleet poll loop are
         owned by :class:`ReplicaFleet` — stop them there."""
-        if self.httpd is not None:
-            self.httpd.shutdown()
-            self.httpd.server_close()
-            self.httpd = None
         if self._aio is not None:
             self._aio.stop()
             self._aio = None
-        self._pool.close_all()
         self._pool.close_all()

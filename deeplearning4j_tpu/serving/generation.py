@@ -29,7 +29,7 @@ the queue and mid-generation.
 
 Admission control (docs/serving.md "Overload and admission control"):
 requests carry a priority class (``interactive`` default, ``batch``
-shed first — batch work only gets the front ``batch_queue_fraction``
+shed first — batch work only gets the front ``BATCH_QUEUE_FRACTION``
 of the queue), and admission is cost-aware: the engine keeps measured
 EWMAs of per-token prefill time and per-step decode time, rejects a
 request up front when its estimated prefill + ``max_tokens`` decode
@@ -51,12 +51,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..faults import (CorruptedStateFault, PoisonRequestError,
+                      TransientFault, poll_until_idle)
 from ..profiler import OpProfiler
 from .batcher import (PRIORITIES, DeadlineExceededError, DrainingError,
                       QueueFullError)
 from .engine import ClientError, ServingError, compile_memoized
-from .faults import (CorruptedStateFault, PoisonRequestError,
-                     TransientFault, poll_until_idle)
 from ..kernels.kv_quant import (canonical_kv_dtype, kv_bytes_per_token,
                                 kv_copy_row, kv_pack_host,
                                 kv_unpack_host, kv_update_slice)
@@ -83,6 +83,22 @@ _NEG_INF = -1e30
 #: CPU and the cap keeps the executable shape static. Requests asking
 #: for top_k >= vocab get exact no-filter sampling.
 TOP_K_CAP = 128
+
+#: entries the prefix index holds before it evicts the least recently
+#: used (one entry a full prompt block)
+PREFIX_INDEX_CAPACITY = 1024
+
+#: recompute-recoveries one request may take part in before it is
+#: failed as the likely cause
+MAX_RECOVERIES_PER_REQUEST = 3
+
+#: heartbeat age past which ``alive()`` calls the scheduler wedged (the
+#: loop beats every iteration; its longest pause is one device call)
+STALL_TIMEOUT_S = 30.0
+
+#: share of the queue that batch-class work may fill; interactive work
+#: gets all of it
+BATCH_QUEUE_FRACTION = 0.5
 
 
 def _sample_from_logits(logits, temps, top_ks, us):
@@ -400,24 +416,19 @@ class GenerationEngine:
                  num_blocks: Optional[int] = None,
                  prefill_chunk_tokens: Optional[int] = None,
                  enable_prefix_sharing: bool = True,
-                 prefix_index_capacity: int = 1024,
                  session_capacity: int = 64,
                  metrics: Optional[GenerationMetrics] = None,
                  fault_injector=None,
                  max_step_retries: int = 3,
                  retry_backoff_ms: float = 1.0,
                  retry_backoff_max_ms: float = 50.0,
-                 max_recoveries_per_request: int = 3,
-                 stall_timeout_s: float = 30.0,
-                 batch_queue_fraction: float = 0.5,
                  speculation_k: int = 0,
                  draft_model=None,
                  decode_pipeline: bool = True,
                  kv_dtype: str = "f32",
                  offload_host_bytes: int = 0,
                  offload_disk_bytes: int = 0,
-                 offload_dir: Optional[str] = None,
-                 offload_prefetch: bool = True):
+                 offload_dir: Optional[str] = None):
         if getattr(model, "_params", None) is None:
             model.init()
         self.model = model
@@ -559,7 +570,7 @@ class GenerationEngine:
             # prefix sharing: chained-hash index over full prompt
             # blocks + session pins; both are scheduler-thread state
             self.enable_prefix_sharing = bool(enable_prefix_sharing)
-            self._prefix_index = PrefixIndex(int(prefix_index_capacity))
+            self._prefix_index = PrefixIndex(PREFIX_INDEX_CAPACITY)
             self._sessions = SessionStore(int(session_capacity))
         else:
             self.prefill_chunk_tokens = None
@@ -599,9 +610,10 @@ class GenerationEngine:
             top = pow2_bucket(self._blocks_per_seq)
             self._off_buckets = [b for b in self._tbl_buckets
                                  if b <= top]
-            if offload_prefetch:
-                self._offload_prefetcher = OffloadPrefetcher(
-                    self._stage_restore)
+            # restores are staged off the scheduler's thread (disk
+            # read, padded operands, h2d) while the request queues
+            self._offload_prefetcher = OffloadPrefetcher(
+                self._stage_restore)
         self.metrics = metrics or GenerationMetrics()
         self.metrics.queue_max = int(max_queue)
         self.metrics.num_slots = self.num_slots
@@ -728,9 +740,8 @@ class GenerationEngine:
         self._wake = threading.Event()
         # priority shedding: batch-class work only gets the front
         # fraction of the queue; interactive gets all of it
-        self.batch_queue_fraction = float(batch_queue_fraction)
         self._batch_queue_limit = max(
-            1, int(self.batch_queue_fraction * int(max_queue)))
+            1, int(BATCH_QUEUE_FRACTION * int(max_queue)))
         # cost-aware admission: measured EWMAs (per PROMPT TOKEN of
         # prefill, per STEP of decode) — 0.0 until the first call
         # lands, so a cold engine admits everything. What the decode
@@ -742,7 +753,7 @@ class GenerationEngine:
         # is the cycle). Admission reads it as a worst case.
         self._prefill_ms_per_tok = 0.0
         self._decode_ewma_ms = 0.0
-        # -- fault tolerance (serving/faults.py) --------------------
+        # -- fault tolerance (deeplearning4j_tpu/faults.py) ---------
         # seams fire only when an injector is configured; the
         # supervised loop always runs (real device faults need no
         # injector to happen)
@@ -750,8 +761,6 @@ class GenerationEngine:
         self._max_step_retries = int(max_step_retries)
         self._retry_backoff_s = float(retry_backoff_ms) / 1e3
         self._retry_backoff_max_s = float(retry_backoff_max_ms) / 1e3
-        self._max_recoveries = int(max_recoveries_per_request)
-        self._stall_timeout_s = float(stall_timeout_s)
         # requests to re-admit AHEAD of the queue: transient-faulted
         # admissions and recompute-recovery re-admissions (they were
         # already accepted — later arrivals must not starve them)
@@ -1222,10 +1231,7 @@ class GenerationEngine:
         sid = req.session_id
         if sid in self._sessions:
             return False  # device pin is current; host copy is stale
-        staged = None
-        pf = self._offload_prefetcher
-        if pf is not None:
-            staged = pf.take(sid)
+        staged = self._offload_prefetcher.take(sid)
         run = ops = None
         if staged is not None:
             run, ops = staged
@@ -1252,8 +1258,7 @@ class GenerationEngine:
             # torn restore: invalidate the host copy and re-prefill —
             # the lane never saw a device call, nothing to corrupt
             off.pop(sid)
-            if pf is not None:
-                pf.discard(sid)
+            self._offload_prefetcher.discard(sid)
             self.metrics.inc("offload_restore_failures")
             return False
         blocks = self._alloc_with_eviction(run.n_blocks)
@@ -1731,7 +1736,6 @@ class GenerationEngine:
                 req.qspan = trace.span("queue",
                                        priority=req.priority)
             if (self._offload is not None
-                    and self._offload_prefetcher is not None
                     and req.session_id is not None
                     and req.session_id not in self._sessions
                     and req.session_id in self._offload):
@@ -1976,7 +1980,7 @@ class GenerationEngine:
         except queue.Empty:
             with self._sched.phase("idle"):
                 self._wake.wait(
-                    max(0.05, min(1.0, self._stall_timeout_s / 4.0)))
+                    max(0.05, min(1.0, STALL_TIMEOUT_S / 4.0)))
             return None
 
     def _admit(self):
@@ -2522,8 +2526,7 @@ class GenerationEngine:
             # the freshly-pinned device copy supersedes any demoted
             # one — a stale host run must never be restored over it
             self._offload.pop(req.session_id)
-            if self._offload_prefetcher is not None:
-                self._offload_prefetcher.discard(req.session_id)
+            self._offload_prefetcher.discard(req.session_id)
         self._slots.free(slot)
         self._slot_blocks[slot] = None
         self._tables[slot] = NULL_BLOCK
@@ -2572,7 +2575,7 @@ class GenerationEngine:
         its PRNG stream continues at ``fold_in(seed, len(emitted))``,
         so post-recovery output is token-identical to a fault-free
         run and NO accepted request is ever lost. Only requests that
-        keep triggering recoveries (``max_recoveries_per_request``) or
+        keep triggering recoveries (``MAX_RECOVERIES_PER_REQUEST``) or
         age past their deadline are failed."""
         recovered: List[_GenRequest] = []
         st = self._slots
@@ -2621,7 +2624,7 @@ class GenerationEngine:
                 self._fail(req, DeadlineExceededError(
                     "deadline exceeded during fault recovery "
                     f"({len(req.tokens)} tokens emitted)"))
-            elif req.recoveries >= self._max_recoveries:
+            elif req.recoveries >= MAX_RECOVERIES_PER_REQUEST:
                 # a request that rides every crash is probably causing
                 # them — attribution of last resort
                 self._fail(req, ServingError(
@@ -3256,10 +3259,10 @@ class GenerationEngine:
         behind it, the step before collected, the chunk collected.
         Failure ladder:
 
-        - :class:`~.faults.TransientFault` (raised before any
+        - :class:`~..faults.TransientFault` (raised before any
           donation): retry the iteration with bounded exponential
           backoff, up to ``max_step_retries`` consecutive strikes.
-        - strikes exhausted, :class:`~.faults.CorruptedStateFault`, or
+        - strikes exhausted, :class:`~..faults.CorruptedStateFault`, or
           ANY other exception (a device call dying after the caches
           were donated): recompute-recovery via :meth:`_recover`.
         - recovery itself failing: :meth:`_poison` (fail all in-flight
@@ -3462,14 +3465,14 @@ class GenerationEngine:
     def alive(self) -> bool:
         """Liveness for ``/healthz``: False only when the scheduler is
         WEDGED — thread dead while it should be running, or no
-        heartbeat within ``stall_timeout_s`` (the loop beats every
+        heartbeat within ``STALL_TIMEOUT_S`` (the loop beats every
         iteration; its longest legitimate pause is one device call).
         A deliberately stopped/drained engine is not wedged."""
         if not self._running:
             return True
         if not self._thread.is_alive():
             return False
-        return (time.monotonic() - self._beat) <= self._stall_timeout_s
+        return (time.monotonic() - self._beat) <= STALL_TIMEOUT_S
 
     def _idle(self) -> bool:
         empty = (self._queue.empty() and not self._requeue
@@ -3505,9 +3508,8 @@ class GenerationEngine:
         self._running = False
         self._wake.set()  # unpark an idle scheduler immediately
         self._thread.join(timeout=timeout_s)
-        if self._offload_prefetcher is not None:
-            self._offload_prefetcher.stop()
         if self._offload is not None:
+            self._offload_prefetcher.stop()
             # drops the host entries and unlinks the disk ring's
             # tempfile; runs after the scheduler join so no demote/
             # restore can still be writing into the store

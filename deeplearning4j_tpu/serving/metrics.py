@@ -55,7 +55,7 @@ class ServingMetrics:
         #                            device call: rejected at dequeue-
         #                            admission, zero device work spent
         self.timeouts = 0          # request deadline exceeded (504)
-        # fault-tolerance counters (serving/faults.py)
+        # fault-tolerance counters (deeplearning4j_tpu/faults.py)
         self.retries = 0           # transient step failures retried
         self.recoveries = 0        # state rebuilds (n/a for batcher)
         self.quarantined = 0       # poison requests failed alone
@@ -300,68 +300,6 @@ class SchedulerAccount:
             }
 
 
-#: the ``moe`` block of a generator's ``/stats``: what the routing of
-#: a served mixture-of-experts model did, from the small integer vector
-#: (pairs, experts touched, pairs of each expert) that every decode
-#: step and prefill chunk returns beside its tokens
-MOE_COUNTERS = ("decode_pairs", "decode_experts_touched",
-                "decode_expert_slots", "chunk_pairs")
-
-
-class MoeAccount:
-    """Routing counters of a served model with experts: the account a
-    model hands the engine (``model.step_account()``), which feeds it
-    the vector every step and chunk returns and publishes its snapshot
-    under :attr:`block`. ``expert_slots_per_step`` is expert layers x
-    experts held: what one step could touch. Written by the scheduler
-    thread when a step's or a chunk's results reach the host; all
-    counters of one step are committed together under the lock, so a
-    ratio of two deltas (``decode_experts_touched`` over
-    ``decode_expert_slots``) is exact over whole steps."""
-
-    #: the account's key in a generator's ``/stats``
-    block = "moe"
-
-    def __init__(self, expert_slots_per_step: int):
-        self._lock = threading.Lock()
-        self._slots_per_step = int(expert_slots_per_step)
-        self.decode_pairs = 0            # token-expert pairs computed
-        self.decode_experts_touched = 0  # experts with >= 1 live token
-        self.decode_expert_slots = 0     # expert layers x experts x steps
-        self.chunk_pairs = 0
-        self.expert_tokens = None        # [E] pairs of each expert
-
-    def _add_tokens(self, per_expert) -> None:
-        if self.expert_tokens is None:
-            self.expert_tokens = per_expert.astype("int64")
-        else:
-            self.expert_tokens += per_expert
-
-    def decode_step(self, counters) -> None:
-        with self._lock:
-            self.decode_pairs += int(counters[0])
-            self.decode_experts_touched += int(counters[1])
-            self.decode_expert_slots += self._slots_per_step
-            self._add_tokens(counters[2:])
-
-    def chunk(self, counters) -> None:
-        with self._lock:
-            self.chunk_pairs += int(counters[0])
-            self._add_tokens(counters[2:])
-
-    def snapshot(self) -> Dict:
-        with self._lock:
-            tokens = [] if self.expert_tokens is None \
-                else self.expert_tokens.tolist()
-            out = {k: getattr(self, k) for k in MOE_COUNTERS}
-        total = sum(tokens)
-        out["expert_tokens"] = {str(e): n for e, n in enumerate(tokens)}
-        # load balance: the fullest expert over the mean (1.0 = even)
-        out["expert_tokens_max_over_mean"] = round(
-            max(tokens) * len(tokens) / total, 4) if total else 0.0
-        return out
-
-
 class GenerationMetrics:
     """Always-on counters for one continuous-batching generation
     engine. Same threading discipline as :class:`ServingMetrics`
@@ -381,7 +319,7 @@ class GenerationMetrics:
         #                            prefill/decode step: rejected at
         #                            admission, zero device work spent
         self.timeouts = 0          # deadline exceeded (504)
-        # fault-tolerance counters (serving/faults.py): transient step
+        # fault-tolerance counters (deeplearning4j_tpu/faults.py): transient step
         # retries, recompute-recoveries (every in-flight request
         # re-prefilled from prompt + emitted tokens), poison requests
         # quarantined (non-finite logits -> 500, batchmates unharmed),

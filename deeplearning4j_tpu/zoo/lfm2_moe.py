@@ -44,7 +44,7 @@ import jax.numpy as jnp
 from ..kernels.paged_attention import (gather_span, kv_pool_set,
                                        paged_attention)
 from ..nn.layers.attention import _span_attend
-from ..nn.layers.moe import moe_ffn
+from ..nn.layers.moe import MoeAccount, moe_ffn
 
 #: layout of the int32 vector both forwards return beside the logits:
 #: token-expert pairs computed, experts that received at least one live
@@ -179,7 +179,6 @@ class Lfm2MoeLM:
     def step_account(self):
         """What the :data:`STEP_COUNTERS` vectors add up into, an engine:
         the ``moe`` block of its ``/stats``."""
-        from ..serving.metrics import MoeAccount
         return MoeAccount(self.n_moe_layers * self.n_experts)
 
     # -- pieces ------------------------------------------------------------

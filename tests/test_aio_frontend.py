@@ -1,14 +1,12 @@
-"""Event-loop HTTP front-end (ISSUE 14): the socket edge cases the
-thread-per-connection backend never saw (slow-loris heads, malformed
-request lines, oversized headers), the zero-thread cost of idle
-streaming connections, keep-alive reuse under many idle conns, the
-thread backend staying selectable at both tiers, and the
-pipelined-decode token-identity A/B.
+"""Event-loop HTTP front-end (ISSUE 14): the socket edge cases
+(slow-loris heads, malformed request lines, oversized headers), the
+zero-thread cost of idle streaming connections, keep-alive reuse under
+many idle conns, and the pipelined-decode token-identity A/B.
 
-The REST of the serving surface (routes, drain/readyz/SIGTERM,
-mid-stream disconnect through the router, chunked framing, shed
-semantics) is covered by the existing suites — which now run on the
-aio default, so every one of those tests exercises the event loop."""
+The route table is pinned in tests/test_http_routes.py; the rest of the
+serving surface (drain/readyz/SIGTERM, mid-stream disconnect through
+the router, chunked framing, shed semantics) is covered by the other
+serving suites, all of which go through this listener."""
 import http.client
 import json
 import socket
@@ -17,8 +15,7 @@ import time
 
 import pytest
 
-from deeplearning4j_tpu.serving import (FleetRouter, GenerationEngine,
-                                        InferenceServer, ReplicaFleet)
+from deeplearning4j_tpu.serving import GenerationEngine, InferenceServer
 from deeplearning4j_tpu.zoo.transformer_lm import CausalTransformerLM
 
 
@@ -103,8 +100,7 @@ class TestSocketEdgeCases:
             buf = sk.recv(4096)
             assert buf.startswith(b"HTTP/1.1 400"), buf[:80]
             sk.close()
-            # an unknown METHOD on a well-formed line is 501, the
-            # thread backend's unsupported-method answer
+            # an unknown METHOD on a well-formed line is 501
             sk = socket.create_connection((srv.host, srv.port),
                                           timeout=10)
             sk.sendall(b"BREW /healthz HTTP/1.1\r\nHost: x\r\n\r\n")
@@ -215,60 +211,6 @@ class TestIdleStreamCost:
             for sk, _ in socks:
                 sk.close()
             srv.stop()
-
-
-class TestThreadBackendSelectable:
-    def test_replica_thread_backend_roundtrip(self):
-        srv = _predict_server(http_backend="thread")
-        try:
-            c = http.client.HTTPConnection(srv.host, srv.port,
-                                           timeout=30)
-            c.request("POST", "/v1/models/m/predict",
-                      body=json.dumps({"inputs": X}).encode())
-            r = c.getresponse()
-            assert r.status == 200
-            assert json.loads(r.read())["outputs"] == \
-                [[2.0, 4.0, 6.0, 8.0]]
-            c.close()
-        finally:
-            srv.stop()
-
-    def test_router_thread_backend_roundtrip_and_stream(self, lm):
-        srv = InferenceServer(port=0, http_backend="thread")
-        g = srv.register_generator("lm", lm, num_slots=2, max_queue=16,
-                                   prompt_buckets=[8])
-        g.warmup()
-        fleet = ReplicaFleet(poll_interval_s=None)
-        fleet.add(srv)
-        router = FleetRouter(fleet)
-        host, port = router.serve(backend="thread")
-        body = json.dumps({"prompt": [1, 2, 3], "max_tokens": 4,
-                           "stream": True, "seed": 5,
-                           "timeout_ms": 60_000}).encode()
-        try:
-            sk, buf = _post_stream_head(host, port, body)
-            sk.settimeout(60)
-            while not buf.endswith(b"0\r\n\r\n"):
-                d = sk.recv(65536)
-                assert d, f"truncated stream: {buf[-80:]!r}"
-                buf += d
-            assert buf.count(b'"token"') == 4
-            sk.close()
-        finally:
-            router.stop()
-            fleet.stop(stop_replicas=True)
-
-    def test_unknown_backend_rejected(self):
-        with pytest.raises(ValueError):
-            InferenceServer(port=0, http_backend="gevent")
-        fleet = ReplicaFleet(poll_interval_s=None)
-        router = FleetRouter(fleet)
-        try:
-            with pytest.raises(ValueError):
-                router.serve(backend="gevent")
-        finally:
-            router.stop()
-            fleet.stop()
 
 
 class TestPipelinedDecodeIdentity:

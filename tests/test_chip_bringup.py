@@ -31,7 +31,7 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 # blocks (a table's worth for every slot and the null block, if not given)
 SHAPES = {
     "served": dict(S=8, H=12, D=64, Bs=16, T=1024),   # GPT-2-small
-    "toy": dict(S=4, H=4, D=16, Bs=8, T=192),         # bench.py's LM
+    "toy": dict(S=4, H=4, D=16, Bs=8, T=192),         # the CPU tests' LM
     # the benchmark's cell, gpt2-xl.decode_backlog
     "cell": dict(S=16, H=25, D=64, Bs=16, T=1024, N=321),
 }

@@ -536,7 +536,7 @@ class TestPoisonQuarantine:
         # the shared-faults hierarchy (FaultError, no longer a
         # ServingError subclass) still maps to HTTP 500 via the
         # front-end's default branch
-        from deeplearning4j_tpu.serving import _status_for
+        from deeplearning4j_tpu.serving.aio import _status_for
         assert _status_for(errs[3]) == 500
         assert "quarantined" in str(errs[3])
         assert [errs[i] for i in range(3)] == [None] * 3
@@ -752,7 +752,8 @@ class TestGracefulDrain:
             signal.signal(signal.SIGTERM, old)
             srv.stop()
 
-    def test_healthz_flags_stalled_loop(self, lm):
+    def test_healthz_flags_stalled_loop(self, lm, monkeypatch):
+        from deeplearning4j_tpu.serving import generation
         srv = InferenceServer(port=0)
         eng = srv.register_generator("gen", lm, num_slots=2,
                                      min_prompt_bucket=4).engine
@@ -769,12 +770,12 @@ class TestGracefulDrain:
                     jam.wait(3.0)
                 return False
         try:
-            eng._stall_timeout_s = 0.5
+            monkeypatch.setattr(generation, "STALL_TIMEOUT_S", 0.5)
             eng._faults = _Jam()
             time.sleep(2.2)  # loop is stuck inside the iteration; the
             # heartbeat has gone stale past the watchdog. The settle
             # time covers one full idle submit-wake park (up to 1 s,
-            # started before _stall_timeout_s shrank) plus comfortably
+            # started before STALL_TIMEOUT_S shrank) plus comfortably
             # more than the 0.5 s watchdog after the wedge engages.
             assert not eng.alive()
             with pytest.raises(urllib.error.HTTPError) as ei:
@@ -790,7 +791,6 @@ class TestGracefulDrain:
         finally:
             jam.set()
             eng._faults = None
-            eng._stall_timeout_s = 30.0
             srv.stop()
 
 

@@ -3,6 +3,7 @@ health-gated membership, straggler hedging under a retry budget, the
 compact /stats routing summary, streaming + mid-stream disconnect
 THROUGH the router, and zero-loss rolling restarts extending PR 4's
 single-replica drain guarantee fleet-wide."""
+import http.client
 import json
 import socket
 import threading
@@ -504,6 +505,66 @@ class TestRollingRestart:
         assert m.restarts == 3
         assert m.requests_lost == 0
         assert m.requests == m.responses
+
+    def test_zero_loss_predict_over_the_routers_listener(self, mlp):
+        """The same bar through the router's HTTP listener and
+        keep-alive client sockets: every 503 a draining replica emits
+        is absorbed by the router's retry path, so a client sees no
+        non-200 and the router loses nothing. Outputs are compared
+        within tolerance: coalescing pads requests into varying batch
+        buckets, and reductions across shapes are not bit-stable."""
+        xs = [(np.arange(4, dtype=np.float32) + i).reshape(1, 4)
+              for i in range(6)]
+        expected = [np.asarray(mlp.output(x)) for x in xs]
+        f = _predict_factory(mlp)
+        fleet = _mkfleet([f, f, f], poll_interval_s=0.05)
+        router = FleetRouter(fleet)
+        host, port = router.serve()
+        stop = threading.Event()
+        failures = []
+        counts = [0] * 6
+
+        def client(i):
+            body = json.dumps({"inputs": xs[i].tolist(),
+                               "timeout_ms": 60_000}).encode()
+            conn = http.client.HTTPConnection(host, port, timeout=60)
+            try:
+                while not stop.is_set():
+                    conn.request("POST", "/predict", body=body)
+                    r = conn.getresponse()
+                    data = r.read()
+                    if r.status != 200:
+                        failures.append((i, r.status, data[:200]))
+                    elif not np.allclose(json.loads(data)["outputs"],
+                                         expected[i], rtol=1e-4,
+                                         atol=1e-6):
+                        failures.append((i, "mismatch", data[:200]))
+                    else:
+                        counts[i] += 1
+            except Exception as e:   # noqa: BLE001 — record, never a
+                failures.append((i, repr(e)))   # silently dead client
+            finally:
+                conn.close()
+        threads = [threading.Thread(target=client, args=(i,))
+                   for i in range(6)]
+        try:
+            for t in threads:
+                t.start()
+            time.sleep(0.3)            # traffic is rolling
+            ok = fleet.rolling_restart(drain_timeout_s=30.0,
+                                       ready_timeout_s=120.0)
+            time.sleep(0.3)            # traffic outlives the restarts
+        finally:
+            stop.set()
+            for t in threads:
+                t.join(timeout=60)
+            router.stop()
+            fleet.stop(stop_replicas=True)
+        assert ok, "a replica failed to drain/return ready"
+        assert not failures, failures[:5]
+        assert all(c > 0 for c in counts), counts
+        assert fleet.metrics.restarts == 3
+        assert fleet.metrics.requests_lost == 0
 
     def test_zero_loss_token_identical_generation(self, tiny_lm):
         """Fleet-wide extension of recompute-recovery's guarantee for

@@ -314,6 +314,31 @@ class TestEngineRoundtrip:
         finally:
             eng.stop()
 
+    def test_ten_times_the_pools_sessions_resume_without_a_reprefill(
+            self, lm):
+        """Session capacity is bounded by host bytes, not by the pool:
+        ten times the conversations the pool can pin run two turns
+        each, every turn 1 before any turn 2, so each is evicted (and
+        demoted) long before its resume. No resume is an evicted
+        session's re-prefill (``session_misses`` stands still), all
+        but the few still pinned are restored from the host tier, and
+        every token matches the oracle (``_turn`` asserts it)."""
+        eng = _mkeng(lm)
+        try:
+            blocks_a_session = -(-(16 + 5 - 1) // 8)    # turn 1's pin
+            pinned = eng._allocator.capacity // blocks_a_session
+            n = 10 * pinned
+            outs = [_turn(eng, lm, f"s{i}", _prompt(i)) for i in range(n)]
+            misses = eng.metrics.session_misses
+            restores = _offsnap(eng)["restores"]
+            for i in range(n):
+                _turn(eng, lm, f"s{i}", _prompt(i) + outs[i] + [7, 11],
+                      n=4)
+            assert eng.metrics.session_misses == misses
+            assert _offsnap(eng)["restores"] - restores >= n - pinned
+        finally:
+            eng.stop()
+
     def test_prefetch_overlaps_restore(self, lm):
         """A resume submitted while its session sits in the host tier
         kicks the prefetcher at submit time; admission then takes the

@@ -7,6 +7,8 @@ sidecar, never be laundered to finite garbage), COW copying scale rows
 with blocks, recompute-recovery rebuilding quantized pools
 token-identically, int8 weight-only MLP accuracy, and /stats //metrics
 exposition parity for the new quantization observability leaves."""
+import collections
+import re
 import threading
 import urllib.request
 
@@ -393,6 +395,66 @@ class TestEngineKVDtypes:
 # ---------------------------------------------------------------------------
 # quarantine THROUGH the quantized cache
 # ---------------------------------------------------------------------------
+class TestDotOperandAudit:
+    """What the MXU streams is a dot's OPERAND dtype (the result is f32
+    by design: ``preferred_element_type``). On a bf16 or int8 pool the
+    two cache-side attention dots of every layer (QK and PV) must run
+    on bf16 operands and NOTHING else may change dtype: an f32-operand
+    dot there would mean the dequantised cache was materialised in f32.
+    Read from the StableHLO of the programs the engine itself lowers
+    (backend-independent: the CPU backend upcasts bf16 only later)."""
+
+    N_LAYERS = 2
+
+    @staticmethod
+    def _dot_operands(text):
+        pairs = re.findall(
+            r"stablehlo\.dot(?:_general)?\b[^\n]*:\s*"
+            r"\(tensor<[^>]*x(\w+)>,\s*tensor<[^>]*x(\w+)>\)", text)
+        return collections.Counter(pairs)
+
+    def _lowered(self, monkeypatch, program, dt):
+        """StableHLO of one of the engine's programs, lowered with the
+        arguments and donation the engine compiles it with."""
+        from deeplearning4j_tpu.serving import generation
+        texts = []
+        real = generation.compile_memoized
+
+        def capture(fn, args, donate):
+            texts.append(jax.jit(fn, donate_argnums=tuple(donate))
+                         .lower(*args).as_text())
+            return real(fn, args, donate)
+
+        kw = dict(num_slots=4, max_queue=16, prompt_buckets=[16],
+                  kv_dtype=dt)
+        if program != "slot_decode":
+            kw.update(cache="paged", block_size=8,
+                      prefill_chunk_tokens=16)
+        eng = GenerationEngine(_lm(), **kw)
+        try:
+            monkeypatch.setattr(generation, "compile_memoized", capture)
+            if program == "chunk":
+                eng._get_chunk_exe(16, 8)
+            else:
+                eng._get_decode_exe()
+        finally:
+            eng.stop()
+        assert len(texts) == 1
+        return self._dot_operands(texts[0])
+
+    @pytest.mark.parametrize("program",
+                             ["paged_decode", "slot_decode", "chunk"])
+    def test_only_the_attention_dots_move_to_bf16(self, monkeypatch,
+                                                  program):
+        base = self._lowered(monkeypatch, program, "f32")
+        assert set(base) == {("f32", "f32")}
+        moved = 2 * self.N_LAYERS            # QK and PV, every layer
+        for dt in ("bf16", "int8"):
+            dots = self._lowered(monkeypatch, program, dt)
+            assert dots == {("f32", "f32"): base["f32", "f32"] - moved,
+                            ("bf16", "bf16"): moved}, (program, dt, dots)
+
+
 class _CachePoisonLM(CausalTransformerLM):
     """Poison rig that NaNs the prefill K/V SLABS (never the prefill
     logits) for prompts containing NAN_TRIGGER. The NaN therefore

@@ -15,6 +15,7 @@ import json
 import os
 import re
 import socket
+import threading
 import time
 import tracemalloc
 import urllib.error
@@ -519,6 +520,49 @@ class TestEngineSpans:
                         if s["kind"] == "admission")
             assert adm2["attrs"]["verdict"] == "shed"
             assert "error" in adm2["attrs"]
+        finally:
+            srv.stop()
+
+
+    def test_tracing_leaves_the_tokens_as_they_were(self, tiny_lm):
+        """A traced request samples what the same request samples
+        untraced (all temperatures, requests in flight together), and
+        each trace holds at least its admission, queue and decode."""
+        srv = InferenceServer(port=0, tracing=True)
+        g = srv.register_generator("lm", tiny_lm, num_slots=2,
+                                   max_seq_len=32, prompt_buckets=[8],
+                                   cache="paged", block_size=4,
+                                   num_blocks=16)
+        g.warmup()
+        cases = [([1 + i, 2, 3], 4 + i % 3, (0.0, 0.8)[i % 2], i)
+                 for i in range(8)]
+
+        def run(traced):
+            outs, traces = [None] * len(cases), [None] * len(cases)
+
+            def go(i):
+                prompt, n, temp, seed = cases[i]
+                if traced:
+                    traces[i] = srv.tracer.begin(f"t{i}")
+                outs[i] = g.engine.generate(
+                    prompt, max_tokens=n, temperature=temp, seed=seed,
+                    trace=traces[i])["tokens"]
+                if traced:
+                    srv.tracer.finish(traces[i])
+            threads = [threading.Thread(target=go, args=(i,))
+                       for i in range(len(cases))]
+            for t in threads:
+                t.start()
+            for t in threads:
+                t.join(timeout=120)
+            return outs, traces
+        try:
+            plain, _ = run(False)
+            traced, traces = run(True)
+            assert traced == plain
+            for tr in traces:
+                kinds = {s["kind"] for s in tr.to_dict()["spans"]}
+                assert {"admission", "queue", "decode"} <= kinds
         finally:
             srv.stop()
 

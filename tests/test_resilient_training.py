@@ -70,12 +70,11 @@ class TestSharedInjector:
         # one class hierarchy for both runtimes: an `except
         # TransientFault` in serving code catches a training fire
         from deeplearning4j_tpu import faults as shared
-        from deeplearning4j_tpu.serving import faults as served
+        from deeplearning4j_tpu import serving as served
         assert served.FaultInjector is shared.FaultInjector
         assert served.TransientFault is shared.TransientFault
         assert served.CorruptedStateFault is shared.CorruptedStateFault
         assert served.PoisonRequestError is shared.PoisonRequestError
-        assert served.poll_until_idle is shared.poll_until_idle
 
     def test_training_seams_exist_and_unknown_rejected(self):
         FaultInjector(rates={"train_step": 0.5, "data_batch": 0.1,

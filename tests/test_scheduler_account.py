@@ -409,9 +409,8 @@ def test_decode_and_chunk_programs_lower_as_jit_step_and_jit_chunk(
 
 
 # -- the HTTP tier's boundary ------------------------------------------------
-@pytest.mark.parametrize("backend", ["aio", "thread"])
-def test_stream_write_is_counted_from_the_emit_stamp(lm, backend):
-    srv = InferenceServer(port=0, http_backend=backend)
+def test_stream_write_is_counted_from_the_emit_stamp(lm):
+    srv = InferenceServer(port=0)
     try:
         gen = srv.register_generator(
             "lm", lm, num_slots=2, max_queue=8, min_prompt_bucket=4,
