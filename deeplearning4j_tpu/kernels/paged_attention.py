@@ -1,10 +1,12 @@
-"""Paged KV-cache decode attention (Pallas TPU + XLA fallback), and
-the one place that knows how a pool block is laid out.
+"""Paged KV-cache attention: a decode step's (Pallas TPU + XLA
+fallback) and a prefill chunk's (XLA), and the one place that knows how
+a pool block is laid out.
 
 The paged sibling of :mod:`.decode_attention`: one query row per
-sequence attends over a prefix whose K/V lives in POOL BLOCKS
-(`serving/paging.py`) addressed through a per-sequence block table,
-instead of a contiguous per-slot panel.
+sequence (:func:`paged_attention`), or the rows of one sequence's
+prefill chunk (:func:`paged_prefill_attention`), attend over a prefix
+whose K/V lives in POOL BLOCKS (`serving/paging.py`) addressed through
+a per-sequence block table, instead of a contiguous per-slot panel.
 
 **The pool's layout.** One array a layer, ``[num_blocks, H_kv, Bs,
 2 * D]``: a position's key in lanes ``0 .. D - 1`` of its row and its
@@ -19,14 +21,14 @@ pool is a :class:`~.kv_quant.QuantArray` whose values have that shape
 and whose f32 sidecar is ``[num_blocks, 2, H_kv, Bs]``: the keys'
 scales, then the values'. Everything else addresses a pool by its
 leading block axis alone. :func:`kv_pool_zeros`, :func:`fuse_kv`,
-:func:`split_kv`, :func:`kv_pool_set`, :func:`gather_blocks` and
-:func:`gather_span` are the layout's whole surface; the kernel below is
-its other reader.
+:func:`split_kv`, :func:`kv_pool_set`, :func:`kv_pool_set_span`,
+:func:`gather_blocks` and :func:`gather_span` are the layout's whole
+surface; the kernel below is its other reader.
 
-The op is HBM-bandwidth bound by its bytes, but a Pallas grid step has
-a cost of its own (~0.35 us with three small operands on a v5e), so the
-kernel's job is to stream the LIVE K/V once in few, large steps and
-keep the online-softmax state in VMEM.
+**The decode kernel.** The op is HBM-bandwidth bound by its bytes, but
+a Pallas grid step has a cost of its own (~0.35 us with three small
+operands on a v5e), so the kernel's job is to stream the LIVE K/V once
+in few, large steps and keep the online-softmax state in VMEM.
 
 The grid is ``(S, ceil(B / G))``: one grid step takes one slot, ALL its
 heads, and ``G`` table entries. A pool block ``[H, Bs, 2 * D]`` is
@@ -58,6 +60,18 @@ splits the lanes of what it gathered, and reuses
 :func:`~.decode_attention.decode_attention_xla` -- the gathered
 [S, H, B*Bs, D] panels are bit-identical to a slot cache holding the
 same prefix, which is what makes paged-vs-slot token parity testable.
+
+**A chunk's write** (:func:`kv_pool_set_span`). The ``C`` rows of a
+prefill chunk are consecutive positions of one table, so they are
+written by blocks: the ``C / Bs + 1`` blocks they lie in are read,
+overlaid and written back whole. The row-by-row scatter of
+:func:`kv_pool_set` is for a decode step's rows, one a sequence.
+
+**A chunk's attention** (:func:`paged_prefill_attention`) is XLA's:
+the table's span gathered (:func:`gather_span`) and attended densely
+(:func:`span_attend`, the mathematics the slot backend's verify shares).
+Its cost follows the table's bucket, not the live length; what a tiled
+kernel over the blocks read beside it on a v5e is in PERF.md section 7.
 """
 from __future__ import annotations
 
@@ -130,6 +144,53 @@ def kv_pool_set(pool, idx, k, v):
         jnp.concatenate([k, v], axis=-1).astype(pool.dtype))
 
 
+def kv_pool_set_span(pool, block_table, p0, k, v):
+    """Write the rows ``k`` and ``v`` ``[C, H, D]`` of the ``C``
+    consecutive positions ``p0 .. p0 + C - 1`` of one sequence (a
+    prefill chunk, a verify span) into ``pool`` through its
+    ``block_table`` ``[n_blocks]``: what :func:`kv_pool_set` writes at
+    ``(block_table[j // Bs], :, j % Bs)``, by BLOCKS. The positions lie
+    in ``C / Bs + 1`` blocks at most: those are read, the rows laid
+    over them at ``p0 % Bs``, and written back whole, one ``[H, Bs,
+    2 * D]`` update a block. (A scatter a row costs a v5e ~70 ns for
+    each of ``C * H`` rows whatever their width: 449 us a layer for 256
+    positions of 25 heads, 21.6 of GPT-2 XL's 35.6 ms a chunk; PERF.md
+    section 6, PR 33.) A block read and written back unchanged in its
+    other rows is the sequence's own (a shared block is copied before
+    its sharer writes into it); a position past the table goes to the
+    null block, as one on a NULL-padded entry does."""
+    quant = is_quantized(pool)
+    vals = pool.q if quant else pool
+    Bs = vals.shape[2]
+    C = k.shape[0]
+    B = block_table.shape[0]
+    nb = (C + Bs - 2) // Bs + 1
+    i = p0 // Bs + jnp.arange(nb)
+    ids = jnp.where(i < B, block_table[jnp.minimum(i, B - 1)], 0)
+    at = (jnp.asarray(p0) % Bs).astype(jnp.int32)
+
+    def lay(old, rows, axis):
+        """``rows`` [C, ...] over the blocks ``old`` [nb, ...] whose
+        axis ``axis`` is the block's positions."""
+        win = jnp.moveaxis(old, axis, 1)
+        shape = win.shape
+        win = lax.dynamic_update_slice(
+            win.reshape((nb * Bs,) + shape[2:]), rows.astype(old.dtype),
+            (at,) + (0,) * (rows.ndim - 1))
+        return jnp.moveaxis(win.reshape(shape), 1, axis)
+
+    if quant:
+        qk, qv = quantize_rows(k), quantize_rows(v)
+        return QuantArray(
+            pool.q.at[ids].set(lay(
+                pool.q[ids], jnp.concatenate([qk.q, qv.q], axis=-1), 2)),
+            pool.scale.at[ids].set(lay(
+                pool.scale[ids], jnp.stack([qk.scale, qv.scale], axis=1),
+                3)))
+    return pool.at[ids].set(lay(pool[ids],
+                                jnp.concatenate([k, v], axis=-1), 2))
+
+
 def gather_blocks(pool, block_tables):
     """Pool + [S, B] tables -> the dense per-sequence K and V panels
     ``[S, H, B*Bs, D]`` (the slot-cache layout), via one gather whose
@@ -182,6 +243,65 @@ def paged_attention_xla(q, pool, block_tables, lengths):
     out = jax.vmap(lambda qg: decode_attention_xla(qg, k, v, lengths),
                    in_axes=2, out_axes=2)(q.reshape(S, Hkv, Hq // Hkv, D))
     return out.reshape(S, Hq, D)
+
+
+def span_attend(q, kk, vv, gpos, p0c, out_dtype):
+    """Causal span attention over one gathered K/V panel: the
+    mathematics of :func:`paged_prefill_attention` (a block-table
+    gather) and of ``SelfAttentionLayer.apply_verify`` (the dense slot
+    panel).
+
+    q: [C, H_q, Dh] span queries (H_q a multiple of H: grouped-query
+    heads); kk/vv: [H, T, Dh] panels — plain f32
+    (bit-identical to the pre-quantization math), bf16, or int8
+    QuantArrays with [H, T] scales; gpos: [C] global positions (row c
+    sees keys j <= gpos[c]); p0c: scalar — first position NOT written
+    by this sequence (p0 + C): V beyond it is a previous occupant's
+    stale leavings and may be non-finite, so it is where-masked
+    (0 * NaN = NaN). Quantized legs run bf16-operand dots with f32
+    accumulation, K scales applied post-dot and V scales folded into
+    the probabilities — the same scale placement as the decode kernels
+    (kernels/decode_attention.py), checked in StableHLO
+    (tests/test_kv_quant.py::TestDotOperandAudit)."""
+    H, T, Dh = kk.shape
+    C, Hq = q.shape[:2]
+    if Hq != H:
+        # grouped-query heads (query head i reads KV head i // g): the
+        # g members of a group are mapped over the one gathered panel
+        out = jax.vmap(
+            lambda qg: span_attend(qg, kk, vv, gpos, p0c, out_dtype),
+            in_axes=2, out_axes=2)(q.reshape(C, H, Hq // H, Dh))
+        return out.reshape(C, Hq, Dh)
+    scale = 1.0 / jnp.sqrt(jnp.float32(Dh))
+    valid = jnp.arange(T)[None, None, :] <= gpos[None, :, None]
+    written = (jnp.arange(T) < p0c)[None, :, None]
+    if is_quantized(kk) or kk.dtype == jnp.bfloat16:
+        kb = (kk.q if is_quantized(kk) else kk).astype(jnp.bfloat16)
+        vb = (vv.q if is_quantized(vv) else vv).astype(jnp.bfloat16)
+        s = jnp.einsum("chd,htd->hct", q.astype(jnp.bfloat16), kb,
+                       preferred_element_type=jnp.float32) * scale
+        if is_quantized(kk):              # [H, T] per-position scales
+            s = s * kk.scale[:, None, :]
+        s = jnp.where(valid, s, -1e30)
+        p = jax.nn.softmax(s, axis=-1)
+        if is_quantized(vv):
+            # fold V scales into p. The where-guard matters: a stale
+            # row's scale may be NaN (poison is scale-carried, see
+            # kv_quant.quantize_rows) and 0 * NaN = NaN
+            p = jnp.where(valid, p * vv.scale[:, None, :], 0.0)
+        else:
+            p = jnp.where(valid, p, 0.0)
+        vb = jnp.where(written, vb, jnp.bfloat16(0))
+        att = jnp.einsum("hct,htd->chd", p.astype(jnp.bfloat16), vb,
+                         preferred_element_type=jnp.float32)
+        return att.astype(out_dtype)
+    s = jnp.einsum("chd,htd->hct", q.astype(jnp.float32),
+                   kk.astype(jnp.float32)) * scale
+    s = jnp.where(valid, s, -1e30)
+    p = jax.nn.softmax(s, axis=-1)
+    p = jnp.where(valid, p, 0.0)
+    vv = jnp.where(written, vv.astype(jnp.float32), 0.0)
+    return jnp.einsum("hct,htd->chd", p, vv).astype(out_dtype)
 
 
 # ---------------------------------------------------------------------------
@@ -408,3 +528,27 @@ def paged_attention(q, pool, block_tables, lengths, impl: str = "auto",
     if impl == "xla":
         return paged_attention_xla(q, pool, block_tables, lengths)
     raise ValueError(f"unknown paged attention impl {impl!r}")
+
+
+def paged_prefill_attention(q, pool, block_table, p0):
+    """A prefill chunk's causal attention over its sequence's prefix in
+    the paged pool.
+
+    q: [C, H_q, D], the chunk's queries, row ``c`` at position ``p0 +
+    c``; pool: [N, H_kv, Bs, 2 * D] (any pool type) AFTER the chunk's
+    own rows were written into it (the chunk's K and V come back out of
+    the pool they went into, so a start at ``p0 > 0`` -- a second
+    chunk, a shared prefix, a session, a recovery -- needs nothing
+    special); block_table: [n_blocks] with ``n_blocks * Bs >= p0 + C``,
+    NULL-padded past the sequence's allocation; p0: scalar. Returns
+    [C, H_q, D]: row ``c`` attends keys ``j <= p0 + c``; a row of
+    padding attends like any other and is nobody's to read.
+
+    The table's whole span is gathered out of the pool as ``[H, T, D]``
+    panels and attended densely (:func:`span_attend`): XLA fuses the
+    pair, and on a v5e it costs GPT-2 XL's chunk ~0.9 of its 17 ms at
+    the tables the benchmark's traffic meets (PERF.md section 5,
+    PR 33)."""
+    C = q.shape[0]
+    kk, vv = gather_span(pool, block_table)
+    return span_attend(q, kk, vv, p0 + jnp.arange(C), p0 + C, q.dtype)

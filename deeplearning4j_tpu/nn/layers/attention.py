@@ -39,65 +39,6 @@ def _cache_row(cache, i):
     return cache[i]
 
 
-def _span_attend(q, kk, vv, gpos, p0c, out_dtype):
-    """Causal span attention over one gathered K/V panel — the shared
-    math of :meth:`SelfAttentionLayer.apply_verify` (dense slot panel)
-    and :meth:`SelfAttentionLayer.apply_prefill_paged` (block-table
-    gather).
-
-    q: [C, H_q, Dh] span queries (H_q a multiple of H: grouped-query
-    heads); kk/vv: [H, T, Dh] panels — plain f32
-    (bit-identical to the pre-quantization math), bf16, or int8
-    QuantArrays with [H, T] scales; gpos: [C] global positions (row c
-    sees keys j <= gpos[c]); p0c: scalar — first position NOT written
-    by this sequence (p0 + C): V beyond it is a previous occupant's
-    stale leavings and may be non-finite, so it is where-masked
-    (0 * NaN = NaN). Quantized legs run bf16-operand dots with f32
-    accumulation, K scales applied post-dot and V scales folded into
-    the probabilities — the same scale placement as the decode kernels
-    (kernels/decode_attention.py), checked in StableHLO
-    (tests/test_kv_quant.py::TestDotOperandAudit)."""
-    H, T, Dh = kk.shape
-    C, Hq = q.shape[:2]
-    if Hq != H:
-        # grouped-query heads (query head i reads KV head i // g): the
-        # g members of a group are mapped over the one gathered panel
-        out = jax.vmap(
-            lambda qg: _span_attend(qg, kk, vv, gpos, p0c, out_dtype),
-            in_axes=2, out_axes=2)(q.reshape(C, H, Hq // H, Dh))
-        return out.reshape(C, Hq, Dh)
-    scale = 1.0 / jnp.sqrt(jnp.float32(Dh))
-    valid = jnp.arange(T)[None, None, :] <= gpos[None, :, None]
-    written = (jnp.arange(T) < p0c)[None, :, None]
-    if is_quantized(kk) or kk.dtype == jnp.bfloat16:
-        kb = (kk.q if is_quantized(kk) else kk).astype(jnp.bfloat16)
-        vb = (vv.q if is_quantized(vv) else vv).astype(jnp.bfloat16)
-        s = jnp.einsum("chd,htd->hct", q.astype(jnp.bfloat16), kb,
-                       preferred_element_type=jnp.float32) * scale
-        if is_quantized(kk):              # [H, T] per-position scales
-            s = s * kk.scale[:, None, :]
-        s = jnp.where(valid, s, -1e30)
-        p = jax.nn.softmax(s, axis=-1)
-        if is_quantized(vv):
-            # fold V scales into p. The where-guard matters: a stale
-            # row's scale may be NaN (poison is scale-carried, see
-            # kv_quant.quantize_rows) and 0 * NaN = NaN
-            p = jnp.where(valid, p * vv.scale[:, None, :], 0.0)
-        else:
-            p = jnp.where(valid, p, 0.0)
-        vb = jnp.where(written, vb, jnp.bfloat16(0))
-        att = jnp.einsum("hct,htd->chd", p.astype(jnp.bfloat16), vb,
-                         preferred_element_type=jnp.float32)
-        return att.astype(out_dtype)
-    s = jnp.einsum("chd,htd->hct", q.astype(jnp.float32),
-                   kk.astype(jnp.float32)) * scale
-    s = jnp.where(valid, s, -1e30)
-    p = jax.nn.softmax(s, axis=-1)
-    p = jnp.where(valid, p, 0.0)
-    vv = jnp.where(written, vv.astype(jnp.float32), 0.0)
-    return jnp.einsum("hct,htd->chd", p, vv).astype(out_dtype)
-
-
 @register
 class SelfAttentionLayer(Layer):
     """Multi-head self-attention over recurrent-format [B, T, C] input."""
@@ -299,6 +240,7 @@ class SelfAttentionLayer(Layer):
         contract as the paged chunk path (rows past ``T_max`` are
         dropped by the scatter). Returns (out [1, C, n_out], k_cache,
         v_cache)."""
+        from ...kernels.paged_attention import span_attend
         if not self.causal:
             raise ValueError("cached decode needs causal=True attention")
         C = x.shape[1]
@@ -317,18 +259,22 @@ class SelfAttentionLayer(Layer):
         # with the block-table gather replaced by one dense panel
         kk = _cache_row(k_cache, slot)
         vv = _cache_row(v_cache, slot)
-        att = _span_attend(q, kk, vv, gpos, p0 + C, x.dtype)
+        att = span_attend(q, kk, vv, gpos, p0 + C, x.dtype)
         out = att.reshape(C, self.n_out) @ params["Wo"] + params["b"]
         return self.activation(out)[None], k_cache, v_cache
 
     def apply_prefill_paged(self, params, x, pool, block_table, p0,
                             chunk_len):
         """One prefill CHUNK against the paged pool: project the chunk,
-        scatter its K/V rows into the owning blocks, and attend each
-        chunk query causally over the gathered prefix (earlier chunks +
-        this one). Chunked prefill is what keeps a long prompt from
-        monopolizing the decode loop — the scheduler interleaves these
-        with decode steps (Sarathi-Serve, OSDI '24; PAPERS.md).
+        write its K/V rows into the owning blocks, and attend each
+        chunk query causally over the prefix (earlier chunks + this
+        one) as it comes back out of the pool
+        (:func:`~...kernels.paged_attention.kv_pool_set_span`, a write
+        by blocks, then
+        :func:`~...kernels.paged_attention.paged_prefill_attention`).
+        Chunked prefill is what keeps a long prompt from monopolizing
+        the decode loop — the scheduler interleaves these with decode
+        steps (Sarathi-Serve, OSDI '24; PAPERS.md).
 
         x: [1, C, Cin] chunk activations (C is the chunk bucket);
         pool: [N, H, Bs, 2 * Dh]; block_table: [n_blocks] int32, sized
@@ -339,35 +285,28 @@ class SelfAttentionLayer(Layer):
         — masked by every reader, and overwritten by the decode step's
         write at ``pos`` before that position is ever unmasked — and
         rows past the allocation land on NULL-padded table entries,
-        i.e. the reserved null block. An UNDERSIZED table is the one
-        fatal case: position ``p0 + C - 1`` would alias into another
-        sequence's block, which is why the size contract above is the
-        caller's to uphold.
+        i.e. the reserved null block; their output rows are nobody's to
+        read. The size contract above is the caller's to uphold: rows
+        past an UNDERSIZED table go to the null block, that is, are
+        lost.
         Returns (out [1, C, n_out], pool).
         """
-        from ...kernels.paged_attention import gather_span, kv_pool_set
+        from ...kernels.paged_attention import (kv_pool_set_span,
+                                                paged_prefill_attention)
         if not self.causal:
             raise ValueError("cached decode needs causal=True attention")
         C = x.shape[1]
         H = self.n_heads
         Dh = self.n_out // H
-        Bs = pool.shape[2]
         xx = x[0]
         q = (xx @ params["Wq"]).reshape(C, H, Dh)
         k_t = (xx @ params["Wk"]).reshape(C, H, Dh)
         v_t = (xx @ params["Wv"]).reshape(C, H, Dh)
-        gpos = p0 + jnp.arange(C)
-        blk = block_table[gpos // Bs]
-        off = gpos % Bs
-        heads = jnp.arange(H)[None, :]
-        pool = kv_pool_set(pool, (blk[:, None], heads, off[:, None]),
-                           k_t, v_t)
-        # gather the sequence's whole table span and attend causally:
+        pool = kv_pool_set_span(pool, block_table, p0, k_t, v_t)
         # chunk query c (global position p0+c) sees keys j <= p0+c —
-        # earlier chunks' K/V comes back out of the pool it went into
-        # (quantized on write, scales gathered alongside)
-        kk, vv = gather_span(pool, block_table)
-        att = _span_attend(q, kk, vv, gpos, p0 + C, x.dtype)
+        # earlier chunks' K/V, and this chunk's own, come back out of
+        # the pool they went into (quantized on write)
+        att = paged_prefill_attention(q, pool, block_table, p0)
         out = att.reshape(C, self.n_out) @ params["Wo"] + params["b"]
         return self.activation(out)[None], pool
 

@@ -41,9 +41,9 @@ from typing import Dict, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 
-from ..kernels.paged_attention import (gather_span, kv_pool_set,
-                                       paged_attention)
-from ..nn.layers.attention import _span_attend
+from ..kernels.paged_attention import (kv_pool_set, kv_pool_set_span,
+                                       paged_attention,
+                                       paged_prefill_attention)
 from ..nn.layers.moe import MoeAccount, moe_ffn
 
 #: layout of the int32 vector both forwards return beside the logits:
@@ -296,11 +296,13 @@ class Lfm2MoeLM:
         p0, chunk_len scalars; block_table [n_blocks]. The chunk reads
         row ``slot`` of every state array (zeros instead where
         ``p0 == 0``: a request never inherits its slot's last occupant)
-        and writes back the state after its last valid row. Rows past
-        ``chunk_len`` route to no expert. Returns (logits [C, V],
-        pools, state, counters)."""
+        and writes back the state after its last valid row; an attention
+        layer writes the chunk's K and V into its pool by blocks and
+        attends over the sequence's span as it comes back out
+        (:func:`~..kernels.paged_attention.paged_prefill_attention`).
+        Rows past ``chunk_len`` route to no expert. Returns (logits
+        [C, V], pools, state, counters)."""
         C = tokens.shape[1]
-        Bs = pools[0].shape[2] if pools else 1
         gpos = p0 + jnp.arange(C)
         live = jnp.arange(C) < chunk_len
         x = params["embed"][tokens[0]].astype(jnp.float32)
@@ -327,13 +329,10 @@ class Lfm2MoeLM:
             else:
                 with jax.named_scope("lfm2.attn"):
                     q, k, v = self._qkv(w, h, gpos)
-                    at = (block_table[gpos // Bs][:, None],
-                          jnp.arange(self.n_kv_heads)[None, :],
-                          (gpos % Bs)[:, None])
-                    pools[ai] = kv_pool_set(pools[ai], at, k, v)
-                    kk, vv = gather_span(pools[ai], block_table)
-                    att = _span_attend(q, kk, vv, gpos, p0 + C,
-                                       jnp.float32)
+                    pools[ai] = kv_pool_set_span(pools[ai], block_table,
+                                                 p0, k, v)
+                    att = paged_prefill_attention(q, pools[ai],
+                                                  block_table, p0)
                     op = self._mm(att.reshape(C, self.d_model), w["Wo"])
                 ai += 1
             x = x + op

@@ -155,7 +155,7 @@ class CausalTransformerLM:
         """One prefill CHUNK against the paged pools: embed the chunk
         at its global positions, run every block's
         ``apply_prefill_paged`` (scatter K/V into the owning blocks,
-        attend causally over the gathered prefix), and return the
+        attend causally over the prefix in the pool), and return the
         chunk's logits. The caller splits a prompt into chunks and
         feeds them in order; on the final chunk it samples from row
         ``chunk_len - 1``.

@@ -23,7 +23,8 @@ from deeplearning4j_tpu.kernels.decode_attention import \
 from deeplearning4j_tpu.kernels.flash_attention import flash_attention
 from deeplearning4j_tpu.kernels.kv_quant import QuantArray
 from deeplearning4j_tpu.kernels.paged_attention import (
-    KERNEL_NAME, gather_span, kv_pool_set, paged_attention_pallas)
+    KERNEL_NAME, kv_pool_set, kv_pool_set_span, paged_attention_pallas,
+    paged_prefill_attention)
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -152,19 +153,16 @@ def _decode_layer(pool, q, k, v, tables, pos):
                                   interpret=False), pool
 
 
-def _chunk_layer(pool, k, v, table, p0):
+def _chunk_layer(pool, q, k, v, table, p0):
     """What one layer of ``jit_chunk`` does to its pool: the chunk's
-    rows written, then the sequence's span gathered out."""
-    Bs = pool.shape[2]
-    gpos = p0 + jnp.arange(k.shape[0])
-    pool = kv_pool_set(pool, (table[gpos // Bs][:, None],
-                              jnp.arange(k.shape[1])[None],
-                              (gpos % Bs)[:, None]), k, v)
-    kk, vv = gather_span(pool, table)
-    return kk.astype(jnp.float32).sum() + vv.astype(jnp.float32).sum(), pool
+    rows written by blocks, then the sequence's span gathered out and
+    attended."""
+    pool = kv_pool_set_span(pool, table, p0, k, v)
+    return paged_prefill_attention(q, pool, table, p0), pool
 
 
-@pytest.mark.parametrize("program", ["step", "chunk"])
+@pytest.mark.parametrize("program", ["step", "chunk16", "chunk32",
+                                     "chunk64"])
 @pytest.mark.parametrize("cell", list(CELLS))
 def test_a_donated_pool_goes_through_a_layer_with_no_relayout(
         v5e, cell, program):
@@ -172,9 +170,14 @@ def test_a_donated_pool_goes_through_a_layer_with_no_relayout(
     result has the pool's shape, takes the pool in the default row-major
     tiled layout and aliases its output to it; the step has exactly the
     one kernel. (Two arrays ``[N, H, Bs, 64]`` compiled to four and six
-    pool-sized copies here: 39 of ``gpt2-xl``'s 62 ms a step.)"""
+    pool-sized copies here: 39 of ``gpt2-xl``'s 62 ms a step.) A chunk
+    layer, at each table bucket the cells' traffic meets, is the write
+    by blocks and XLA's span path: no kernel, and of the pool the write
+    gathers the ``C / Bs + 1`` blocks the chunk lies in and the
+    attention the table's span, nothing else."""
     S, Hq, Hkv, N, dt = CELLS[cell]
-    D, Bs, B, C = 64, 16, 64, 256
+    D, Bs, C = 64, 16, 256
+    B = 64 if program == "step" else int(program[5:])
     sds = jax.ShapeDtypeStruct
     pool = _pool(N, Hkv, Bs, D, dt)
     rows = lambda n: sds((n, Hkv, D), jnp.float32)        # noqa: E731
@@ -184,9 +187,13 @@ def test_a_donated_pool_goes_through_a_layer_with_no_relayout(
                    sds((S,), jnp.int32), donate=(0,))
         assert _custom_calls(text) == [KERNEL_NAME]
     else:
-        text = v5e(_chunk_layer, pool, rows(C), rows(C),
-                   sds((B,), jnp.int32), sds((), jnp.int32), donate=(0,),
-                   kernel=False)
+        text = v5e(_chunk_layer, pool, sds((C, Hq, D), jnp.float32),
+                   rows(C), rows(C), sds((B,), jnp.int32),
+                   sds((), jnp.int32), donate=(0,), kernel=False)
+        gathered = sorted(int(n) for n in re.findall(
+            r"= (?:f32|bf16)\[(\d+),%d,%d,\d+\]\S* gather\(" % (Hkv, Bs),
+            text))
+        assert gathered == sorted([C // Bs + 1, B])
     shape = "%s[%d,%d,%d,%d]" % (dt, N, Hkv, Bs, 2 * D)
     copies = [ln.strip()[:120] for ln in text.splitlines()
               if re.search(r"= " + re.escape(shape) + r"\S* copy\(", ln)]

@@ -409,6 +409,44 @@ def test_served_tokens_are_the_references_greedy_tokens(baseline, ref):
         assert (gap <= 2 * LOGIT_TOL).all(), (toks, first, gap)
 
 
+@pytest.fixture(scope="module")
+def tokens_written_by_rows(lm):
+    """The same five prompts through an engine whose chunk programs
+    write their K and V a row at a time (``kv_pool_set``, as before
+    PR 33) where the model writes them by blocks."""
+    import importlib
+    zoo = importlib.import_module("deeplearning4j_tpu.zoo.lfm2_moe")
+    shapes = []
+
+    def by_rows(pool, table, p0, k, v):
+        shapes.append((k.shape, pool.shape))
+        g = p0 + jnp.arange(k.shape[0])
+        Bs = pool.shape[2]
+        return zoo.kv_pool_set(
+            pool, (table[g // Bs][:, None], jnp.arange(k.shape[1])[None, :],
+                   (g % Bs)[:, None]), k, v)
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(zoo, "kv_pool_set_span", by_rows)
+        eng = GenerationEngine(lm, **ENGINE)
+        try:
+            eng.warmup()
+            tokens = [eng.generate(p, max_tokens=NEW)["tokens"]
+                      for p in PROMPTS]
+        finally:
+            eng.stop()
+    # 2 KV heads of 16, a chunk of 16 rows
+    assert shapes and set(shapes) == {((16, 2, 16), (33, 2, 8, 32))}
+    return tokens
+
+
+@pytest.mark.parametrize("i", range(len(PROMPTS)))
+def test_greedy_tokens_equal_those_of_a_chunk_written_by_rows(
+        baseline, tokens_written_by_rows, i):
+    """Prompts of 1, 2 and 3 chunks, grouped-query heads, the conv
+    state beside the pool: token for token."""
+    assert tokens_written_by_rows[i] == baseline[0][i]
+
+
 def test_zero_compiles_after_warmup_and_the_counters_add_up(baseline, lm):
     _, compiles, s0, s1 = baseline
     assert compiles == 0

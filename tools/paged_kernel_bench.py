@@ -13,6 +13,17 @@ lengths.
 grouped-query shape of ``lfm2-8b-a1b.decode_backlog`` (PR 30): three
 calls a step, four query heads to a KV head.
 
+``--prefill`` times a chunk's two pool operations instead (PR 33),
+``--layers`` of each in one program, every call with a pool of its own
+as every layer of the program has (a gather out of one shared pool is
+hoisted out of the timed loop and reads 10x too fast): the write of one
+request's 256 rows, row by row (``kv_pool_set``, what a chunk did
+before PR 33: ~70 ns a row and head) and by blocks
+(``kv_pool_set_span``), at ``p0`` 0 and 37; and the attention
+(``paged_prefill_attention``: the span gathered and attended densely)
+at the three table buckets the cell's traffic meets (16, 32, 64 blocks)
+and starts ``p0`` 0 and 256.
+
 Prints, for XLA's gather path and for the Pallas kernel at each ``G``
 (pool blocks a grid step; ``--chunk-blocks`` sets it past the kernel's
 own budget and cap), the milliseconds per ``--layers`` calls and the largest error against the XLA path at ``highest``
@@ -54,6 +65,9 @@ def main(argv=None) -> int:
     ap.add_argument("--layers", type=int, default=48,
                     help="calls in the timed program")
     ap.add_argument("--blocks", type=int, default=321, help="pool blocks")
+    ap.add_argument("--prefill", action="store_true",
+                    help="a chunk's write and attention, not the decode "
+                         "step's kernel")
     a = ap.parse_args(argv)
     H, N, LAYERS = a.heads, a.blocks, a.layers
     HKV = a.kv_heads or H
@@ -69,14 +83,31 @@ def main(argv=None) -> int:
     pa = importlib.import_module(
         "deeplearning4j_tpu.kernels.paged_attention")
 
-    ks = jax.random.split(jax.random.PRNGKey(0), 3)
-    q = jax.random.normal(ks[0], (S, H, D), jnp.float32)
     cast = {"f32": lambda x: x, "bf16": lambda x: x.astype(jnp.bfloat16),
             "int8": quantize_rows}[a.kv]
+    itemsize = {"f32": 4, "bf16": 2, "int8": 1}[a.kv]
+    tag = a.kv if HKV == H == 25 else f"{a.kv}_h{H}kv{HKV}"
+
+    def ms(f, *args, n=10):
+        f(*args).block_until_ready()
+        t = time.perf_counter()
+        for _ in range(n):
+            out = f(*args)
+        out.block_until_ready()
+        return (time.perf_counter() - t) / n * 1e3
+
+    def write(res, name):
+        os.makedirs(os.path.join(ROOT, "chiprun_out"), exist_ok=True)
+        with open(os.path.join(ROOT, "chiprun_out", name), "w") as f:
+            json.dump(res, f, indent=1)
+
+    if a.prefill:
+        return prefill(a, pa, cast, ms, write, tag)
+    ks = jax.random.split(jax.random.PRNGKey(0), 3)
+    q = jax.random.normal(ks[0], (S, H, D), jnp.float32)
     pool = pa.fuse_kv(
         cast(jax.random.normal(ks[1], (N, HKV, BS, D), jnp.float32)),
         cast(jax.random.normal(ks[2], (N, HKV, BS, D), jnp.float32)))
-    itemsize = {"f32": 4, "bf16": 2, "int8": 1}[a.kv]
     rs = np.random.RandomState(0)
 
     def tables(lens):
@@ -96,14 +127,6 @@ def main(argv=None) -> int:
                 0, LAYERS,
                 lambda i, x: q + 1e-3 * fn(x, pool, tbl, lens), q)
         return jax.jit(run)
-
-    def ms(f, *args, n=10):
-        f(*args).block_until_ready()
-        t = time.perf_counter()
-        for _ in range(n):
-            out = f(*args)
-        out.block_until_ready()
-        return (time.perf_counter() - t) / n * 1e3
 
     variants = {"xla": pa.paged_attention_xla}
     for g in (int(x) for x in a.chunk_blocks.split(",")):
@@ -132,11 +155,74 @@ def main(argv=None) -> int:
     live = sum(RAGGED)
     print(f"ragged: {live} live keys, "
           f"{live * 2 * HKV * D * itemsize * LAYERS} pool bytes a step")
-    os.makedirs(os.path.join(ROOT, "chiprun_out"), exist_ok=True)
-    tag = a.kv if HKV == H == 25 else f"{a.kv}_h{H}kv{HKV}"
-    with open(os.path.join(ROOT, "chiprun_out",
-                           f"paged_kernel_bench_{tag}.json"), "w") as f:
-        json.dump(res, f, indent=1)
+    write(res, f"paged_kernel_bench_{tag}.json")
+    return 0
+
+
+C = 256     # the chunk's rows
+
+
+def prefill(a, pa, cast, ms, write, tag) -> int:
+    """``--prefill``: a chunk's write, by rows and by blocks, and its
+    attention by table bucket."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    N, H, LAYERS = a.blocks, a.heads, a.layers
+    HKV = a.kv_heads or H
+    q = jax.random.normal(jax.random.PRNGKey(7), (C, H, D), jnp.float32)
+    make = jax.jit(lambda k: pa.fuse_kv(*(cast(jax.random.normal(
+        kk, (N, HKV, BS, D), jnp.float32)) for kk in jax.random.split(k))))
+    pools = [make(k) for k in jax.random.split(jax.random.PRNGKey(8),
+                                               LAYERS)]
+    rs = np.random.RandomState(1)
+
+    def by_rows(pool, tbl, p0, k, v):
+        g = p0 + jnp.arange(C)
+        return pa.kv_pool_set(pool, (tbl[g // BS][:, None],
+                                     jnp.arange(HKV)[None, :],
+                                     (g % BS)[:, None]), k, v)
+
+    res = {}
+    kv = jax.random.normal(jax.random.PRNGKey(9), (C, HKV, D), jnp.float32)
+    tbl = jnp.asarray(rs.permutation(np.arange(1, N))[:32], jnp.int32)
+    for name, fn in (("write_rows", by_rows),
+                     ("write_span", pa.kv_pool_set_span)):
+        f = jax.jit(lambda ps, p0, fn=fn: [fn(p, tbl, p0, kv + i, kv)
+                                           for i, p in enumerate(ps)],
+                    donate_argnums=0)
+        for p0 in (0, 37):
+            pools = jax.block_until_ready(f(pools, jnp.int32(p0)))
+            t0 = time.perf_counter()
+            for _ in range(10):
+                pools = f(pools, jnp.int32(p0))
+            jax.block_until_ready(pools)
+            t = (time.perf_counter() - t0) / 10 * 1e3
+            res[f"{name}.p0_{p0}"] = {f"ms_per_{LAYERS}_calls": t}
+            print(f"{name:11s} p0 {p0:3d} {t:9.3f} ms / {LAYERS} calls",
+                  flush=True)
+
+    def attend(q, pools, tbl, p0):
+        """``--layers`` calls in one program, each fed by the one
+        before and reading a pool of its own."""
+        x = q
+        for pool in pools:
+            x = q + 1e-3 * pa.paged_prefill_attention(x, pool, tbl, p0)
+        return x
+    attend = jax.jit(attend)
+    for p0 in (0, C):
+        need = (p0 + C) // BS
+        for bucket in (16, 32, 64):
+            if bucket < need:
+                continue
+            tbl = np.zeros(bucket, np.int32)
+            tbl[:need] = rs.permutation(np.arange(1, N))[:need]
+            t = ms(attend, q, pools, jnp.asarray(tbl), jnp.int32(p0))
+            res[f"attend.p0_{p0}.b{bucket}"] = {
+                f"ms_per_{LAYERS}_calls": t}
+            print(f"attend      p0 {p0:3d} bucket {bucket:2d} {t:9.3f} ms "
+                  f"/ {LAYERS} calls", flush=True)
+    write(res, f"paged_prefill_bench_{tag}.json")
     return 0
 
 
