@@ -57,6 +57,10 @@ def silu(x):
     return x * jax.nn.sigmoid(x)
 
 
+#: what a gated pair puts on its first product: ``act(x W1) * (x W3)``
+GATES = {"silu": silu, "relu": lambda x: jnp.maximum(x, 0.0)}
+
+
 def _row_tile(M: int) -> int:
     """Rows a visit computes: the whole of a decode step's pairs, 128
     of a chunk's (a chunk's experts hold ~32 rows each, so a larger
@@ -79,10 +83,10 @@ def _col_tile(N: int) -> int:
 
 
 def _grouped_kernel(gid_ref, mt_ref, off_ref, nv_ref, x_ref, *refs,
-                    gated: bool, tm: int):
+                    gated: bool, tm: int, gate: str = "silu"):
     """One visit: row tile ``mt[v]`` of ``x`` against expert ``gid[v]``'s
     ``[K, tn]`` block(s). ``gated`` takes two weight blocks and stores
-    ``silu(x W1) * (x W3)``; else one, and stores ``x W``. Only the rows
+    ``gate(x W1) * (x W3)`` (:data:`GATES`); else one, and stores ``x W``. Only the rows
     of this visit's expert are stored (``off`` holds every expert's
     first row); what the output tile holds elsewhere is another visit's
     work, or nothing yet."""
@@ -94,8 +98,8 @@ def _grouped_kernel(gid_ref, mt_ref, off_ref, nv_ref, x_ref, *refs,
         x = x_ref[...]
         y = jnp.dot(x, refs[0][...], preferred_element_type=jnp.float32)
         if gated:
-            y = silu(y) * jnp.dot(x, refs[1][...],
-                                  preferred_element_type=jnp.float32)
+            y = GATES[gate](y) * jnp.dot(
+                x, refs[1][...], preferred_element_type=jnp.float32)
         e = gid_ref[v]
         row = mt_ref[v] * tm + lax.broadcasted_iota(jnp.int32, y.shape, 0)
         mine = (row >= off_ref[e]) & (row < off_ref[e + 1])
@@ -120,7 +124,8 @@ def visit_metadata(group_sizes, M: int, tm: int):
 
 
 def grouped_matmul_pallas(x, ws, meta, out_dtype,
-                          interpret: Optional[bool] = None):
+                          interpret: Optional[bool] = None,
+                          gate: str = "silu"):
     """``x`` [M, K] (rows sorted by expert) against ``ws``: one
     ``[E, K, N]`` stack (plain product) or two (the gated pair).
     ``meta`` is :func:`visit_metadata`'s. Rows of no expert come back
@@ -137,7 +142,8 @@ def grouped_matmul_pallas(x, ws, meta, out_dtype,
                           lambda j, v, g, t, o, n: (g[v], 0, j))
     o_spec = pl.BlockSpec((tm, tn), lambda j, v, g, t, o, n: (t[v], j))
     return pl.pallas_call(
-        functools.partial(_grouped_kernel, gated=len(ws) == 2, tm=tm),
+        functools.partial(_grouped_kernel, gated=len(ws) == 2, tm=tm,
+                          gate=gate),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=4, grid=(N // tn, V),
             in_specs=[x_spec] + [w_spec] * len(ws), out_specs=o_spec),
@@ -150,8 +156,10 @@ def grouped_matmul_pallas(x, ws, meta, out_dtype,
     )(gids, mts, offsets, n, x, *ws)
 
 
-def expert_ffn(x, w1, w3, w2, group_sizes, impl: str = "auto", **kw):
-    """The experts' SwiGLU over rows sorted by expert: x [M, D];
+def expert_ffn(x, w1, w3, w2, group_sizes, impl: str = "auto",
+               gate: str = "silu", **kw):
+    """The experts' gated unit (``gate`` ``silu``: SwiGLU; ``relu``:
+    ReGLU) over rows sorted by expert: x [M, D];
     w1, w3 [E, D, F]; w2 [E, F, D]; group_sizes [E] int32 (rows of each
     expert, in order; rows past their sum belong to none and come back
     undefined). Returns [M, D] float32.
@@ -163,11 +171,12 @@ def expert_ffn(x, w1, w3, w2, group_sizes, impl: str = "auto", **kw):
     M = x.shape[0]
     if impl == "pallas":
         meta = visit_metadata(group_sizes, M, _row_tile(M))
-        h = grouped_matmul_pallas(x, (w1, w3), meta, x.dtype, **kw)
+        h = grouped_matmul_pallas(x, (w1, w3), meta, x.dtype, gate=gate,
+                                  **kw)
         return grouped_matmul_pallas(h, (w2,), meta, jnp.float32, **kw)
     if impl == "ragged":
         dot = functools.partial(lax.ragged_dot, group_sizes=group_sizes,
                                 preferred_element_type=jnp.float32)
-        h = (silu(dot(x, w1)) * dot(x, w3)).astype(x.dtype)
+        h = (GATES[gate](dot(x, w1)) * dot(x, w3)).astype(x.dtype)
         return dot(h, w2)
     raise ValueError(f"unknown expert impl {impl!r}")

@@ -2,8 +2,9 @@
 over an explicit parameter dict (so a served forward and, later, a
 training step can both trace it: ROADMAP D5).
 
-Every token picks ``top_k`` of ``E`` experts by a sigmoid router and
-gets the weighted sum of their SwiGLU outputs. No capacity, no token
+Every token picks ``top_k`` of ``E`` experts by a router (sigmoid scores
+with a selection bias, or softmax over the chosen logits) and gets the
+weighted sum of their gated units' outputs (SwiGLU or ReGLU). No capacity, no token
 dropped, no shared expert. The shapes are static whatever the routing:
 the ``T * top_k`` token-expert pairs are sorted by expert and the three
 matmuls run as grouped products over the sorted rows
@@ -57,30 +58,59 @@ def route(x, w_gate, expert_bias, top_k: int, live=None,
     return experts.astype(jnp.int32), g
 
 
+def route_softmax(logits, top_k: int, live=None
+                  ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """The router of a model that hands its logits in: the ``top_k``
+    largest of ``logits`` [T, E] (float32) are chosen, and their
+    weights are the softmax over those ``top_k`` logits alone. Dead
+    tokens as :func:`route`."""
+    E = logits.shape[1]
+    chosen, experts = jax.lax.top_k(logits.astype(jnp.float32), top_k)
+    g = jax.nn.softmax(chosen, axis=-1)
+    if live is not None:
+        experts = jnp.where(live[:, None], experts, E)
+        g = jnp.where(live[:, None], g, 0.0)
+    return experts.astype(jnp.int32), g
+
+
 def moe_ffn(params: Dict, x, top_k: int, live=None,
-            norm_topk_prob: bool = True, scaling: float = 1.0):
+            norm_topk_prob: bool = True, scaling: float = 1.0, *,
+            router_logits=None, scoring: str = "sigmoid",
+            gate: str = "silu", scope: str = "lfm2"):
     """The expert layer over x [T, D] (float32, already normed).
 
     params: ``W_g`` [D, E], ``expert_bias`` [E], ``W1``/``W3``
     [E, D, F], ``W2`` [E, F, D]. The matmul operands take the experts'
     dtype (bfloat16 weights: bf16 operands, f32 accumulation).
 
+    ``scoring`` ``sigmoid`` is :func:`route` over ``x``; ``softmax`` is
+    :func:`route_softmax` over ``router_logits`` [T, E], which the
+    caller computed from whatever its router reads (``W_g`` and
+    ``expert_bias`` are then not looked at). ``gate`` is the experts'
+    activation (:data:`~...kernels.moe_experts.GATES`); ``scope``
+    prefixes the two named scopes.
+
     Returns (y [T, D] float32, counts) where ``counts`` is
     ``{"pairs": live pairs, "experts_touched": experts that received at
     least one, "expert_tokens": [E] pairs of each}``, int32, for the
     serving engine's account."""
     T, D = x.shape
-    E = params["W_g"].shape[1]
-    with jax.named_scope("lfm2.moe.route"):
-        experts, g = route(x, params["W_g"], params["expert_bias"], top_k,
-                           live, norm_topk_prob, scaling)
+    E = params["W1"].shape[0]
+    with jax.named_scope(scope + ".moe.route"):
+        if scoring == "sigmoid":
+            experts, g = route(x, params["W_g"], params["expert_bias"],
+                               top_k, live, norm_topk_prob, scaling)
+        elif scoring == "softmax":
+            experts, g = route_softmax(router_logits, top_k, live)
+        else:
+            raise ValueError(f"unknown scoring {scoring!r}")
         flat = experts.reshape(-1)                     # [T * k]
         order = jnp.argsort(flat, stable=True)         # dead pairs last
         sizes = jnp.zeros(E + 1, jnp.int32).at[flat].add(1)[:E]
-    with jax.named_scope("lfm2.moe.experts"):
+    with jax.named_scope(scope + ".moe.experts"):
         xs = x.astype(params["W1"].dtype)[order // top_k]   # [T * k, D]
         ys = expert_ffn(xs, params["W1"], params["W3"], params["W2"],
-                        sizes)
+                        sizes, gate=gate)
         # rows of no expert are undefined: mask, never multiply away
         ys = jnp.where((flat[order] < E)[:, None], ys, 0.0)
         pairs = jnp.zeros_like(ys).at[order].set(ys).reshape(T, top_k, D)

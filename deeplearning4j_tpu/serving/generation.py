@@ -58,16 +58,17 @@ from .batcher import (PRIORITIES, DeadlineExceededError, DrainingError,
                       QueueFullError)
 from .engine import ClientError, ServingError, compile_memoized
 from ..kernels.kv_quant import (canonical_kv_dtype, kv_bytes_per_token,
-                                kv_copy_row, kv_pack_host,
+                                kv_copy_row, kv_nbytes, kv_pack_host,
                                 kv_unpack_host, kv_update_slice)
 from ..kernels.paged_attention import kv_pool_zeros
 from .kvcache import KVCache, SlotTable
 from .metrics import GenerationMetrics
 from .offload import (DiskRing, HostBlockStore, HostRun,
                       OffloadPrefetcher)
-from .paging import (NULL_BLOCK, BlockAllocator, BlockTable, PagedKVCache,
-                     PrefixIndex, SessionStore, blocks_for, chain_hashes,
-                     export_block_run, import_block_run, pow2_bucket)
+from .paging import (NULL_BLOCK, BlockAllocator, BlockTable, CacheGroup,
+                     PagedKVCache, PrefixIndex, SessionStore, blocks_for,
+                     chain_hashes, export_block_run, import_block_run,
+                     pow2_bucket)
 from .speculative import (make_prime_fn, make_propose_fn,
                           make_verify_paged_fn, make_verify_slots_fn,
                           verify_bucket)
@@ -514,6 +515,35 @@ class GenerationEngine:
             # the state: neither the prefix index nor the session
             # store is consulted
             enable_prefix_sharing = False
+        # a model whose layers do not all keep the same positions
+        # DECLARES its cache groups (``cache_groups()``: which layers
+        # share a block table, which of those groups is a window) and
+        # this engine keeps a pool size, an allocator and a table a
+        # group, a window group's table as a ring (`serving/paging.py`,
+        # CacheGroup). What cannot carry a ring is refused, not served
+        # wrong (docs/generation.md, "Cache groups")
+        declared = getattr(model, "cache_groups", lambda: None)()
+        self._groups: List[CacheGroup] = []
+        if declared:
+            if cache != "paged":
+                raise ValueError(
+                    "a model with cache groups is served by the paged "
+                    "backend only (cache='paged'): a window group lives "
+                    "in a ring of pool blocks")
+            if self.speculation_k:
+                raise ValueError(
+                    "speculation_k > 0 is refused for a model with cache "
+                    "groups: a rejected draft rolls the KV cursor back "
+                    "over ring entries that an older lap no longer holds")
+            if int(offload_host_bytes) > 0:
+                raise ValueError(
+                    "offload_host_bytes > 0 is refused for a model with "
+                    "cache groups: a demoted run is one table's blocks, "
+                    "and a ring keeps only a window of them")
+            # a shared prefix's window blocks are overwritten by the
+            # ring's next lap: neither the prefix index nor the session
+            # store is consulted
+            enable_prefix_sharing = False
         if cache == "paged":
             self.block_size = int(block_size)
             if not 1 <= self.block_size <= self.max_seq_len:
@@ -523,6 +553,15 @@ class GenerationEngine:
             # has a table entry, so one decode executable serves all
             self._blocks_per_seq = blocks_for(self.max_seq_len,
                                               self.block_size)
+            # one count, or ``{group: count}`` for a model with cache
+            # groups (a count alone is its first group's)
+            by_group: Dict[str, int] = {}
+            if isinstance(num_blocks, dict):
+                if not declared:
+                    raise ValueError("num_blocks by group for a model "
+                                     "that declares no cache groups")
+                by_group = dict(num_blocks)
+                num_blocks = by_group.pop(declared[0]["name"], None)
             if num_blocks is None:
                 # dense-equivalent capacity (+1 for the null block);
                 # shrink it to realize the memory win, or keep it and
@@ -564,9 +603,35 @@ class GenerationEngine:
                 np.int32)
             self._slot_blocks: List[Optional[BlockTable]] = \
                 [None] * self.num_slots
+            for i, g in enumerate(declared or ()):
+                ring = None
+                if g.get("window") is not None:
+                    if i == 0:
+                        raise ValueError("a model's first cache group "
+                                         "keeps every position")
+                    # the window, one chunk written ahead of the oldest
+                    # key that chunk reads, and a block for a window
+                    # that starts inside one
+                    ring = blocks_for(int(g["window"]) + cap,
+                                      self.block_size) + 1
+                n = self.num_blocks if i == 0 else by_group.pop(
+                    g["name"], self.num_slots * (
+                        ring or self._blocks_per_seq) + 1)
+                self._groups.append(CacheGroup(
+                    g["name"], g["layers"], n, self.num_slots,
+                    ring or self._blocks_per_seq, g.get("window"), ring))
+            if by_group:
+                raise ValueError(f"num_blocks names no cache group of "
+                                 f"the model: {sorted(by_group)}")
+            self._alias_first_group()
+            # a model that declares slot state or cache groups takes
+            # ``live`` / ``slot`` and returns counters beside its logits
+            self._extended = self._stateful or bool(self._groups)
             self._prefilling: "collections.deque[_ChunkState]" = \
                 collections.deque()
             self._held: Optional[_GenRequest] = None
+            # the group whose pool could not cover the held request
+            self._held_group: Optional[str] = None
             # prefix sharing: chained-hash index over full prompt
             # blocks + session pins; both are scheduler-thread state
             self.enable_prefix_sharing = bool(enable_prefix_sharing)
@@ -620,6 +685,10 @@ class GenerationEngine:
         self.metrics.cache_backend = self.cache_backend
         self._cache = self._fresh_cache()
         self.metrics.cache_bytes = self._cache.nbytes()
+        for g in self._groups:     # bytes one block pins, its layers
+            g.block_bytes = sum(
+                2 * kv_nbytes((1,) + self._cache.layer_shapes[i],
+                              self.kv_dtype) for i in g.layers)
         self.metrics.kv_dtype = self.kv_dtype
         self.metrics.kv_bits = {"f32": 32, "bf16": 16, "int8": 8}[
             self.kv_dtype]
@@ -732,6 +801,7 @@ class GenerationEngine:
         # the scheduler's time account (metrics.SchedulerAccount):
         # phase counters in /stats and gen.* spans in a profiler trace
         self._sched = self.metrics.scheduler
+        self._sched.declare_groups(g.name for g in self._groups)
         self._queue: "queue.Queue[_GenRequest]" = queue.Queue(
             maxsize=int(max_queue))
         # submit-wake: an idle scheduler parks on this event instead
@@ -780,9 +850,12 @@ class GenerationEngine:
         configured bound, not the architectural one. Paged: the pool's
         per-block layer shapes come from the same model surface."""
         if self.cache_backend == "paged":
-            return PagedKVCache(self.model.cache_shapes(self.block_size),
-                                self.num_blocks,
-                                kv_dtype=self.kv_dtype)
+            shapes = self.model.cache_shapes(self.block_size)
+            blocks = [self.num_blocks] * len(shapes)
+            for g in self._groups:
+                for i in g.layers:
+                    blocks[i] = g.num_blocks
+            return PagedKVCache(shapes, blocks, kv_dtype=self.kv_dtype)
         return KVCache(self.model.cache_shapes(self.max_seq_len),
                        self.num_slots, kv_dtype=self.kv_dtype)
 
@@ -801,6 +874,46 @@ class GenerationEngine:
         starts from zeros whatever its slot held."""
         return [jnp.zeros(shape, dtype)
                 for shape, dtype in self._state_shapes]
+
+    def _alias_first_group(self):
+        """A model with cache groups: the engine's own allocator, decode
+        tables and per-slot tables ARE its first group's."""
+        if self._groups:
+            g = self._groups[0]
+            self._allocator, self._tables, self._slot_blocks = (
+                g.allocator, g.tables, g.slot_blocks)
+
+    def _reset_blocks(self):
+        """Nothing allocated, every table NULL (recovery: the pools
+        were donated away)."""
+        if self._groups:
+            for g in self._groups:
+                g.reset()
+            self._alias_first_group()
+            return
+        self._allocator = BlockAllocator(self.num_blocks)
+        self._tables[:] = NULL_BLOCK
+        self._slot_blocks = [None] * self.num_slots
+
+    def _step_tables(self):
+        """The decode tables as the step takes them: the array, or one
+        a cache group."""
+        if not self._groups:
+            return self._tables.copy()
+        return tuple(g.tables.copy() for g in self._groups)
+
+    def _null_tables(self, width: Optional[int] = None):
+        """Tables of NULL entries in the shapes the programs take (for
+        compiling them): ``width`` entries of one sequence (a chunk's
+        bucket), or the decode step's ``[num_slots, table width]``."""
+        def one(w):
+            return np.full(w if width is not None
+                           else (self.num_slots, w), NULL_BLOCK, np.int32)
+        first = one(width if width is not None else self._blocks_per_seq)
+        if not self._groups:
+            return first
+        return (first,) + tuple(one(g.table_width)
+                                for g in self._groups[1:])
 
     def _update_block_gauges(self):
         """Push allocator + liveness gauges into the metrics object
@@ -842,6 +955,20 @@ class GenerationEngine:
         if self.kv_dtype == "int8":
             # every allocated block holds quantize-on-write content
             self.metrics.quant_blocks_quantized = a.used_count
+        if self._groups:
+            # a group's live positions: what a later step can still
+            # read of each sequence (its window's at most)
+            lens = [int(st.pos[s]) + 1 for s in range(self.num_slots)
+                    if st.requests[s] is not None and st.step[s] > 0]
+            lens += [c.done_tokens for c in self._prefilling]
+            self.metrics.groups = {g.name: {
+                "blocks_total": g.allocator.capacity,
+                "blocks_free": g.allocator.free_count,
+                "blocks_peak_used": g.allocator.peak_used,
+                "block_bytes": g.block_bytes, "window": g.window,
+                "ring_blocks": g.ring,
+                "kv_tokens_live": g.rows_read(lens)}
+                for g in self._groups}
         self.metrics.shared_blocks = a.shared_count
         self.metrics.prefix_blocks = len(self._prefix_index)
         self.metrics.sessions_live = len(self._sessions)
@@ -887,7 +1014,8 @@ class GenerationEngine:
         impl = self.decode_impl
 
         if self.cache_backend == "paged":
-            stateful = self._stateful
+            stateful = self._extended
+            grouped = bool(self._groups)
 
             def step(params, pools, state, tok_host, tok_dev, use_host,
                      pos, tables, seeds, steps, temps, top_ks, eos,
@@ -899,7 +1027,8 @@ class GenerationEngine:
                     # to emit: a mid-prefill slot (its chunks own its
                     # state) and a lane the pipeline ran once past its
                     # end write no state and count nowhere
-                    live = (tables[:, 0] != NULL_BLOCK) \
+                    first = tables[0] if grouped else tables
+                    live = (first[:, 0] != NULL_BLOCK) \
                         & (steps < max_steps)
                     logits, pools, state, counters = \
                         model.forward_decode_paged(
@@ -930,7 +1059,7 @@ class GenerationEngine:
     def _chunk_fn(self):
         model = self.model
 
-        stateful = self._stateful
+        stateful = self._extended
 
         def chunk(params, pools, state, tokens, p0, chunk_len, table,
                   slot, seed, temp, top_k):
@@ -997,8 +1126,7 @@ class GenerationEngine:
                 args = (self.model._params, self._pools, self._state,
                         np.zeros(S, np.int32), np.zeros(S, np.int32),
                         np.ones(S, bool), np.zeros(S, np.int32),
-                        np.full((S, self._blocks_per_seq), NULL_BLOCK,
-                                np.int32),
+                        self._null_tables(),
                         np.zeros(S, np.uint32), np.zeros(S, np.int32),
                         np.zeros(S, np.float32), np.zeros(S, np.int32),
                         np.full(S, -1, np.int32), np.zeros(S, np.int32))
@@ -1030,8 +1158,7 @@ class GenerationEngine:
                 return exe
             args = (self.model._params, self._pools, self._state,
                     np.zeros((1, chunk_bucket), np.int32), np.int32(0),
-                    np.int32(1),
-                    np.full(tbl_bucket, NULL_BLOCK, np.int32),
+                    np.int32(1), self._null_tables(tbl_bucket),
                     np.int32(0), np.uint32(0), np.float32(0.0),
                     np.int32(0))
             with self._profiler.record("generation.compile"):
@@ -1514,6 +1641,11 @@ class GenerationEngine:
                 raise ClientError(
                     "session_id is refused for a model with slot state: "
                     "a pinned session holds keys and values only")
+            if self._groups:
+                raise ClientError(
+                    "session_id is refused for a model with cache "
+                    "groups: the ring's next lap overwrites a pinned "
+                    "turn's window blocks")
             if not self.enable_prefix_sharing:
                 raise ClientError(
                     "session_id requires prefix sharing "
@@ -1885,6 +2017,11 @@ class GenerationEngine:
                 self._allocator.free(table.blocks)
                 self._slot_blocks[slot] = None
             self._tables[slot] = NULL_BLOCK
+            for g in self._groups[1:]:
+                if g.slot_blocks[slot] is not None:
+                    g.allocator.free(g.slot_blocks[slot].blocks)
+                    g.slot_blocks[slot] = None
+                g.tables[slot] = NULL_BLOCK
             self._update_block_gauges()
         self.metrics.active_slots = self._slots.active_count
 
@@ -2005,7 +2142,9 @@ class GenerationEngine:
                 else:
                     self._admit_slots()
             finally:
-                self._sched.head_blocked(self._head_blocked_cause())
+                cause = self._head_blocked_cause()
+                self._sched.head_blocked(
+                    cause, self._held_group if cause == "blocks" else None)
 
     def _head_blocked_cause(self) -> Optional[str]:
         """Why the request at the head of the queue is still there
@@ -2251,6 +2390,21 @@ class GenerationEngine:
                 # reads it
                 self._allocator.share(pinned)
             fresh = self._alloc_with_eviction(need - len(shared))
+            # every other cache group's blocks too, or nothing at all
+            extra: List[List[int]] = []
+            short = self._groups[0].name if self._groups \
+                and fresh is None else None
+            if fresh is not None:
+                for g in self._groups[1:]:
+                    got = g.allocator.alloc(g.blocks_needed(need))
+                    if got is None:
+                        for h, blk in zip(self._groups[1:], extra):
+                            h.allocator.free(blk)
+                        self._allocator.free(fresh)
+                        fresh, short = None, g.name
+                        break
+                    extra.append(got)
+            self._held_group = short
             if fresh is None:
                 if pinned:
                     self._allocator.free(pinned)
@@ -2305,8 +2459,14 @@ class GenerationEngine:
             slot = self._slots.alloc(req)
             assert slot is not None  # guarded by free_count
             self._slot_blocks[slot] = table
+            for g, blk in zip(self._groups[1:], extra):
+                g.slot_blocks[slot] = BlockTable(blk, self.block_size)
             if req.trace is not None:
                 req.qspan.end()  # queue wait ends at the block claim
+                if self._groups:
+                    req.trace.span("admission", verdict="reserved", **{
+                        "blocks_" + g.name: len(g.slot_blocks[slot])
+                        for g in self._groups}).end()
             self._prefilling.append(
                 _ChunkState(req, slot, table, tbl_bucket, plan, seq,
                             start=match_len))
@@ -2348,6 +2508,10 @@ class GenerationEngine:
             tokens = np.zeros((1, bucket), np.int32)
             tokens[0, :clen] = st.seq[p0:p0 + clen]
             table = st.table.padded(st.tbl_bucket)
+            if self._groups:
+                table = (table,) + tuple(
+                    g.slot_blocks[st.slot].padded(g.table_width)
+                    for g in self._groups[1:])
             c0 = self.metrics.compiles
             try:
                 exe = self._get_chunk_exe(bucket, st.tbl_bucket)
@@ -2457,6 +2621,9 @@ class GenerationEngine:
         if req.pipe_d0 is None:
             req.pipe_d0, req.pipe_w0 = self._sched.busy_and_blocked()
         self._tables[st.slot] = st.table.padded(self._blocks_per_seq)
+        for g in self._groups[1:]:
+            g.tables[st.slot] = g.slot_blocks[st.slot].padded(
+                g.table_width)
         if self.enable_prefix_sharing and not resumed:
             # the prompt's full blocks now hold finished, immutable
             # K/V (decode writes land at pos >= prompt_len): publish
@@ -2550,9 +2717,7 @@ class GenerationEngine:
             # above; reset the block bookkeeping wholesale — including
             # the prefix/session pins, whose K/V went with the pools
             self._prefilling.clear()
-            self._allocator = BlockAllocator(self.num_blocks)
-            self._tables[:] = NULL_BLOCK
-            self._slot_blocks = [None] * self.num_slots
+            self._reset_blocks()
             self._prefix_index.clear()
             self._sessions.clear()
             # the HOST tier deliberately survives: demoted runs are
@@ -2596,9 +2761,7 @@ class GenerationEngine:
             # resets wholesale: the pool arrays were donated away with
             # the caches.
             self._prefilling.clear()
-            self._allocator = BlockAllocator(self.num_blocks)
-            self._tables[:] = NULL_BLOCK
-            self._slot_blocks = [None] * self.num_slots
+            self._reset_blocks()
             # cached prefixes and session pins died with the pools:
             # drop the bookkeeping (no frees — the allocator is new)
             # so post-recovery admissions rebuild refcounts from zero
@@ -3000,6 +3163,26 @@ class GenerationEngine:
             self._sched.step_dispatched(
                 int((-(-lengths // self.block_size)).sum()),
                 self.num_slots * self._blocks_per_seq)
+            if self._groups:
+                # keys read a group (its window's at most, times its
+                # layers), and what one lifetime for every layer reads;
+                # a lane the pipeline runs once past its end reads none
+                st = self._slots
+                lengths = lengths[st.step[active] < st.max_steps[active]]
+                self._sched.step_rows(
+                    {g.name: g.rows_read(lengths) * len(g.layers)
+                     for g in self._groups},
+                    int(lengths.sum()) * sum(
+                        len(g.layers) for g in self._groups))
+
+    def _account_step_collected(self):
+        """A paged step's results are applied: refresh the gauges and
+        add the live KV positions it ran over (a cache group's too)."""
+        self._update_block_gauges()
+        self._sched.step_collected(
+            self.metrics.kv_tokens_live,
+            {n: g["kv_tokens_live"]
+             for n, g in self.metrics.groups.items()})
 
     def _account_step_counters(self, counters):
         """The small integer vector a decode step returns beside its
@@ -3036,7 +3219,7 @@ class GenerationEngine:
                         self.model._params, self._pools, self._state,
                         st.token.copy(), self._no_dev_tok,
                         self._all_host, st.pos.copy(),
-                        self._tables.copy(), st.seed.copy(),
+                        self._step_tables(), st.seed.copy(),
                         st.step.copy(), st.temp.copy(),
                         st.top_k.copy(), st.eos.copy(),
                         st.max_steps.copy())
@@ -3108,8 +3291,7 @@ class GenerationEngine:
         if itl:
             self.metrics.itl_ms.record_many(itl)
         if self.cache_backend == "paged":
-            self._update_block_gauges()
-            self._sched.step_collected(self.metrics.kv_tokens_live)
+            self._account_step_collected()
 
     def _dispatch_decode(self) -> bool:
         """Launch one decode step WITHOUT waiting for its results (the
@@ -3143,7 +3325,7 @@ class GenerationEngine:
                  counters) = self._get_decode_exe()(
                         self.model._params, self._pools, self._state,
                         st.token.copy(), tok_dev, use_host,
-                        st.pos.copy(), self._tables.copy(),
+                        st.pos.copy(), self._step_tables(),
                         st.seed.copy(), st.step.copy(), st.temp.copy(),
                         st.top_k.copy(), st.eos.copy(),
                         st.max_steps.copy())
@@ -3241,8 +3423,7 @@ class GenerationEngine:
         if itl:
             self.metrics.itl_ms.record_many(itl)
         if self.cache_backend == "paged":
-            self._update_block_gauges()
-            self._sched.step_collected(self.metrics.kv_tokens_live)
+            self._account_step_collected()
 
     def _drop_pending(self):
         """Discard in-flight pipelined state (recovery/poison/stop:

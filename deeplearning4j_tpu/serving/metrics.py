@@ -169,6 +169,15 @@ class SchedulerAccount:
         self.kv_live_token_steps = 0
         self.kv_blocks_attended = 0
         self.kv_blocks_spanned = 0
+        # a model with cache groups (serving/paging.py, CacheGroup):
+        # keys the decode steps read by group, what one lifetime for
+        # every layer would have read, live positions by group summed
+        # over steps, and the head's wait for blocks by the group that
+        # could not cover it. Empty, and not in the snapshot, otherwise
+        self.kv_rows_attended: Dict[str, int] = {}
+        self.kv_rows_full = 0
+        self.kv_group_live_token_steps: Dict[str, int] = {}
+        self.admit_blocked_on: Dict[str, float] = {}
         #: how many of the longest non-idle iterations are kept
         self.keep_slowest = 8
         self._slowest: List[tuple] = []      # min-heap on seconds
@@ -181,7 +190,10 @@ class SchedulerAccount:
         self._it_kv = 0
         self._it_attended = self._it_spanned = 0
         self._it_blocked: Dict[str, float] = {}
-        self._blocked = None                 # (cause, since) or None
+        self._blocked = None        # (cause, since, group) or None
+        self._it_groups: Dict[str, Dict] = {
+            "rows": {}, "live": {}, "blocked": {}}
+        self._it_rows_full = 0
 
     # -- scheduler thread ----------------------------------------------
     def start(self) -> None:
@@ -216,23 +228,51 @@ class SchedulerAccount:
                                         **parent[1])
             parent[2].__enter__()
 
-    def head_blocked(self, cause) -> None:
+    def head_blocked(self, cause, group=None) -> None:
         """Called once an admission pass: ``cause`` is why the request
         at the head of the queue could not be admitted ("blocks": the
         pool cannot cover it though a slot is free; "slots": no slot
-        is free), or None when nothing waits. The time until the next
-        call is charged to that cause."""
+        is free), or None when nothing waits; ``group`` names the cache
+        group whose pool it was. The time until the next call is
+        charged to that cause (and group)."""
         now = self._clock()
         if self._blocked is not None:
-            was, since = self._blocked
+            was, since, grp = self._blocked
             self._it_blocked[was] = self._it_blocked.get(was, 0.0) \
                 + (now - since)
-        self._blocked = None if cause is None else (cause, now)
+            if grp is not None:
+                self._add("blocked", {grp: now - since})
+        self._blocked = None if cause is None else (cause, now, group)
 
-    def step_collected(self, kv_tokens_live: int) -> None:
+    def declare_groups(self, names) -> None:
+        """The model has cache groups: their counters show from the
+        start, at zero."""
+        names = list(names)
+        for kept in (self.kv_rows_attended, self.kv_group_live_token_steps,
+                     self.admit_blocked_on):
+            for n in names:
+                kept.setdefault(n, 0)
+
+    def _add(self, what: str, by_group: Dict) -> None:
+        it = self._it_groups[what]
+        for k, v in by_group.items():
+            it[k] = it.get(k, 0) + v
+
+    def step_collected(self, kv_tokens_live: int, by_group=None) -> None:
         """One decode step's results are on the host: add the live KV
-        tokens it ran over (memory in use, integrated over steps)."""
+        tokens it ran over (memory in use, integrated over steps), and
+        those of each cache group."""
         self._it_kv += int(kv_tokens_live)
+        if by_group:
+            self._add("live", by_group)
+
+    def step_rows(self, attended: Dict[str, int], full: int) -> None:
+        """One decode step of a model with cache groups is dispatched:
+        the keys its lanes read a group (a window group's lanes their
+        window at most, times the group's layers), and what every
+        layer keeping every position would read."""
+        self._add("rows", attended)
+        self._it_rows_full += int(full)
 
     def step_dispatched(self, blocks_attended: int,
                         blocks_spanned: int) -> None:
@@ -261,6 +301,13 @@ class SchedulerAccount:
             self.kv_live_token_steps += self._it_kv
             self.kv_blocks_attended += self._it_attended
             self.kv_blocks_spanned += self._it_spanned
+            self.kv_rows_full += self._it_rows_full
+            for what, kept in (
+                    ("rows", self.kv_rows_attended),
+                    ("live", self.kv_group_live_token_steps),
+                    ("blocked", self.admit_blocked_on)):
+                for k, v in self._it_groups[what].items():
+                    kept[k] = kept.get(k, 0) + v
             if not it_s.get("idle") and self.keep_slowest > 0:
                 entry = (total, t0, int(step), it_s)
                 if len(self._slowest) < self.keep_slowest:
@@ -271,6 +318,8 @@ class SchedulerAccount:
         self._it_s, self._it_n, self._it_blocked = {}, {}, {}
         self._it_kv = 0
         self._it_attended = self._it_spanned = 0
+        self._it_groups = {"rows": {}, "live": {}, "blocked": {}}
+        self._it_rows_full = 0
 
     # -- readers ---------------------------------------------------------
     def busy_and_blocked(self):
@@ -284,7 +333,14 @@ class SchedulerAccount:
     def snapshot(self) -> Dict:
         with self._lock:
             slowest = sorted(self._slowest, key=lambda e: -e[0])
+            groups = {} if not self.kv_rows_attended else {
+                "kv_rows_attended": dict(self.kv_rows_attended),
+                "kv_rows_full": self.kv_rows_full,
+                "kv_group_live_token_steps":
+                    dict(self.kv_group_live_token_steps),
+                "admit_blocked_on": dict(self.admit_blocked_on)}
             return {
+                **groups,
                 "loop_s": self.loop_s,
                 "iterations": self.iterations,
                 "phase_s": dict(self.phase_s),
@@ -392,6 +448,10 @@ class GenerationMetrics:
         self.chunked_prefills = 0      # prompts that spanned >1 chunk
         self.kv_tokens_live = 0        # written positions, live seqs
         self.kv_tokens_allocated = 0   # blocks_used * block_size
+        # a model with cache groups: allocator and liveness gauges a
+        # group, by name (empty, and not in the snapshot, otherwise;
+        # the fields above are then its first group's)
+        self.groups: Dict[str, Dict] = {}
         # prefix sharing + persistent sessions (paged backend only;
         # docs/generation.md "Prefix sharing")
         self.prefix_sharing = False    # config flag
@@ -484,6 +544,7 @@ class GenerationMetrics:
                 "kv_tokens_allocated": alloc,
                 "prefill_chunks": self.prefill_chunks,
                 "chunked_prefills": self.chunked_prefills,
+                **({"groups": dict(self.groups)} if self.groups else {}),
                 "prefix_cache": {
                     "enabled": self.prefix_sharing,
                     "prefix_hits": self.prefix_hits,

@@ -219,6 +219,58 @@ class BlockTable:
         return out
 
 
+class CacheGroup:
+    """The layers of a model that keep the same positions, and so share
+    one block table a sequence and one allocator: what a model declares
+    with ``cache_groups()`` (``name``, ``layers``: indices into its
+    ``cache_shapes()``, ``window``: how many of the newest positions
+    the layers read, None for all of them), sized by the engine.
+
+    A window group's table is a RING of ``ring`` entries a slot:
+    position ``p`` lives in entry ``(p // block_size) % ring``, so a
+    sequence holds ``min(blocks_for(prompt + max_tokens), ring)`` blocks
+    from admission to retirement however long it grows, and nothing is
+    freed in between. The ring covers the window, one prefill chunk
+    written ahead of the oldest key that chunk still reads, and one
+    block for a window that starts inside a block. A model without
+    ``cache_groups`` is one group with no window: the engine's
+    allocator and tables as they always were."""
+
+    def __init__(self, name: str, layers: Sequence[int], num_blocks: int,
+                 num_slots: int, table_width: int,
+                 window: Optional[int] = None,
+                 ring: Optional[int] = None):
+        self.name = str(name)
+        self.layers = [int(i) for i in layers]
+        self.window = None if window is None else int(window)
+        self.ring = None if ring is None else int(ring)
+        self.num_blocks = int(num_blocks)
+        self.num_slots = int(num_slots)
+        self.table_width = int(table_width)
+        self.reset()
+
+    def reset(self):
+        """Nothing allocated, every table row NULL (construction, and
+        recovery: the pools were donated away)."""
+        self.allocator = BlockAllocator(self.num_blocks)
+        self.tables = np.full((self.num_slots, self.table_width),
+                              NULL_BLOCK, np.int32)
+        self.slot_blocks: List[Optional[BlockTable]] = \
+            [None] * self.num_slots
+
+    def blocks_needed(self, seq_blocks: int) -> int:
+        """Blocks a sequence of ``seq_blocks`` blocks holds here."""
+        return int(seq_blocks) if self.ring is None \
+            else min(int(seq_blocks), self.ring)
+
+    def rows_read(self, lengths) -> int:
+        """Keys a decode step reads a layer of this group, summed over
+        lanes at ``lengths``: all of them, or the window's."""
+        n = np.asarray(lengths)
+        return int((n if self.window is None
+                    else np.minimum(n, self.window)).sum())
+
+
 class PagedKVCache:
     """Per-layer pooled K/V blocks, the paged sibling of
     :class:`~.kvcache.KVCache`: same pytree-threaded-through-donated-
@@ -251,23 +303,28 @@ class PagedKVCache:
     stale (quantized) tail stays masked by the next owner's length."""
 
     def __init__(self, layer_shapes: Sequence[Tuple[int, int, int]],
-                 num_blocks: int, kv_dtype: str = "f32"):
-        self.num_blocks = int(num_blocks)
+                 num_blocks, kv_dtype: str = "f32"):
         self.layer_shapes = [tuple(s) for s in layer_shapes]
+        # one count, or one a layer where the layers lie in cache
+        # groups with pools of their own (:class:`CacheGroup`)
+        self.layer_blocks = [int(n) for n in num_blocks] \
+            if isinstance(num_blocks, (list, tuple)) \
+            else [int(num_blocks)] * len(self.layer_shapes)
+        self.num_blocks = self.layer_blocks[0]
         self.block_size = int(self.layer_shapes[0][1])
         self.kv_dtype = canonical_kv_dtype(kv_dtype)
         self.pools: List = [
-            kv_pool_zeros((self.num_blocks,) + s, self.kv_dtype)
-            for s in self.layer_shapes]
+            kv_pool_zeros((n,) + s, self.kv_dtype)
+            for n, s in zip(self.layer_blocks, self.layer_shapes)]
 
     def nbytes(self) -> int:
         """Device bytes the pool pins: ``num_blocks * block_size * H *
         Dh * 2 (K+V) * layers * itemsize``, plus the f32 scale
         sidecars for int8 — the number to budget against HBM
         (docs/generation.md has the sizing guidance)."""
-        return int(sum(2 * kv_nbytes((self.num_blocks,) + s,
-                                     self.kv_dtype)
-                       for s in self.layer_shapes))
+        return int(sum(2 * kv_nbytes((n,) + s, self.kv_dtype)
+                       for n, s in zip(self.layer_blocks,
+                                       self.layer_shapes)))
 
     def block_nbytes(self) -> int:
         """Bytes one block pins across all layers (K+V, sidecar
@@ -278,8 +335,9 @@ class PagedKVCache:
         """Bytes of the f32 scale sidecars alone (0 unless int8)."""
         if self.kv_dtype != "int8":
             return 0
-        return int(sum(2 * int(np.prod((self.num_blocks,) + s[:-1]))
-                       * 4 for s in self.layer_shapes))
+        return int(sum(2 * int(np.prod((n,) + s[:-1])) * 4
+                       for n, s in zip(self.layer_blocks,
+                                       self.layer_shapes)))
 
     def bytes_per_token(self) -> int:
         """K+V bytes one token position costs across all layers at the

@@ -291,3 +291,90 @@ def test_chip_smoke_refuses_to_run_without_a_tpu():
     assert r.returncode == 2
     assert "found no TPU" in r.stderr
     assert r.stdout == ""
+
+
+# -- the long-context cell's own programs (PR 35) ---------------------------------
+def _smallthinker_program(monkeypatch, which):
+    """(function, abstract arguments, pool shapes by group) of the
+    engine's ``jit_step`` or ``jit_chunk`` for the configuration
+    ``benchmark/configs/smallthinker-21b-a3b.json`` as the chip runs it:
+    the engine's own program bodies over the served class, shapes in
+    place of its weights and pools (an engine of that size is not built
+    here)."""
+    import importlib
+    import json
+    from deeplearning4j_tpu.serving.generation import GenerationEngine
+    from deeplearning4j_tpu.serving.paging import CacheGroup, blocks_for
+    from deeplearning4j_tpu.zoo.smallthinker import SmallThinkerLM
+    for mod in ("paged_attention", "moe_experts"):
+        # the kernels ask which platform they will run on: the chip's
+        monkeypatch.setattr(importlib.import_module(
+            "deeplearning4j_tpu.kernels." + mod), "default_platform",
+            lambda: "tpu")
+    with open(os.path.join(ROOT, "benchmark", "configs",
+                           "smallthinker-21b-a3b.json")) as f:
+        cfg = json.load(f)
+    lm = SmallThinkerLM(**cfg["model"])
+    params = jax.eval_shape(lambda: lm.init()._params)
+    lm._params = None
+    e = cfg["engine"]
+    S, Bs, C = e["num_slots"], e["block_size"], e["prefill_chunk_tokens"]
+    ring = blocks_for(lm.window + C, Bs) + 1
+    blocks = {"global": e["num_blocks"], "window": S * ring + 1}
+    eng = object.__new__(GenerationEngine)
+    eng.model, eng.decode_impl, eng.cache_backend = lm, "auto", "paged"
+    eng._groups = [CacheGroup(g["name"], g["layers"], 2, 1, 1, g["window"])
+                   for g in lm.cache_groups()]
+    eng._extended = True        # the model takes live / slot
+    pools = [None] * lm.n_layers
+    for g in lm.cache_groups():
+        for i in g["layers"]:
+            pools[i] = _pool(blocks[g["name"]], lm.n_kv_heads, Bs,
+                             lm.head_dim, "bf16")
+    sds = jax.ShapeDtypeStruct
+    i32 = lambda *s: sds(s, jnp.int32)                    # noqa: E731
+    if which == "step":
+        return eng._decode_fn(), (
+            params, pools, [], i32(S), i32(S), sds((S,), jnp.bool_), i32(S),
+            (i32(S, e["max_seq_len"] // Bs), i32(S, ring)),
+            sds((S,), jnp.uint32), i32(S), sds((S,), jnp.float32), i32(S),
+            i32(S), i32(S)), blocks
+    return eng._chunk_fn(), (
+        params, pools, [], i32(1, C), i32(), i32(),
+        (i32(e["max_seq_len"] // Bs), i32(ring)), i32(), sds((), jnp.uint32),
+        sds((), jnp.float32), i32()), blocks
+
+
+@pytest.mark.parametrize("which", ["step", "chunk"])
+def test_the_long_context_cells_programs_fit_and_relay_no_pool(
+        v5e, monkeypatch, which):
+    """``jit_step`` and ``jit_chunk`` (table bucket 256, the traffic's
+    largest) of ``smallthinker-21b-a3b`` compile for a v5e with both
+    groups' pools donated: no ``copy`` with either pool's shape, all 12
+    pools aliased, the decode kernels (plain and windowed) or the tiled
+    chunk kernels (plain and windowed) beside the expert kernels, and
+    arguments plus temporaries under the 15.75 GB a v5e's compiler
+    allows (PERF.md section 4)."""
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    fn, args, blocks = _smallthinker_program(monkeypatch, which)
+    one = SingleDeviceSharding(topologies.get_topology_desc(
+        platform="tpu", topology_name="v5e:2x2").devices[0])
+    args = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one), args)
+    compiled = jax.jit(fn, donate_argnums=(1, 2)).lower(*args).compile()
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75e9
+    assert mem.alias_size_in_bytes > 2.7e9          # both groups' pools
+    shape = r"bf16\[(%d|%d),4,64,256\]" % (blocks["global"],
+                                           blocks["window"])
+    assert [ln for ln in text.splitlines()
+            if re.search(r"= " + shape + r"\S* copy\(", ln)] == []
+    (aliases,) = re.findall(r"input_output_alias=\{([^\n]*)", text)
+    assert len(re.findall(r"\{\d+\}: \(\d+, \{\}", aliases)) >= 12
+    names = set(_custom_calls(text))
+    attn = {"step": {"paged_attention_decode",
+                     "paged_attention_decode_window"},
+            "chunk": {"paged_prefill_attention",
+                      "paged_prefill_attention_window"}}[which]
+    assert names == attn | {"moe_experts_up", "moe_experts_down"}

@@ -648,3 +648,41 @@ def test_run_py_judges_the_tiny_configuration(tiny_root, control):
         assert out["control"] == "float8_e4m3fn"
     else:
         assert out["correct"] is True and got["value"] < got["limit"]
+
+
+# -- the expert layer's new arguments leave this model's programs alone (PR 35) -------
+#: SHA-256 (first 16 hex digits) of the StableHLO that the tiny model's
+#: decode step and prefill chunk lower to, locations stripped, taken on
+#: the commit before ``moe_ffn`` gained ``router_logits`` / ``scoring``
+#: / ``gate`` and ``span_attend`` its ``kpos`` / ``window``, with this
+#: container's JAX (0.9.0). A JAX upgrade changes them: take them anew
+#: from the parent commit then, never from the tree under test.
+LOWERED_BEFORE_PR35 = {"step": "181459453d855f41", "chunk": "5ab16d6137fa402a"}
+
+
+@pytest.mark.parametrize("program", ["step", "chunk"])
+def test_the_programs_lower_to_the_operations_they_did_before_pr35(program):
+    import hashlib
+    import re
+    from deeplearning4j_tpu.zoo.lfm2_moe import Lfm2MoeLM
+    lm = Lfm2MoeLM(**{k: v for k, v in TINY.items() if k != "embed_std"}
+                   ).init()
+    pools = PagedKVCache(lm.cache_shapes(8), 20).pools
+    state = [jnp.zeros(s, d) for s, d in lm.slot_state_shapes(3)]
+    if program == "step":
+        text = jax.jit(
+            lambda p, pl, st, t, pos, tb, lv: lm.forward_decode_paged(
+                p, t, pos, pl, tb, "xla", state=st, live=lv)).lower(
+            lm._params, pools, state, jnp.zeros(3, jnp.int32),
+            jnp.zeros(3, jnp.int32), jnp.zeros((3, 8), jnp.int32),
+            jnp.ones(3, bool)).as_text()
+    else:
+        text = jax.jit(
+            lambda p, pl, st, t, p0, cl, tb, sl: lm.forward_prefill_chunk(
+                p, t, p0, cl, pl, tb, state=st, slot=sl)).lower(
+            lm._params, pools, state, jnp.zeros((1, 16), jnp.int32),
+            jnp.int32(0), jnp.int32(16), jnp.zeros(8, jnp.int32),
+            jnp.int32(1)).as_text()
+    text = re.sub(r"loc\(.*?\)|#loc\d*( = .*)?", "", text)
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] == \
+        LOWERED_BEFORE_PR35[program]

@@ -799,3 +799,181 @@ class TestPagedStreamDisconnect:
             time.sleep(0.01)
         assert eng._allocator.free_count == cap
         assert eng._slots.active_count == 0
+
+
+# -- cache groups: a ring for the window layers beside the plain table (PR 35) ---------
+def _grouped_lm(seed=0):
+    from deeplearning4j_tpu.zoo.smallthinker import SmallThinkerLM
+    return SmallThinkerLM(
+        vocab_size=64, hidden_size=32, head_dim=8, num_hidden_layers=4,
+        num_attention_heads=4, num_key_value_heads=2, moe_ffn_hidden_size=16,
+        moe_num_primary_experts=4, moe_num_active_primary_experts=2,
+        sliding_window_layout=[0, 1, 1, 1], rope_layout=[0, 1, 1, 1],
+        sliding_window_size=8, max_position_embeddings=64, dtype="float32",
+        seed=seed).init()
+
+
+def _grouped_engine(**kw):
+    opts = dict(num_slots=3, max_seq_len=64, prompt_buckets=[8],
+                cache="paged", block_size=4, prefill_chunk_tokens=8)
+    opts.update(kw)
+    return GenerationEngine(_grouped_lm(), **opts)
+
+
+def _wait(pred, timeout=30.0):
+    t = time.time() + timeout
+    while time.time() < t:
+        if pred():
+            return True
+        time.sleep(0.01)
+    return False
+
+
+class TestCacheGroups:
+    RING = 5        # blocks_for(window 8 + chunk 8, 4) + 1
+
+    def test_a_group_a_pool_an_allocator_and_a_table(self):
+        eng = _grouped_engine(num_blocks=40)
+        try:
+            g, w = eng._groups
+            assert (g.name, g.window, g.ring, g.num_blocks) == (
+                "global", None, None, 40)
+            assert (w.name, w.window, w.ring) == ("window", 8, self.RING)
+            assert w.num_blocks == 3 * self.RING + 1     # dense-equivalent
+            assert g.allocator is eng._allocator and g.tables is eng._tables
+            assert [p.shape[0] for p in eng._pools] == [40, 16, 16, 16]
+            assert w.tables.shape == (3, self.RING)
+            st = eng.stats()["paged"]
+            assert st["blocks_total"] == 39        # the first group's
+            assert st["groups"]["window"]["blocks_total"] == 15
+        finally:
+            eng.stop()
+
+    def test_a_request_never_holds_more_than_the_ring_and_returns_both(self):
+        eng = _grouped_engine()
+        try:
+            eng.warmup()
+            seen = []
+            orig = eng._chunk_landed
+
+            def spy(st, *a, **k):
+                seen.append((len(eng._slot_blocks[st.slot]),
+                             len(eng._groups[1].slot_blocks[st.slot])))
+                return orig(st, *a, **k)
+            eng._chunk_landed = spy
+            out = eng.generate(list(range(1, 41)), max_tokens=9,
+                               temperature=0.0)
+            assert len(out["tokens"]) == 9
+            # 49 positions: 13 blocks of 4 in the global group, the
+            # ring's 5 in the window group, through all five chunks
+            assert seen == [(13, self.RING)] * 5
+            out = eng.generate([1, 2, 3], max_tokens=4, temperature=0.0)
+            assert seen[-1] == (2, 2)           # shorter than the ring
+            g = eng.stats()["paged"]["groups"]
+            for name in ("global", "window"):
+                assert g[name]["blocks_free"] == g[name]["blocks_total"]
+            assert g["window"]["blocks_peak_used"] == self.RING
+        finally:
+            eng.stop()
+
+    @pytest.mark.parametrize("short", ["global", "window"])
+    def test_admission_reserves_both_groups_or_neither(self, short):
+        """Two long requests: the second cannot be covered by the group
+        ``short``; it holds nothing of either group while it waits,
+        names that group, and is admitted when the first retires."""
+        blocks = {"global": {"global": 14, "window": 16},
+                  "window": {"global": 40, "window": 8}}[short]
+        eng = _grouped_engine(num_blocks=blocks)
+        try:
+            eng.warmup()
+            prompt = list(range(1, 41))
+            outs = [None, None]
+
+            def go(i):
+                outs[i] = eng.generate(prompt, max_tokens=12,
+                                       temperature=0.0)
+            first = threading.Thread(target=go, args=(0,))
+            first.start()
+            assert _wait(lambda: eng._slots.active_count == 1)
+            second = threading.Thread(target=go, args=(1,))
+            second.start()
+            assert _wait(lambda: eng._held is not None
+                         or outs[0] is not None)
+            if outs[0] is None:
+                assert eng._held_group == short
+                used = [g.allocator.used_count for g in eng._groups]
+                assert used == [13, self.RING]    # the first's alone
+            first.join(60)
+            second.join(60)
+            assert outs[0]["tokens"] == outs[1]["tokens"]
+            sch = eng.stats()["scheduler"]
+            assert sch["admit_blocked_on"][short] > 0
+            other = "window" if short == "global" else "global"
+            assert sch["admit_blocked_on"][other] == 0
+            assert sch["head_blocked_s"]["blocks"] > 0
+            for g in eng._groups:
+                assert g.allocator.used_count == 0
+        finally:
+            eng.stop()
+
+    def test_recovery_re_prefills_both_groups(self):
+        from deeplearning4j_tpu.faults import FaultInjector
+        want_eng = _grouped_engine()
+        try:
+            want = want_eng.generate(list(range(1, 31)), max_tokens=10,
+                                     temperature=0.0)["tokens"]
+        finally:
+            want_eng.stop()
+        inj = FaultInjector(plan={"device_step": [4]},
+                            corrupting=("device_step",))
+        eng = _grouped_engine(fault_injector=inj)
+        try:
+            got = eng.generate(list(range(1, 31)), max_tokens=10,
+                               temperature=0.0)["tokens"]
+            assert got == want
+            st = eng.stats()
+            assert st["faults"]["recoveries"] >= 1
+            for g in eng._groups:
+                assert g.allocator.used_count == 0
+                assert (g.tables == NULL_BLOCK).all()
+        finally:
+            eng.stop()
+
+    @pytest.mark.parametrize("kw,why", [
+        (dict(cache="slots"), "paged backend only"),
+        (dict(speculation_k=2), "speculation_k"),
+        (dict(offload_host_bytes=1 << 20), "offload_host_bytes"),
+        (dict(num_blocks={"window": 9, "nobody": 4}), "names no cache group"),
+    ])
+    def test_what_cannot_carry_a_ring_is_refused_at_construction(
+            self, kw, why):
+        with pytest.raises(ValueError, match=why):
+            _grouped_engine(**kw)
+
+    def test_prefix_sharing_is_off_and_a_session_is_a_client_error(self):
+        from deeplearning4j_tpu.serving.engine import ClientError
+        eng = _grouped_engine(enable_prefix_sharing=True)
+        try:
+            assert not eng.enable_prefix_sharing
+            with pytest.raises(ClientError, match="cache groups"):
+                eng.generate([1, 2, 3], max_tokens=2, session_id="s")
+            a = eng.generate(list(range(1, 20)), max_tokens=3,
+                             temperature=0.0)
+            b = eng.generate(list(range(1, 20)), max_tokens=3,
+                             temperature=0.0)
+            assert a["tokens"] == b["tokens"]
+            assert eng.stats()["paged"]["prefix_cache"]["prefix_hits"] == 0
+        finally:
+            eng.stop()
+
+    def test_a_one_group_models_stats_keys_are_unchanged(self, paged_engine):
+        st = paged_engine.stats()
+        assert "groups" not in st["paged"]
+        assert set(st["scheduler"]) == {
+            "loop_s", "iterations", "phase_s", "phase_n", "head_blocked_s",
+            "kv_live_token_steps", "kv_blocks_attended",
+            "kv_blocks_spanned", "slowest"}
+        assert paged_engine._groups == []
+        with pytest.raises(ValueError, match="declares no cache groups"):
+            GenerationEngine(_lm(), cache="paged", block_size=8,
+                             num_blocks={"global": 9})

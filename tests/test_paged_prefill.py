@@ -262,3 +262,180 @@ def test_a_prefix_shared_second_request_starts_past_the_shared_blocks(
         eng.stop()
     assert first == tokens["two_whole_chunks"]
     assert got == tokens["shared"]
+
+
+# -- a window, a ring and the tiled kernels (PR 35) -----------------------------
+def _ring_case(Hq, Hkv, D, Bs, R, total, dt, seed=0, N=40):
+    """A sequence of ``total`` positions written through a ring of ``R``
+    entries (each position where ``(p // Bs) % R`` puts it, later laps
+    over earlier ones), every other row of the pool NaN: the null block,
+    blocks of nobody, and what the ring's entries hold past the newest
+    position. Returns (pool, table, k [total, Hkv, D], v)."""
+    rs = np.random.RandomState(seed)
+    k = rs.randn(total, Hkv, D).astype(np.float32)
+    v = rs.randn(total, Hkv, D).astype(np.float32)
+    tbl = rs.permutation(np.arange(1, N))[:R].astype(np.int32)
+    pool = np.full((N, Hkv, Bs, 2 * D), np.nan, np.float32)
+    for p in range(total):
+        pool[tbl[(p // Bs) % R], :, p % Bs] = np.concatenate(
+            [k[p], v[p]], -1)
+    pool = jnp.asarray(pool, _DT[dt])
+    back = np.asarray(pool.astype(jnp.float32))
+    for p in range(max(0, total - R * Bs), total):   # as stored
+        row = back[tbl[(p // Bs) % R], :, p % Bs]
+        k[p], v[p] = row[:, :D], row[:, D:]
+    return pool, tbl, k, v
+
+
+def _plain_rows(q, k, v, positions, window):
+    """float64 attention of query rows ``q [n, Hq, D]`` at
+    ``positions`` over the sequence's own keys ``j <= pos`` (``j > pos
+    - window``)."""
+    n, Hq, D = q.shape
+    g = Hq // k.shape[1]
+    out = np.zeros((n, Hq, D))
+    for i, pos in enumerate(positions):
+        lo = 0 if window is None else max(0, pos - window + 1)
+        for h in range(Hq):
+            kk = k[lo:pos + 1, h // g].astype(np.float64)
+            s = kk @ q[i, h].astype(np.float64) / np.sqrt(D)
+            w = np.exp(s - s.max())
+            out[i, h] = (w / w.sum()) @ v[lo:pos + 1, h // g]
+    return out
+
+
+#: name -> (Hq, Hkv, D, Bs, ring entries, window)
+RINGS = {
+    "g1_head64": (2, 2, 64, 4, 5, 8),
+    "g4_head64": (8, 2, 64, 4, 5, 8),
+    "g7_head128": (14, 2, 128, 4, 5, 8),
+    "g7_head128_block8": (7, 1, 128, 8, 4, 16),
+    "window_longer_than_any_context": (4, 2, 128, 4, 12, 40),
+}
+_TOL = {"f32": 2e-5, "bf16": 3e-2}
+
+
+@pytest.mark.parametrize("impl", ["pallas", "xla"])
+@pytest.mark.parametrize("dt", ["f32", "bf16"])
+@pytest.mark.parametrize("case", list(RINGS))
+def test_windowed_decode_reads_the_last_window_through_the_ring(
+        case, dt, impl):
+    """Lanes at lengths below, at and several laps past the window,
+    each with a ring of its own; every row of the pool outside a lane's
+    window is NaN. f32: 2e-5 (f32 products and softmax against float64);
+    bf16: 3e-2 (the pool's rounding is in both sides' keys; the
+    kernel's bf16 probabilities and query rows are what is left)."""
+    Hq, Hkv, D, Bs, R, W = RINGS[case]
+    lengths = [1, W - 1, W, W + 3, 2 * R * Bs + 1, 37]
+    rs = np.random.RandomState(1)
+    pools, tbls, want = [], [], []
+    q = rs.randn(len(lengths), Hq, D).astype(np.float32)
+    N = 1 + R * len(lengths)
+    pool = np.full((N, Hkv, Bs, 2 * D), np.nan, np.float32)
+    pool = jnp.asarray(pool, _DT[dt])
+    for s, n in enumerate(lengths):
+        p1, t1, k, v = _ring_case(Hq, Hkv, D, Bs, R, n, dt, seed=s, N=R + 1)
+        t1 = t1 + s * R             # this lane's own blocks
+        pool = pool.at[t1].set(p1[t1 - s * R])
+        tbls.append(t1)
+        want.append(_plain_rows(q[s:s + 1], k, v, [n - 1], W)[0])
+    got = PA.paged_attention(
+        jnp.asarray(q), pool, jnp.asarray(np.stack(tbls)),
+        jnp.asarray(lengths, jnp.int32), impl=impl, window=W,
+        **({"interpret": True} if impl == "pallas" else {}))
+    np.testing.assert_allclose(np.asarray(got), np.stack(want),
+                               atol=_TOL[dt], rtol=_TOL[dt])
+
+
+@pytest.mark.parametrize("dt", ["f32", "bf16"])
+@pytest.mark.parametrize("g,D", [(1, 128), (4, 128), (7, 128)])
+def test_wide_heads_decode_on_the_mxu_form_without_a_window(g, D, dt):
+    """Heads of 128 lanes take ``_paged_kernel_wide`` with or without a
+    window: the plain table, lengths bounded, NaN past them."""
+    Hkv, Bs, B = 2, 4, 6
+    lengths = [1, 4, 9, 23]
+    rs = np.random.RandomState(2)
+    q = rs.randn(len(lengths), g * Hkv, D).astype(np.float32)
+    pool = jnp.full((1 + B * len(lengths), Hkv, Bs, 2 * D), np.nan,
+                    _DT[dt])
+    tbls, want = [], []
+    for s, n in enumerate(lengths):
+        p1, t1, k, v = _ring_case(g * Hkv, Hkv, D, Bs, B, n, dt, seed=s,
+                                  N=B + 1)
+        t1 = t1 + s * B
+        pool = pool.at[t1].set(p1[t1 - s * B])
+        tbls.append(t1)
+        want.append(_plain_rows(q[s:s + 1], k, v, [n - 1], None)[0])
+    got = PA.paged_attention_pallas(
+        jnp.asarray(q), pool, jnp.asarray(np.stack(tbls)),
+        jnp.asarray(lengths, jnp.int32), interpret=True)
+    np.testing.assert_allclose(np.asarray(got), np.stack(want),
+                               atol=_TOL[dt], rtol=_TOL[dt])
+
+
+#: name -> (Hq, Hkv, D, Bs, table or ring entries, window, C, p0, chunk_len)
+CHUNKS = {
+    "first_chunk": (4, 2, 64, 4, 8, None, 8, 0, 8),
+    "g7_third_chunk_padded": (7, 1, 128, 4, 8, None, 8, 16, 5),
+    "g4_unaligned_start": (8, 2, 128, 4, 16, None, 16, 21, 16),
+    "ring_first_chunk": (4, 2, 64, 4, 5, 8, 8, 0, 8),
+    "ring_wraps_g7": (14, 2, 128, 4, 5, 8, 8, 40, 8),
+    "ring_wraps_padded_unaligned": (7, 1, 128, 4, 5, 8, 8, 27, 3),
+    "ring_window_inside_the_chunk": (2, 2, 64, 4, 9, 4, 16, 32, 11),
+    "ring_window_longer_than_the_context": (4, 2, 128, 4, 13, 40, 8, 8, 8),
+}
+
+
+@pytest.mark.parametrize("case,dt,impl", [
+    (c, dt, impl) for c in CHUNKS for dt in ("f32", "bf16")
+    for impl in ("pallas", "xla")
+    # XLA's CPU backend has no bf16 x bf16 = f32 dot for the form a
+    # query group is mapped over its panel in
+    if dt == "f32" or impl == "pallas" or CHUNKS[c][0] == CHUNKS[c][1]])
+def test_a_chunk_attends_its_own_keys_tiled_or_dense(case, dt, impl):
+    """The tiled kernel (interpret mode) and XLA's span path against
+    the float64 sum, with and without a window: every row of the pool
+    that is not one of the sequence's first ``p0 + chunk_len``
+    positions (as the ring holds them) is NaN. Rows of padding are not
+    compared. Tolerances as for decode."""
+    Hq, Hkv, D, Bs, B, W, C, p0, clen = CHUNKS[case]
+    total = p0 + clen
+    R = B if W is not None else total // Bs + 2
+    pool, tbl, k, v = _ring_case(Hq, Hkv, D, Bs, R, total, dt, seed=5)
+    if W is None:               # a plain table, NULL-padded to B entries
+        tbl = np.concatenate([tbl[:-(-total // Bs)],
+                              np.zeros(B, np.int32)])[:B]
+    q = np.random.RandomState(6).randn(C, Hq, D).astype(np.float32)
+    want = _plain_rows(q[:clen], k, v, p0 + np.arange(clen), W)
+    if impl == "pallas":
+        got = PA.paged_prefill_attention_pallas(
+            jnp.asarray(q), pool, jnp.asarray(tbl), p0, clen, window=W,
+            interpret=True)
+    else:
+        # the chunk's padded rows were written too (finite junk)
+        pad = np.arange(total, p0 + C)
+        ent = (pad // Bs) % R if W is not None else pad // Bs
+        pool = pool.at[tbl[ent], :, pad % Bs].set(0.5)
+        got = PA.paged_prefill_attention(
+            jnp.asarray(q), pool, jnp.asarray(tbl), p0, clen, window=W)
+    np.testing.assert_allclose(np.asarray(got)[:clen], want,
+                               atol=_TOL[dt], rtol=_TOL[dt])
+
+
+def test_a_rings_span_write_lands_where_the_ring_puts_each_position():
+    """``kv_pool_set_span(ring=True)``: position p in entry ``(p // Bs)
+    % R``, other rows as they were."""
+    H, Bs, D, R, C, p0 = 2, 4, 8, 5, 8, 14
+    rs = np.random.RandomState(8)
+    pool = jnp.asarray(rs.randn(9, H, Bs, 2 * D), jnp.float32)
+    tbl = np.array([3, 7, 1, 5, 8], np.int32)
+    k = jnp.asarray(rs.randn(C, H, D), jnp.float32)
+    v = jnp.asarray(rs.randn(C, H, D), jnp.float32)
+    got = np.asarray(kv_pool_set_span(pool, jnp.asarray(tbl), p0, k, v,
+                                      ring=True))
+    want = np.asarray(pool).copy()
+    for c in range(C):
+        p = p0 + c
+        want[tbl[(p // Bs) % R], :, p % Bs] = np.concatenate(
+            [np.asarray(k[c]), np.asarray(v[c])], -1)
+    np.testing.assert_array_equal(got, want)

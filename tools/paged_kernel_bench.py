@@ -22,7 +22,12 @@ before PR 33: ~70 ns a row and head) and by blocks
 (``kv_pool_set_span``), at ``p0`` 0 and 37; and the attention
 (``paged_prefill_attention``: the span gathered and attended densely)
 at the three table buckets the cell's traffic meets (16, 32, 64 blocks)
-and starts ``p0`` 0 and 256.
+and starts ``p0`` 0 and 256. ``--prefill --heads 28 --kv-heads 4
+--head-dim 128 --block-size 64 --chunk 1024 --window 4096 --layers 3
+--blocks 3073 --kv bf16`` is the long-context cell's shape (PR 35):
+there the table spans more than 2,048 keys and the attention is the
+tiled Pallas kernel, timed at starts 0 to 13,312 in table bucket 256
+and, with ``--window``, through a ring of window + chunk + one block.
 
 Prints, for XLA's gather path and for the Pallas kernel at each ``G``
 (pool blocks a grid step; ``--chunk-blocks`` sets it past the kernel's
@@ -49,6 +54,7 @@ if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
 S, D, BS, B = 16, 64, 16, 64
+C = 256     # --prefill: the chunk's rows
 RAGGED = [301, 122, 275, 155, 179, 314, 378, 545, 169, 363, 268, 187, 274,
           229, 1, 640]
 CASES = {"ragged": RAGGED, "full": [320] * S, "ones": [1] * S}
@@ -65,6 +71,12 @@ def main(argv=None) -> int:
     ap.add_argument("--layers", type=int, default=48,
                     help="calls in the timed program")
     ap.add_argument("--blocks", type=int, default=321, help="pool blocks")
+    ap.add_argument("--head-dim", type=int, default=D)
+    ap.add_argument("--block-size", type=int, default=BS)
+    ap.add_argument("--chunk", type=int, default=C,
+                    help="--prefill: rows of the chunk")
+    ap.add_argument("--window", type=int, default=None,
+                    help="--prefill: also the windowed call, over a ring")
     ap.add_argument("--prefill", action="store_true",
                     help="a chunk's write and attention, not the decode "
                          "step's kernel")
@@ -159,9 +171,6 @@ def main(argv=None) -> int:
     return 0
 
 
-C = 256     # the chunk's rows
-
-
 def prefill(a, pa, cast, ms, write, tag) -> int:
     """``--prefill``: a chunk's write, by rows and by blocks, and its
     attention by table bucket."""
@@ -170,6 +179,7 @@ def prefill(a, pa, cast, ms, write, tag) -> int:
     import numpy as np
     N, H, LAYERS = a.blocks, a.heads, a.layers
     HKV = a.kv_heads or H
+    D, BS, C = a.head_dim, a.block_size, a.chunk
     q = jax.random.normal(jax.random.PRNGKey(7), (C, H, D), jnp.float32)
     make = jax.jit(lambda k: pa.fuse_kv(*(cast(jax.random.normal(
         kk, (N, HKV, BS, D), jnp.float32)) for kk in jax.random.split(k))))
@@ -185,7 +195,8 @@ def prefill(a, pa, cast, ms, write, tag) -> int:
 
     res = {}
     kv = jax.random.normal(jax.random.PRNGKey(9), (C, HKV, D), jnp.float32)
-    tbl = jnp.asarray(rs.permutation(np.arange(1, N))[:32], jnp.int32)
+    tbl = jnp.asarray(rs.permutation(np.arange(1, N))[:max(
+        32, C // BS + 2)], jnp.int32)
     for name, fn in (("write_rows", by_rows),
                      ("write_span", pa.kv_pool_set_span)):
         f = jax.jit(lambda ps, p0, fn=fn: [fn(p, tbl, p0, kv + i, kv)
@@ -202,17 +213,19 @@ def prefill(a, pa, cast, ms, write, tag) -> int:
             print(f"{name:11s} p0 {p0:3d} {t:9.3f} ms / {LAYERS} calls",
                   flush=True)
 
-    def attend(q, pools, tbl, p0):
+    def attend(q, pools, tbl, p0, window=None):
         """``--layers`` calls in one program, each fed by the one
         before and reading a pool of its own."""
         x = q
         for pool in pools:
-            x = q + 1e-3 * pa.paged_prefill_attention(x, pool, tbl, p0)
+            x = q + 1e-3 * pa.paged_prefill_attention(
+                x, pool, tbl, p0, window=window)
         return x
-    attend = jax.jit(attend)
-    for p0 in (0, C):
+    attend = jax.jit(attend, static_argnames="window")
+    long_context = C * 16 > pa._DENSE_SPAN_MAX   # the kernel's side
+    for p0 in ((0, 4 * C, 8 * C, 13 * C) if long_context else (0, C)):
         need = (p0 + C) // BS
-        for bucket in (16, 32, 64):
+        for bucket in ((256,) if long_context else (16, 32, 64)):
             if bucket < need:
                 continue
             tbl = np.zeros(bucket, np.int32)
@@ -221,6 +234,15 @@ def prefill(a, pa, cast, ms, write, tag) -> int:
             res[f"attend.p0_{p0}.b{bucket}"] = {
                 f"ms_per_{LAYERS}_calls": t}
             print(f"attend      p0 {p0:3d} bucket {bucket:2d} {t:9.3f} ms "
+                  f"/ {LAYERS} calls", flush=True)
+        if a.window:    # the same start through the window's ring
+            ring = -(-(a.window + C) // BS) + 1
+            tbl = rs.permutation(np.arange(1, N))[:ring]
+            t = ms(attend, q, pools, jnp.asarray(tbl, jnp.int32),
+                   jnp.int32(p0), a.window)
+            res[f"attend_window.p0_{p0}.ring{ring}"] = {
+                f"ms_per_{LAYERS}_calls": t}
+            print(f"attend win  p0 {p0:5d} ring {ring:3d} {t:9.3f} ms "
                   f"/ {LAYERS} calls", flush=True)
     write(res, f"paged_prefill_bench_{tag}.json")
     return 0
