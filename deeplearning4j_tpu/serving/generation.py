@@ -1068,23 +1068,21 @@ class GenerationEngine:
                 logits, pools, state, counters = \
                     model.forward_prefill_chunk(
                         params, tokens, p0, chunk_len, pools, table,
-                        state=state, slot=slot)
+                        state=state, slot=slot, last_only=True)
             else:
                 logits, pools, state = model.forward_prefill_chunk(
                     params, tokens, p0, chunk_len, pools, table,
-                    state=state)
-            # guard only rows < chunk_len: padded tail rows attend
-            # positions past the live length — stale block junk that
-            # is allowed to be anything (no-zeroing invariant)
-            ok = jnp.all(jnp.where(
-                (jnp.arange(tokens.shape[1]) < chunk_len)[:, None],
-                jnp.isfinite(logits), True))
-            last = jax.lax.dynamic_index_in_dim(
-                logits, chunk_len - 1, axis=0, keepdims=False)
+                    state=state, last_only=True)
+            # ``logits`` is [1, V], the head for the one row sampled
+            # below, and NaN when a row < chunk_len of the final hidden
+            # state is not finite. Padded tail rows attend positions
+            # past the live length — stale block junk that is allowed
+            # to be anything (no-zeroing invariant) — and are not read
+            ok = jnp.all(jnp.isfinite(logits))
             # same step-0 fold as the slot prefill — the first token's
             # sample is bit-identical across backends
             key = jax.random.fold_in(jax.random.PRNGKey(seed), 0)
-            first = _sample_one(last, temp, top_k, key)
+            first = _sample_one(logits[0], temp, top_k, key)
             return first, ok, pools, state, counters
         return chunk
 

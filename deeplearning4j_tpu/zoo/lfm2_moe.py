@@ -36,6 +36,7 @@ and the convolution's sum are float32; logits are float32.
 """
 from __future__ import annotations
 
+import functools
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import jax
@@ -44,6 +45,7 @@ import jax.numpy as jnp
 from ..kernels.paged_attention import (kv_pool_set, kv_pool_set_span,
                                        paged_attention,
                                        paged_prefill_attention)
+from ..nn.functional import sampled_row_logits
 from ..nn.layers.moe import MoeAccount, moe_ffn
 
 #: layout of the int32 vector both forwards return beside the logits:
@@ -291,7 +293,8 @@ class Lfm2MoeLM:
                 self._counters(counts))
 
     def forward_prefill_chunk(self, params, tokens, p0, chunk_len, pools,
-                              block_table, *, state, slot):
+                              block_table, *, state, slot,
+                              last_only: bool = False):
         """One prefill chunk of the request in ``slot``. tokens [1, C];
         p0, chunk_len scalars; block_table [n_blocks]. The chunk reads
         row ``slot`` of every state array (zeros instead where
@@ -301,7 +304,10 @@ class Lfm2MoeLM:
         attends over the sequence's span as it comes back out
         (:func:`~..kernels.paged_attention.paged_prefill_attention`).
         Rows past ``chunk_len`` route to no expert. Returns (logits
-        [C, V], pools, state, counters)."""
+        [C, V], pools, state, counters); with ``last_only`` the final
+        norm and the head run for the sampled row and not for the chunk
+        (:func:`~..nn.functional.sampled_row_logits`) and the logits
+        are ``[1, V]``."""
         C = tokens.shape[1]
         gpos = p0 + jnp.arange(C)
         live = jnp.arange(C) < chunk_len
@@ -338,5 +344,7 @@ class Lfm2MoeLM:
             x = x + op
             x = x + self._ff(i, w, rms_norm(x, w["ffn_norm"], self.norm_eps),
                              live, counts)
-        return (self._logits(params, x), pools, state,
-                self._counters(counts))
+        head = functools.partial(self._logits, params)
+        logits = (sampled_row_logits(x, chunk_len, head) if last_only
+                  else head(x))
+        return logits, pools, state, self._counters(counts)

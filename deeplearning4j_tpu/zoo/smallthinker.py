@@ -37,6 +37,7 @@ rotary and softmax are float32; the pools take the engine's
 """
 from __future__ import annotations
 
+import functools
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import jax
@@ -45,6 +46,7 @@ import jax.numpy as jnp
 from ..kernels.paged_attention import (kv_pool_set, kv_pool_set_span,
                                        paged_attention,
                                        paged_prefill_attention)
+from ..nn.functional import sampled_row_logits
 from ..nn.layers.moe import MoeAccount, moe_ffn
 from .lfm2_moe import rms_norm, rope
 
@@ -241,14 +243,18 @@ class SmallThinkerLM:
                 self._counters(counts))
 
     def forward_prefill_chunk(self, params, tokens, p0, chunk_len, pools,
-                              block_table, *, state=(), slot=None):
+                              block_table, *, state=(), slot=None,
+                              last_only: bool = False):
         """One prefill chunk of a request. tokens [1, C]; p0, chunk_len
         scalars; ``block_table`` one table a group: the global group's
         ``[n_blocks]`` bucket and the window group's ring. A layer
         writes the chunk's K and V into its pool by blocks and attends
         over the sequence's span (its window) as it comes back out.
         Rows past ``chunk_len`` route to no expert. Returns (logits
-        [C, V], pools, state, counters)."""
+        [C, V], pools, state, counters); with ``last_only`` the final
+        norm and the head run for the sampled row and not for the chunk
+        (:func:`~..nn.functional.sampled_row_logits`) and the logits
+        are ``[1, V]``."""
         C = tokens.shape[1]
         gpos = p0 + jnp.arange(C)
         live = jnp.arange(C) < chunk_len
@@ -274,5 +280,7 @@ class SmallThinkerLM:
                     window=self.window if windowed else None)
                 x = x + self._mm(att.reshape(C, -1), w["Wo"])
             x = x + self._experts(w, x, logits, live, counts)
-        return (self._logits(params, x), pools, list(state),
-                self._counters(counts))
+        head = functools.partial(self._logits, params)
+        out = (sampled_row_logits(x, chunk_len, head) if last_only
+               else head(x))
+        return out, pools, list(state), self._counters(counts)

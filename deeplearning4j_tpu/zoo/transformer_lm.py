@@ -25,7 +25,7 @@ from typing import List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 
-from ..nn.functional import layer_norm
+from ..nn.functional import layer_norm, sampled_row_logits
 from ..nn.layers.attention import TransformerEncoderLayer
 
 
@@ -151,21 +151,26 @@ class CausalTransformerLM:
         return x @ params["head"], new_pools, state
 
     def forward_prefill_chunk(self, params, tokens, p0, chunk_len,
-                              pools, block_table, state=()):
+                              pools, block_table, state=(),
+                              last_only: bool = False):
         """One prefill CHUNK against the paged pools: embed the chunk
         at its global positions, run every block's
         ``apply_prefill_paged`` (scatter K/V into the owning blocks,
         attend causally over the prefix in the pool), and return the
         chunk's logits. The caller splits a prompt into chunks and
         feeds them in order; on the final chunk it samples from row
-        ``chunk_len - 1``.
+        ``chunk_len - 1``. With ``last_only`` the final norm and the
+        head run for that row and not for the chunk
+        (:func:`~..nn.functional.sampled_row_logits`) and the logits
+        are ``[1, V]``: what the engine's chunk asks for; a caller that
+        reads every row (speculative verification) leaves it off.
 
         tokens: [1, C] int32 (C = chunk bucket); p0: scalar int32
         chunk start; chunk_len: scalar int32 valid tokens in this
         chunk; block_table: [n_blocks] int32 covering at least
         ``p0 + C`` positions; ``state`` as in
         :meth:`forward_decode_paged`.
-        Returns (logits [C, V], pools, state)."""
+        Returns (logits [C, V] or [1, V], pools, state)."""
         C = tokens.shape[1]
         gpos = p0 + jnp.arange(C)
         # padded tail rows can run past the position table; clamp the
@@ -180,8 +185,13 @@ class CausalTransformerLM:
             x, pool = blk.apply_prefill_paged(bp, x, pool, block_table,
                                               p0, chunk_len)
             new_pools.append(pool)
-        x = layer_norm(x[0], params["lnf_g"], params["lnf_b"])
-        return x @ params["head"], new_pools, state
+
+        def head(h):
+            return (layer_norm(h, params["lnf_g"], params["lnf_b"])
+                    @ params["head"])
+        logits = (sampled_row_logits(x[0], chunk_len, head) if last_only
+                  else head(x[0]))
+        return logits, new_pools, state
 
     def forward_verify(self, params, tokens, p0, chunk_len, k_caches,
                        v_caches, slot):

@@ -106,10 +106,13 @@ class _PoisonLM(CausalTransformerLM):
         return jnp.where(bad[:, None], jnp.nan, logits), pools, state
 
     def forward_prefill_chunk(self, params, tokens, p0, chunk_len,
-                              pools, block_table, state=()):
-        # same rig for the paged chunked-prefill path: logits [C, V]
+                              pools, block_table, state=(),
+                              last_only=False):
+        # same rig for the paged chunked-prefill path: logits [C, V],
+        # or [1, V] as the engine asks (every rig broadcasts over rows)
         logits, pools, state = super().forward_prefill_chunk(
-            params, tokens, p0, chunk_len, pools, block_table, state)
+            params, tokens, p0, chunk_len, pools, block_table, state,
+            last_only)
         logits = self._rig(logits)
         trig = jnp.any(tokens == TRIGGER)
         hot = jnp.where(jnp.arange(self.vocab_size) == POISON,

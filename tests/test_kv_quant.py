@@ -472,9 +472,11 @@ class _CachePoisonLM(CausalTransformerLM):
         return logits, ks, vs
 
     def forward_prefill_chunk(self, params, tokens, p0, chunk_len,
-                              pools, block_table, state=()):
+                              pools, block_table, state=(),
+                              last_only=False):
         logits, pools, state = super().forward_prefill_chunk(
-            params, tokens, p0, chunk_len, pools, block_table, state)
+            params, tokens, p0, chunk_len, pools, block_table, state,
+            last_only)
         bad = jnp.any(tokens == NAN_TRIGGER)
         C = tokens.shape[1] if tokens.ndim > 1 else tokens.shape[0]
         Bs = pools[0].shape[2]
