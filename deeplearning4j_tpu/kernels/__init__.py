@@ -8,6 +8,11 @@ autoscheduler — attention being the canonical case (per
 """
 from .decode_attention import decode_attention
 from .flash_attention import flash_attention
-from .paged_attention import paged_attention
+from .paged_attention import paged_attention, paged_prefill_attention
+from .selective_scan import selective_scan_chunk, selective_scan_step
 
-__all__ = ["flash_attention", "decode_attention", "paged_attention"]
+# (`moe_experts.expert_ffn` is imported by the one layer that runs it:
+# its import pulls in megablox, which no other model should pay for)
+__all__ = ["flash_attention", "decode_attention", "paged_attention",
+           "paged_prefill_attention", "selective_scan_chunk",
+           "selective_scan_step"]

@@ -869,9 +869,11 @@ class GenerationEngine:
             self._vcs = self._cache.vs
 
     def _fresh_state(self):
-        """The arrays a model keeps a slot (``slot_state_shapes``),
-        zeroed once here and never again: a request's first chunk
-        starts from zeros whatever its slot held."""
+        """The arrays a model keeps a slot (``slot_state_shapes``:
+        each its own shape and type, a recurrence's float32 state
+        beside a convolution's bfloat16 inputs), zeroed once here and
+        never again: a request's first chunk starts from zeros
+        whatever its slot held."""
         return [jnp.zeros(shape, dtype)
                 for shape, dtype in self._state_shapes]
 

@@ -703,6 +703,8 @@ _PROM_COUNTERS = frozenset({
     # routing counters of a served model with experts (the `moe` block)
     "decode_pairs", "decode_experts_touched", "decode_expert_slots",
     "chunk_pairs",
+    # rows the state-space layers of a served model walked (`ssm`)
+    "chunk_rows", "decode_rows",
     # fleet-side counters
     "routed", "hedges", "hedges_won", "hedge_budget_denied",
     "requests_lost", "ejections", "readmissions", "restarts",

@@ -172,9 +172,11 @@ class Lfm2MoeLM:
 
     def slot_state_shapes(self, num_slots: int):
         """The arrays kept a SLOT, as (shape, dtype) with the slot
-        first: the last ``conv_L_cache - 1`` values of ``v = B * u`` of
-        each short convolution. A request's first chunk starts from
-        zeros whatever the slot held; the engine never clears it."""
+        first (a model may declare arrays of several types: the engine
+        takes each as it is given): here one a short convolution, the
+        last ``conv_L_cache - 1`` values of its ``v = B * u``. A
+        request's first chunk starts from zeros whatever the slot held;
+        the engine never clears it."""
         return [((int(num_slots), self.conv_taps - 1, self.d_model),
                  self.dtype)] * len(self.conv_layers)
 

@@ -378,3 +378,114 @@ def test_the_long_context_cells_programs_fit_and_relay_no_pool(
             "chunk": {"paged_prefill_attention",
                       "paged_prefill_attention_window"}}[which]
     assert names == attn | {"moe_experts_up", "moe_experts_down"}
+
+
+# -- the fourth configuration's kernels and programs (PR 37) -------------------------
+@pytest.mark.parametrize("gated", [False, True], ids=["plain", "gated"])
+def test_selective_scan_kernel_compiles_for_v5e_at_the_cells_widths(v5e,
+                                                                    gated):
+    """``jamba2-3b``: a chunk of 1,024 rows over 5,120 channels of 16
+    states; the call carries the name the trace reads
+    (``custom-call/selective_scan_chunk``)."""
+    from deeplearning4j_tpu.kernels import selective_scan as ss
+    sds = jax.ShapeDtypeStruct
+    f = lambda *s: sds(s, jnp.float32)                      # noqa: E731
+    T, Di, N = 1024, 5120, 16
+    text = v5e(lambda c, dt, B, C, A, D, h, n, z:
+               ss.selective_scan_chunk_pallas(c, dt, B, C, A, D, h, n,
+                                              z if gated else None),
+               f(T, Di), f(T, Di), f(T, N), f(T, N), f(N, Di), f(Di),
+               f(N, Di), sds((), jnp.int32), f(T, Di))
+    assert _custom_calls(text) == [ss.CHUNK_KERNEL_NAME]
+
+
+def test_paged_kernels_take_20_heads_over_one_kv_head_at_the_cells_shape(
+        v5e):
+    """``jamba2-3b``: 20 query heads over 1 KV head of 128, a bf16 pool
+    of 4,097 blocks of 64, a table of 256: the decode kernel's MXU body
+    and the tiled chunk kernel over 1,024 rows (its 20 x 128 query rows
+    a KV head and their score tiles inside the VMEM limit)."""
+    from deeplearning4j_tpu.kernels.paged_attention import (
+        PREFILL_KERNEL_NAME, paged_prefill_attention_pallas)
+    sds = jax.ShapeDtypeStruct
+    pool = _pool(4097, 1, 64, 128, "bf16")
+    text = v5e(lambda q, kv, t, l: paged_attention_pallas(
+        q, kv, t, l, interpret=False),
+        sds((16, 20, 128), jnp.float32), pool, sds((16, 256), jnp.int32),
+        sds((16,), jnp.int32))
+    assert _custom_calls(text) == [KERNEL_NAME]
+    text = v5e(lambda q, kv, t, p0, n: paged_prefill_attention_pallas(
+        q, kv, t, p0, n),
+        sds((1024, 20, 128), jnp.float32), pool, sds((256,), jnp.int32),
+        sds((), jnp.int32), sds((), jnp.int32))
+    assert _custom_calls(text) == [PREFILL_KERNEL_NAME]
+
+
+def _jamba_program(monkeypatch, which):
+    """(function, abstract arguments) of the engine's ``jit_step`` or
+    ``jit_chunk`` for ``benchmark/configs/jamba2-3b.json`` as the chip
+    runs it: the engine's own program bodies over the served class,
+    shapes in place of its weights, pools and slot state."""
+    import importlib
+    import json
+    from deeplearning4j_tpu.serving.generation import GenerationEngine
+    from deeplearning4j_tpu.zoo.jamba import JambaLM
+    for mod in ("paged_attention", "selective_scan"):
+        monkeypatch.setattr(importlib.import_module(
+            "deeplearning4j_tpu.kernels." + mod), "default_platform",
+            lambda: "tpu")
+    with open(os.path.join(ROOT, "benchmark", "configs",
+                           "jamba2-3b.json")) as f:
+        cfg = json.load(f)
+    lm = JambaLM(**cfg["model"])
+    params = jax.eval_shape(lambda: lm.init()._params)
+    lm._params = None
+    e = cfg["engine"]
+    S, Bs, C = e["num_slots"], e["block_size"], e["prefill_chunk_tokens"]
+    eng = object.__new__(GenerationEngine)
+    eng.model, eng.decode_impl, eng.cache_backend = lm, "auto", "paged"
+    eng._groups, eng._extended = [], True
+    sds = jax.ShapeDtypeStruct
+    pools = [_pool(e["num_blocks"], 1, Bs, 128, "bf16")] * 2
+    state = [sds(s, d) for s, d in lm.slot_state_shapes(S)]
+    i32 = lambda *s: sds(s, jnp.int32)                    # noqa: E731
+    B = e["max_seq_len"] // Bs
+    if which == "step":
+        return eng._decode_fn(), (
+            params, pools, state, i32(S), i32(S), sds((S,), jnp.bool_),
+            i32(S), i32(S, B), sds((S,), jnp.uint32), i32(S),
+            sds((S,), jnp.float32), i32(S), i32(S), i32(S))
+    return eng._chunk_fn(), (
+        params, pools, state, i32(1, C), i32(), i32(), i32(B), i32(),
+        sds((), jnp.uint32), sds((), jnp.float32), i32())
+
+
+@pytest.mark.parametrize("which", ["step", "chunk"])
+def test_the_jamba_cells_programs_fit_and_relay_no_state(
+        v5e, monkeypatch, which):
+    """``jit_step`` and ``jit_chunk`` (table bucket 256, the traffic's
+    largest) of ``jamba2-3b`` compile for a v5e with the pools and the
+    52 slot arrays donated: no ``copy`` with a pool's or a state's
+    shape, pools and state aliased (0.27 + 0.15 GB), the kernels the
+    metrics read by name, and arguments plus temporaries of 6.6 GB."""
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    fn, args = _jamba_program(monkeypatch, which)
+    one = SingleDeviceSharding(topologies.get_topology_desc(
+        platform="tpu", topology_name="v5e:2x2").devices[0])
+    args = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one), args)
+    compiled = jax.jit(fn, donate_argnums=(1, 2)).lower(*args).compile()
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    assert 6.4e9 < mem.argument_size_in_bytes < 6.6e9
+    assert mem.temp_size_in_bytes < 0.3e9
+    assert mem.alias_size_in_bytes > 0.41e9         # pools and state
+    for shape in (r"bf16\[4097,1,64,256\]", r"f32\[16,16,5120\]",
+                  r"bf16\[16,3,5120\]"):
+        assert [ln for ln in text.splitlines()
+                if re.search(r"= " + shape + r"\S* copy\(", ln)] == []
+    names = set(_custom_calls(text))
+    if which == "chunk":
+        assert names == {"paged_prefill_attention", "selective_scan_chunk"}
+    else:       # the step's recurrence is XLA's fusion: no kernel
+        assert names == {"paged_attention_decode"}
