@@ -380,6 +380,29 @@ def test_the_long_context_cells_programs_fit_and_relay_no_pool(
     assert names == attn | {"moe_experts_up", "moe_experts_down"}
 
 
+@pytest.mark.parametrize("which", ["step", "chunk"])
+def test_the_long_context_cells_experts_come_back_by_a_gather(
+        v5e, monkeypatch, which):
+    """PR 38: in ``smallthinker-21b-a3b``'s ``jit_step`` and
+    ``jit_chunk`` the experts' sorted rows return to pair order by one
+    gather a layer into ``f32[k, T, D]``: no scatter whose result is
+    the ``f32[T*k, D]`` rows (one update a row: 11.2 ms of the parent's
+    chunk, PERF.md section 5) and no ``f32[T, k, D]`` relayout."""
+    import json
+    with open(os.path.join(ROOT, "benchmark", "configs",
+                           "smallthinker-21b-a3b.json")) as f:
+        cfg = json.load(f)
+    m, e = cfg["model"], cfg["engine"]
+    T = e["num_slots"] if which == "step" else e["prefill_chunk_tokens"]
+    k, D = m["moe_num_active_primary_experts"], m["hidden_size"]
+    fn, args, _ = _smallthinker_program(monkeypatch, which)
+    text = v5e(fn, *args, donate=(1, 2))
+    assert not re.search(r"= f32\[%d,%d\]\S* scatter\(" % (T * k, D), text)
+    assert "f32[%d,%d,%d]" % (T, k, D) not in text
+    assert len(re.findall(r"= f32\[%d,%d,%d\]\S* gather\(" % (k, T, D),
+                          text)) == m["num_hidden_layers"]
+
+
 # -- the fourth configuration's kernels and programs (PR 37) -------------------------
 @pytest.mark.parametrize("gated", [False, True], ids=["plain", "gated"])
 def test_selective_scan_kernel_compiles_for_v5e_at_the_cells_widths(v5e,

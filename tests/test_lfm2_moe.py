@@ -252,6 +252,60 @@ def test_dead_lanes_route_nowhere_and_count_nowhere():
                        atol=1e-6)
 
 
+@pytest.mark.parametrize("scoring", ["sigmoid", "softmax"])
+@pytest.mark.parametrize("k", [4, 6])
+def test_the_unsort_by_gather_sums_what_the_scatter_did(monkeypatch, k,
+                                                        scoring):
+    """PR 38: the experts' sorted rows go back to pair order through a
+    gather by the inverse permutation, not ``.at[order].set``. Held to
+    the parent's scatter form, written out here, with the rows of no
+    expert NaN (as the kernel may leave them): they must be selected
+    away, never multiplied by a zero gate."""
+    from deeplearning4j_tpu.nn.layers import moe as moe_mod
+    from deeplearning4j_tpu.nn.layers.moe import route_softmax
+
+    def undefined_tail(xs, w1, w3, w2, sizes, **kw):
+        ys = expert_ffn(xs, w1, w3, w2, sizes, **kw)
+        return jnp.where(jnp.arange(xs.shape[0])[:, None] < sizes.sum(),
+                         ys, jnp.nan)
+    monkeypatch.setattr(moe_mod, "expert_ffn", undefined_tail)
+    rng = np.random.default_rng(38 + k)
+    E, D, F, T = 8, 32, 16, 13
+    p = {"W_g": jnp.asarray(rng.normal(size=(D, E)), jnp.float32),
+         "expert_bias": jnp.asarray(rng.normal(size=E) * 0.1, jnp.float32),
+         "W1": jnp.asarray(rng.normal(size=(E, D, F)) * 0.1, jnp.float32),
+         "W3": jnp.asarray(rng.normal(size=(E, D, F)) * 0.1, jnp.float32),
+         "W2": jnp.asarray(rng.normal(size=(E, F, D)) * 0.1, jnp.float32)}
+    live = jnp.asarray(rng.random(T) < 0.7).at[0].set(False)
+    x = jnp.where(live[:, None], jnp.asarray(rng.normal(size=(T, D)),
+                                             jnp.float32), jnp.nan)
+    logits = jnp.where(live[:, None], jnp.asarray(rng.normal(size=(T, E)),
+                                                  jnp.float32), jnp.nan)
+
+    def parent(x):
+        """``moe_ffn``'s body before PR 38: mask, scatter, [T, k, D]."""
+        if scoring == "sigmoid":
+            experts, g = route(x, p["W_g"], p["expert_bias"], k, live)
+        else:
+            experts, g = route_softmax(logits, k, live)
+        flat = experts.reshape(-1)
+        order = jnp.argsort(flat, stable=True)
+        sizes = jnp.zeros(E + 1, jnp.int32).at[flat].add(1)[:E]
+        ys = undefined_tail(x[order // k], p["W1"], p["W3"], p["W2"],
+                            sizes)
+        ys = jnp.where((flat[order] < E)[:, None], ys, 0.0)
+        pairs = jnp.zeros_like(ys).at[order].set(ys).reshape(T, k, D)
+        return (pairs * g[..., None]).sum(1)
+
+    want = np.asarray(jax.jit(parent)(x))
+    got, _ = jax.jit(lambda x: moe_ffn(
+        p, x, k, live, router_logits=logits, scoring=scoring))(x)
+    got = np.asarray(got)
+    assert np.isfinite(got).all() and np.isfinite(want).all()
+    assert (got[~np.asarray(live)] == 0).all()
+    np.testing.assert_allclose(got, want, rtol=1e-6, atol=1e-6)
+
+
 # -- kernels --------------------------------------------------------------------
 def _wave(shape, f):
     n = int(np.prod(shape))
@@ -650,18 +704,23 @@ def test_run_py_judges_the_tiny_configuration(tiny_root, control):
         assert out["correct"] is True and got["value"] < got["limit"]
 
 
-# -- the expert layer's new arguments leave this model's programs alone (PR 35) -------
+# -- the programs lower to what PR 38 left (PR 35, PR 38) -------------------------
 #: SHA-256 (first 16 hex digits) of the StableHLO that the tiny model's
-#: decode step and prefill chunk lower to, locations stripped, taken on
-#: the commit before ``moe_ffn`` gained ``router_logits`` / ``scoring``
-#: / ``gate`` and ``span_attend`` its ``kpos`` / ``window``, with this
-#: container's JAX (0.9.0). A JAX upgrade changes them: take them anew
+#: decode step and prefill chunk lower to, locations stripped, with this
+#: container's JAX (0.9.0). PR 35 pinned them so that ``moe_ffn``'s and
+#: ``span_attend``'s new arguments could not move this model; PR 38
+#: took them anew, on purpose, when the experts' rows began to return
+#: to pair order by a gather through the inverse permutation instead of
+#: a scatter: the un-sort and the combine changed and nothing else (the
+#: two lowerings differ by the argsort, the transposes, the gather and
+#: the select-reduce in place of the mask, the scatter and the
+#: ``[T, k, D]`` reshape). A JAX upgrade changes them: take them anew
 #: from the parent commit then, never from the tree under test.
-LOWERED_BEFORE_PR35 = {"step": "181459453d855f41", "chunk": "5ab16d6137fa402a"}
+LOWERED_SINCE_PR38 = {"step": "4206d6169f8a2875", "chunk": "d347c7feb7b9b02b"}
 
 
 @pytest.mark.parametrize("program", ["step", "chunk"])
-def test_the_programs_lower_to_the_operations_they_did_before_pr35(program):
+def test_the_programs_lower_to_the_operations_pr38_left(program):
     import hashlib
     import re
     from deeplearning4j_tpu.zoo.lfm2_moe import Lfm2MoeLM
@@ -685,4 +744,4 @@ def test_the_programs_lower_to_the_operations_they_did_before_pr35(program):
             jnp.int32(1)).as_text()
     text = re.sub(r"loc\(.*?\)|#loc\d*( = .*)?", "", text)
     assert hashlib.sha256(text.encode()).hexdigest()[:16] == \
-        LOWERED_BEFORE_PR35[program]
+        LOWERED_SINCE_PR38[program]

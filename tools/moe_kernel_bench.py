@@ -7,6 +7,7 @@ pairs) makes, against ``jax.lax.ragged_dot`` and, for the decode shape,
 the dense product over all 32 experts.
 
     chiprun -- python3 tools/moe_kernel_bench.py [--tokens 16,256]
+    chiprun -- python3 tools/moe_kernel_bench.py --unsort
 
 Prints, for each token count and form, the milliseconds per 12 calls,
 the experts the routing touched, the bytes those experts hold (what the
@@ -15,6 +16,16 @@ roofline reads at 819 GB/s) and the largest error against
 ``chiprun_out/moe_kernel_bench.json``. The sibling of
 ``tools/paged_kernel_bench.py``: the numbers PERF.md section 5 quotes
 for the kernel alone. It measures nothing off a TPU.
+
+``--unsort`` times what follows the kernel instead (PR 38): the experts'
+sorted output rows put back in pair order and summed with their
+weights, the parent's scatter form (mask, ``.at[order].set``,
+``[T, k, D]``) against ``nn/layers/moe.py::combine_sorted`` (a gather
+through the inverse permutation) and against that gather alone, at
+``smallthinker-21b-a3b``'s chunk (6,144 pairs x 2,560) and
+``lfm2-8b-a1b``'s (1,024 x 2,048), 12 calls in one program, every call
+with operands of its own (one shared operand lets XLA hoist the work
+out of the loop, PR 33). Written to ``chiprun_out/moe_unsort_bench.json``.
 """
 from __future__ import annotations
 
@@ -31,9 +42,80 @@ if ROOT not in sys.path:
 E, D, F, K, LAYERS = 32, 2048, 1792, 4, 12
 
 
+#: (pairs, width, experts, experts a token) of each cell's chunk
+UNSORT_SHAPES = ((6144, 2560, 64, 6), (1024, 2048, 32, 4))
+
+
+def _ms(f, *args, n=10):
+    """Milliseconds a call of ``f`` (compiled and warmed first)."""
+    import jax
+    jax.block_until_ready(f(*args))
+    t = time.perf_counter()
+    for _ in range(n):
+        out = f(*args)
+    jax.block_until_ready(out)
+    return (time.perf_counter() - t) / n * 1e3
+
+
+def unsort(out_path: str) -> dict:
+    """The parent's scatter form against the gather, per 12 calls."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from deeplearning4j_tpu.nn.layers.moe import combine_sorted
+
+    def scatter_form(ys, order, experts, g, E):
+        T, k = experts.shape
+        flat = experts.reshape(-1)
+        ys = jnp.where((flat[order] < E)[:, None], ys, 0.0)
+        pairs = jnp.zeros_like(ys).at[order].set(ys).reshape(T, k, -1)
+        return (pairs * g[..., None]).sum(1)
+
+    def gather_alone(ys, order, experts, g, E):
+        T, k = experts.shape
+        return ys[jnp.argsort(order).reshape(T, k).T]
+
+    forms = {"scatter": scatter_form, "gather": combine_sorted,
+             "gather_alone": gather_alone}
+    rs = np.random.RandomState(38)
+    res = {}
+    for P, D, E, K in UNSORT_SHAPES:
+        T = P // K
+        ops = []
+        for i in range(LAYERS):
+            # K distinct experts a token; the last eighth of the tokens
+            # dead (no expert), as past a chunk's length
+            ex = np.stack([rs.permutation(E)[:K] for _ in range(T)])
+            ex[T - T // 8:] = E
+            g = rs.rand(T, K).astype(np.float32)
+            order = np.argsort(ex.reshape(-1), kind="stable")
+            ys = jax.random.normal(jax.random.PRNGKey(i), (P, D),
+                                   jnp.float32)
+            ops.append((ys, jnp.asarray(order, jnp.int32),
+                        jnp.asarray(ex, jnp.int32), jnp.asarray(g)))
+        want = None
+        for name, form in forms.items():
+            f = jax.jit(lambda ops, form=form: [form(*o, E) for o in ops])
+            got = f(ops)
+            if name == "scatter":
+                want = got
+            err = None if name == "gather_alone" else max(
+                float(jnp.max(jnp.abs(a - b))) for a, b in zip(got, want))
+            t = _ms(f, ops)
+            res[f"{P}x{D}.{name}"] = {f"ms_per_{LAYERS}_calls": t,
+                                      "ms_a_call": t / LAYERS,
+                                      "max_err_vs_scatter": err}
+            print(f"{P:5d} x {D} {name:13s} {t:8.3f} ms / {LAYERS} calls "
+                  f"({t / LAYERS:.3f} a call)   err {err}", flush=True)
+    with open(out_path, "w") as f:
+        json.dump(res, f, indent=1)
+    return res
+
+
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--tokens", default="16,256")
+    ap.add_argument("--unsort", action="store_true")
     a = ap.parse_args(argv)
     import jax
     import jax.numpy as jnp
@@ -43,6 +125,10 @@ def main(argv=None) -> int:
         print("moe_kernel_bench: JAX found no TPU; a time from anything "
               "else is not a device number", file=sys.stderr)
         return 2
+    os.makedirs(os.path.join(ROOT, "chiprun_out"), exist_ok=True)
+    if a.unsort:
+        unsort(os.path.join(ROOT, "chiprun_out", "moe_unsort_bench.json"))
+        return 0
     from deeplearning4j_tpu.kernels.moe_experts import expert_ffn, silu
 
     def dense_product(x, w1, w3, w2, sizes):
@@ -79,14 +165,6 @@ def main(argv=None) -> int:
             return lax.fori_loop(0, LAYERS, body, x)
         return jax.jit(run)
 
-    def ms(f, *args, n=10):
-        f(*args).block_until_ready()
-        t = time.perf_counter()
-        for _ in range(n):
-            out = f(*args)
-        out.block_until_ready()
-        return (time.perf_counter() - t) / n * 1e3
-
     res = {}
     for tokens in (int(t) for t in a.tokens.split(",")):
         # every token picks K distinct experts, uniformly
@@ -104,7 +182,7 @@ def main(argv=None) -> int:
             got = jax.jit(lambda x, s, impl=impl: form(impl)(
                 x, w1, w3, w2, s))(x, sizes)
             err = float(jnp.max(jnp.abs(got - ref)))
-            t = ms(step_of(impl), x, sizes)
+            t = _ms(step_of(impl), x, sizes)
             res[f"{tokens}.{impl}"] = {
                 f"ms_per_{LAYERS}_calls": t, "max_err": err,
                 "experts_touched": touched,
@@ -113,7 +191,6 @@ def main(argv=None) -> int:
                   f"calls   touched {touched}/{E}   roofline "
                   f"{LAYERS * touched * 3 * D * F * 2 / 819e9 * 1e3:.3f} ms"
                   f"   err {err:.2e}", flush=True)
-    os.makedirs(os.path.join(ROOT, "chiprun_out"), exist_ok=True)
     with open(os.path.join(ROOT, "chiprun_out", "moe_kernel_bench.json"),
               "w") as f:
         json.dump(res, f, indent=1)
