@@ -39,6 +39,7 @@ from ..faults import TransientFault, poll_until_idle
 from ..profiler import OpProfiler
 from .engine import (ClientError, InferenceEngine, ServingError,
                      _concat_results, _slice)
+from .metrics import GC_ACCOUNT
 
 
 class QueueFullError(ServingError):
@@ -151,6 +152,7 @@ class MicroBatcher:
         self._running = True
         self._draining = False
         self._beat = time.monotonic()  # scheduler heartbeat (/healthz)
+        GC_ACCOUNT.start()
         self._thread = threading.Thread(target=self._loop, daemon=True,
                                         name="serving-batcher")
         self._thread.start()

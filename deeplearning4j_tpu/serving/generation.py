@@ -62,7 +62,7 @@ from ..kernels.kv_quant import (canonical_kv_dtype, kv_bytes_per_token,
                                 kv_unpack_host, kv_update_slice)
 from ..kernels.paged_attention import kv_pool_zeros
 from .kvcache import KVCache, SlotTable
-from .metrics import GenerationMetrics
+from .metrics import GC_ACCOUNT, GenerationMetrics
 from .offload import (DiskRing, HostBlockStore, HostRun,
                       OffloadPrefetcher)
 from .paging import (NULL_BLOCK, BlockAllocator, BlockTable, CacheGroup,
@@ -839,6 +839,7 @@ class GenerationEngine:
         self._draining = False
         self._beat = time.monotonic()  # scheduler heartbeat (/healthz)
         self._running = True
+        GC_ACCOUNT.start()
         self._thread = threading.Thread(target=self._loop, daemon=True,
                                         name="generation-scheduler")
         self._thread.start()

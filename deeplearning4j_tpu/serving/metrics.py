@@ -8,6 +8,7 @@ server returns :meth:`ServingMetrics.snapshot` per model.
 """
 from __future__ import annotations
 
+import gc
 import heapq
 import re
 import threading
@@ -34,6 +35,9 @@ SCHED_SPAN_PREFIX = "gen."
 HTTP_WRITE_SPAN = "http.stream_write"
 #: why a request waits at the head of the queue
 HEAD_BLOCKED_CAUSES = ("blocks", "slots")
+#: one collection of CPython's cyclic collector, on the thread that
+#: triggered it (docs/observability.md, "In a `jax.profiler` trace")
+GC_SPAN = "host.gc"
 
 
 class ServingMetrics:
@@ -354,6 +358,59 @@ class SchedulerAccount:
                 "slowest": [[t0, s, step, dict(ph)]
                             for s, t0, step, ph in slowest],
             }
+
+
+class GcAccount:
+    """What CPython's cyclic collector takes from the process: one
+    ``gc.callbacks`` entry, installed once (:meth:`start` again is a
+    no-op), however many engines start. Each collection takes one
+    ``time.perf_counter()`` stamp going in and one coming out, is added
+    to ``collections[g]`` and ``pause_s[g]`` of its generation, and is a
+    ``host.gc`` span (stat ``generation``) on the profiler's clock,
+    recorded only while a profiler session runs.
+
+    No lock: callbacks run with the interpreter lock held, one
+    collection at a time, and :meth:`snapshot` copies the arrays."""
+
+    def __init__(self):
+        self.collections = [0] * len(gc.get_count())
+        self.pause_s = [0.0] * len(self.collections)
+        self.longest_s = 0.0
+        self._t0 = None
+        self._ann = None
+
+    def start(self) -> None:
+        """Install the callback unless it is installed."""
+        if self._callback not in gc.callbacks:
+            gc.callbacks.append(self._callback)
+
+    def _callback(self, phase: str, info: dict) -> None:
+        if phase == "start":
+            self._t0 = time.perf_counter()
+            self._ann = TraceAnnotation(GC_SPAN,
+                                        generation=info["generation"])
+            self._ann.__enter__()
+        elif self._t0 is not None:
+            dt = time.perf_counter() - self._t0
+            self._ann.__exit__(None, None, None)
+            self._t0 = self._ann = None
+            g = info["generation"]
+            self.collections[g] += 1
+            self.pause_s[g] += dt
+            if dt > self.longest_s:
+                self.longest_s = dt
+
+    def snapshot(self) -> Dict:
+        n, s = list(self.collections), list(self.pause_s)
+        return {"collections": {str(g): v for g, v in enumerate(n)},
+                "pause_s": {str(g): v for g, v in enumerate(s)},
+                "pause_s_total": sum(s),
+                "longest_s": self.longest_s}
+
+
+#: the process's one account of collections (``process.gc`` in every
+#: served model's ``/stats`` block)
+GC_ACCOUNT = GcAccount()
 
 
 class GenerationMetrics:

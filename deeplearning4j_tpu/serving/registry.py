@@ -14,7 +14,7 @@ from typing import Dict, List, Optional, Sequence
 from .batcher import MicroBatcher
 from .engine import ClientError, InferenceEngine
 from .generation import GenerationEngine
-from .metrics import GenerationMetrics, ServingMetrics
+from .metrics import GC_ACCOUNT, GenerationMetrics, ServingMetrics
 
 
 class ModelNotFound(ClientError):
@@ -100,6 +100,7 @@ class ServedModel:
         s["version"] = self.version
         s["model_class"] = type(self.model).__name__
         s["batching"] = self.batcher is not None
+        s["process"] = {"gc": GC_ACCOUNT.snapshot()}
         return s
 
     def summary(self) -> Dict:
@@ -177,6 +178,8 @@ class ServedGenerator:
         s["version"] = self.version
         s["model_class"] = type(self.model).__name__
         s["serving_mode"] = "generation"
+        # process-wide, repeated in every model's block
+        s["process"] = {"gc": GC_ACCOUNT.snapshot()}
         return s
 
     def summary(self) -> Dict:

@@ -36,7 +36,16 @@ _RESERVOIR_KEYS = frozenset(RESERVOIR_SNAPSHOT_KEYS)
 #: reads (sliding-window rates, wall-clock stamps): presence and type
 #: are asserted, the value is not.
 VOLATILE_LEAVES = frozenset({"tokens_per_sec", "samples_per_sec",
-                             "ts", "uptime_s", "iter_seconds"})
+                             "ts", "uptime_s", "iter_seconds",
+                             # CPython collects whenever it allocates,
+                             # between any two reads
+                             "process_gc_collections", "process_gc_pause_s",
+                             "process_gc_pause_s_total",
+                             "process_gc_longest_s"})
+
+
+def _volatile(base):
+    return any(base.endswith("_" + v) or base == v for v in VOLATILE_LEAVES)
 
 
 def parse_prometheus(text):
@@ -95,9 +104,7 @@ def expected_samples(obj, base, labels=()):
     presence only."""
     labels = list(labels)
     if isinstance(obj, (bool, int, float)):
-        volatile = any(base.endswith("_" + v) or base == v
-                       for v in VOLATILE_LEAVES)
-        value = None if volatile else _num(obj)
+        value = None if _volatile(base) else _num(obj)
         if any(base.endswith("_" + c) or base == c
                for c in _PROM_COUNTERS):
             yield base + "_total", _labels(labels), value, "counter"
@@ -121,7 +128,7 @@ def expected_samples(obj, base, labels=()):
                 all(isinstance(v, (int, float)) for v in obj.values()):
             for k, v in obj.items():
                 yield (base, _labels(labels + [("bucket", k)]),
-                       _num(v), "gauge")
+                       None if _volatile(base) else _num(v), "gauge")
             return
         for k, v in obj.items():
             yield from expected_samples(v, _name(base, str(k)), labels)

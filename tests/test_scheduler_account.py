@@ -344,8 +344,10 @@ def test_profiler_trace_holds_the_phases_on_the_schedulers_thread(
             jax.profiler.stop_trace()
     finally:
         eng.stop()
-    from benchmark import trace as trace_mod
-    spans = table.read_spans(trace_mod.find_xplane(str(tmp_path)))
+    from benchmark import host_trace, trace as trace_mod
+    # gen. only: a collection on another thread is a host.gc span there
+    spans = host_trace.load_host_spans(trace_mod.find_xplane(str(tmp_path)),
+                                       prefixes=("gen.",))
     names = {s["name"] for s in spans}
     assert {"gen.admit", "gen.chunk_dispatch", "gen.chunk_wait",
             "gen.decode_dispatch", "gen.decode_wait", "gen.emit",

@@ -17,15 +17,21 @@ removes it, and writes under ``chiprun_out/``:
   ``gen.decode_wait`` and of ``gen.emit``, the first client stamp of
   the burst), every iteration of the scheduler's account in the window
   (``scheduler.slowest`` with the limit lifted), the oversleep probe's
-  late wake-ups, and the result line.
+  late wake-ups, the device-idle gaps split by what the host was
+  doing, and the result line.
 
-``read_spans()`` and ``step_table()`` need only a profile: the CPU
-tests use them on a trace of the tiny model.
+``step_table()`` needs only a profile: the CPU tests use it on a
+trace of the tiny model. The spans are read by the benchmark's own
+reader, ``benchmark/host_trace.py::load_host_spans``; the table also
+holds what that module makes of them (``idle_gaps_by_host``,
+``host_clock``, ``device_idle_on_host_share``), which ``run.py`` does
+not report yet (PERF.md section 7). What stays here is this tool's own
+use: the step table, the late wake-ups and the ``clock_sync`` spans
+that put the clients' ``perf_counter`` stamps on the profile's clock.
 
 A scratch tool of PR 26's finding (PERF.md section 5), kept for the
 ``perf_opt`` PRs that have to show where a device millisecond goes at
-the client. ``benchmark/trace.py`` does not read host lines yet
-(ROADMAP T1).
+the client.
 """
 from __future__ import annotations
 
@@ -42,29 +48,6 @@ if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
 SYNC_SPAN = "clock_sync"        # carries time.perf_counter() as a stat
-
-
-def read_spans(path: str, prefixes=("gen.", "http.", SYNC_SPAN)):
-    """Host spans of a profile whose names start with one of
-    ``prefixes``: dicts of ``line`` (an index: one host thread each),
-    ``name``, ``start_s``, ``end_s`` and the span's own stats
-    (``step``, ``chunk``, ``slots``), sorted by start."""
-    from jax.profiler import ProfileData
-    out = []
-    n_line = 0
-    for plane in ProfileData.from_file(path).planes:
-        if not plane.name.startswith("/host:"):
-            continue
-        for line in plane.lines:
-            n_line += 1
-            for e in line.events:
-                if e.name.startswith(tuple(prefixes)):
-                    out.append({"line": n_line, "name": e.name,
-                                "start_s": e.start_ns / 1e9,
-                                "end_s": (e.start_ns + e.duration_ns) / 1e9,
-                                **{k: v for k, v in e.stats}})
-    out.sort(key=lambda s: s["start_s"])
-    return out
 
 
 def device_runs(path: str, program: str, device_prefix="/device:TPU:"):
@@ -138,7 +121,7 @@ def trace_cell(workload: str, seed: int, seconds: float, out_dir: str,
     rehearsal passes a tiny ``root`` and ``require_chip=False``)."""
     import jax
     import numpy as np
-    from benchmark import run, trace as trace_mod
+    from benchmark import host_trace, run, trace as trace_mod
     from benchmark.kinds import serve_closed_loop as kind
 
     os.makedirs(out_dir, exist_ok=True)
@@ -187,7 +170,10 @@ def trace_cell(workload: str, seed: int, seconds: float, out_dir: str,
         v = run.read_metric(m["name"], obs, os.path.join(root, "benchmark"))
         if v is not None:
             other[m["name"]] = float(v)
-    spans = read_spans(kept) if trace else []
+    spans = host_trace.load_host_spans(
+        kept, host_trace.HOST_PREFIXES + (SYNC_SPAN,)) if trace else []
+    planes = obs["trace"] or []
+    full = trace_mod.fullest(planes) if planes else None
     offset = clock_offset(spans)
     t_open, t_close = obs["window"]["span"]
     gap = obs["traffic"]["burst_gap_ms"] / 1e3
@@ -229,6 +215,11 @@ def trace_cell(workload: str, seed: int, seconds: float, out_dir: str,
         "occupancy_open": obs["stats"]["open"]["slots"]["occupancy_hist"],
         "occupancy_close": obs["stats"]["close"]["slots"]["occupancy_hist"],
         "late_wakeups": late,
+        "idle_gaps_by_host": host_trace.idle_by_host(full, spans)
+        if full else [],
+        "host_clock": host_trace.host_clock(full, spans) if full else None,
+        "device_idle_on_host_share": host_trace.idle_on_host_share(
+            planes, spans),
     }
     return out, table
 
