@@ -30,22 +30,24 @@ a Pallas grid step has a cost of its own (~0.35 us with three small
 operands on a v5e), so the kernel's job is to stream the LIVE K/V once
 in few, large steps and keep the online-softmax state in VMEM.
 
-The grid is ``(S, ceil(B / G))``: one grid step takes one slot, ALL its
-heads, and ``G`` table entries. A pool block ``[H, Bs, 2 * D]`` is
-contiguous in the pool, so it is one DMA; the pool enters the call as
-``G`` operands whose index maps read a scalar-prefetched table
-(`pltpu.PrefetchScalarGridSpec`, pallas guide section 12) and aim each
-at ``pool[tbl[s, c * G + g]]`` -- the gather costs no extra pass over
-memory, and Pallas's pipeline fetches chunk ``c + 1`` (or the next
-slot's first) while chunk ``c`` is computed. ``G`` follows from the
-block's VMEM footprint (:func:`blocks_per_chunk`). Past a slot's last
-live block every operand is aimed at the block it already holds, which
-starts no DMA, and the body is skipped (``pl.when``): a step costs its
-live keys plus ~70 ns an operand for each table entry walked. The
-scores of a block are a ``[H, Bs]`` tile made on the VPU (multiply the
-block by the head's query row, zero in the value lanes, and reduce over
-lanes) in f32; the MXU has no use for one query row a head. The
-accumulator is as wide as a row, and its value half is the output.
+One grid step takes one slot, ALL its heads, and a chunk of ``G`` table
+entries. A pool block ``[H, Bs, 2 * D]`` is contiguous in the pool, so
+it is one DMA; the pool enters the call as ``G`` operands whose index
+maps read a scalar-prefetched list (`pltpu.PrefetchScalarGridSpec`,
+pallas guide section 12) and aim each at its pool block -- the gather
+costs no extra pass over memory, and Pallas's pipeline fetches the next
+chunk (or the next slot's first) while one is computed. ``G`` follows
+from the block's VMEM footprint (:func:`blocks_per_chunk`). The grid is
+one axis over the (slot, chunk) pairs that hold live keys, slot-major
+(:func:`live_pairs`, built once a step in XLA), its size a traced
+scalar: a step costs its live keys, and a table entry past a slot's
+length costs nothing (a static grid over every slot's whole table paid
+~0.28 us a dead step: 3.4 ms of 48 calls at the first cell's shape,
+PERF.md section 5). The scores of a block are a ``[H, Bs]`` tile made
+on the VPU (multiply the block by the head's query row, zero in the
+value lanes, and reduce over lanes) in f32; the MXU has no use for one
+query row a head. The accumulator is as wide as a row, and its value
+half is the output.
 
 Layout: q [S, H, D]; pool [N, H_kv, Bs, 2 * D] (positions contiguous
 per head inside a block, same reasoning as the slot cache's
@@ -98,7 +100,7 @@ masked by POSITION with ``where``, never multiplied away.
 from __future__ import annotations
 
 import functools
-from typing import Optional, Sequence
+from typing import NamedTuple, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -427,37 +429,49 @@ def blocks_per_chunk(H: int, Bs: int, D: int, itemsize: int, B: int) -> int:
     return 1 << (g.bit_length() - 1)
 
 
-def _paged_kernel(tbl_ref, len_ref, *refs, quant: bool, G: int,
-                  scale: float, g: int = 1, windowed: bool = False):
-    """One grid step (slot ``s``, chunk ``c``) of paged decode
-    attention: every head of the slot against the ``G`` pool blocks of
-    table entries ``c * G .. c * G + G - 1``.
+def _pair(slot_ref, chunk_ref, nch_ref, len_ref):
+    """The (slot, chunk) pair a grid step of the decode kernel takes
+    (:func:`live_pairs`), whether it is the slot's first and its last,
+    and the slot's length."""
+    i = pl.program_id(0)
+    s, c = slot_ref[i], chunk_ref[i]
+    return s, c, c == 0, c == nch_ref[s] - 1, len_ref[s]
 
-    Refs (the slot dim squeezed): tbl_ref [S, C * G] (the table entry
-    each operand fetches: the index maps alone read it) and len_ref
-    [S], scalar-prefetched; q_ref [H, 2 * D], the query row in the key
-    lanes and zeros in the value lanes; ``G`` pool blocks [H, Bs,
-    2 * D]; for an int8 pool ``G`` K then ``G`` V scale tiles [H, Bs];
-    o_ref [H, D]; scratch m, l [H, 1] and acc [H, 2 * D].
+
+def _paged_kernel(ent_ref, slot_ref, chunk_ref, nch_ref, len_ref, *refs,
+                  quant: bool, G: int, scale: float, g: int = 1,
+                  windowed: bool = False):
+    """One grid step, the pair (slot ``s``, chunk ``c``), of paged
+    decode attention: every head of the slot against the ``G`` pool
+    blocks of table entries ``c * G .. c * G + G - 1``.
+
+    Refs (the slot dim squeezed): ent_ref, slot_ref, chunk_ref and
+    nch_ref, the pairs' list (:func:`live_pairs`; the index maps alone
+    read ent_ref), and len_ref [S], scalar-prefetched; q_ref [H, 2 * D],
+    the query row in the key lanes and zeros in the value lanes; ``G``
+    pool blocks [H, Bs, 2 * D]; for an int8 pool ``G`` K then ``G`` V
+    scale tiles [H, Bs]; o_ref [H, D]; scratch m, l [H, 1] and acc
+    [H, 2 * D]. The state is set at a slot's first pair and the output
+    written at its last.
 
     The scores of a block are a [H, Bs] tile: a VPU multiply by the
     head's padded query row and a lane reduction, in f32 whatever the
-    pool holds (the value lanes meet zeros). A chunk whose first
-    position is past the length runs no body (and fetched nothing: the
-    index maps repeat a block they already hold). Inside the last live
-    chunk, what a block holds past the length (a stale tail, or another
-    position's rows where the index map repeated a block) is masked by
-    position with ``where``, never multiplied away: it may be NaN. The
-    accumulator takes whole rows; its key half is never read.
+    pool holds (the value lanes meet zeros). A free lane's one pair runs
+    no body. Inside the last live chunk, what a block holds past the
+    length (a stale tail, or another position's rows where the index
+    map repeated a block) is masked by position with ``where``, never
+    multiplied away: it may be NaN. The accumulator takes whole rows;
+    its key half is never read.
 
     Grouped-query heads (``g`` query heads to a KV head, ``H`` the KV
     heads): q, o and the scratch hold ``g * H`` rows, member ``j`` of
     every group in rows ``j * H .. (j + 1) * H`` (the wrapper lays them
     out so), and each member multiplies the same tiles, loaded once.
 
-    ``windowed``: a third scalar-prefetched ref comes first, the slot's
-    first live position ``lo``; chunk 0 starts at the block that holds
-    it, and a position below it is masked like one past the length."""
+    ``windowed``: one more scalar-prefetched ref, [S], comes first, the
+    slot's first live position ``lo``; chunk 0 starts at the block that
+    holds it, and a position below it is masked like one past the
+    length."""
     lo_ref, refs = (refs[0], refs[1:]) if windowed else (None, refs)
     q_ref, refs = refs[0], refs[1:]
     kv_refs = refs[:G]
@@ -465,16 +479,16 @@ def _paged_kernel(tbl_ref, len_ref, *refs, quant: bool, G: int,
         else (None, None)
     o_ref, m_s, l_s, acc_s = refs[-4:]
     H, Bs, D2 = kv_refs[0].shape
-    c = pl.program_id(1)
-    length = len_ref[pl.program_id(0)]
+    s, c, first_pair, last_pair, length = _pair(slot_ref, chunk_ref,
+                                                nch_ref, len_ref)
     if windowed:
-        lo = lo_ref[pl.program_id(0)]
+        lo = lo_ref[s]
         blk0 = lo // Bs + c * G         # the chunk's first logical block
         start = blk0 * Bs
     else:
         blk0, start = c * G, c * (G * Bs)
 
-    @pl.when(c == 0)
+    @pl.when(first_pair)
     def _init():
         m_s[...] = jnp.full_like(m_s, _NEG_INF)
         l_s[...] = jnp.zeros_like(l_s)
@@ -531,15 +545,15 @@ def _paged_kernel(tbl_ref, len_ref, *refs, quant: bool, G: int,
                 acc = acc + jnp.sum(p[:, :, None] * live_tile(b), axis=1)
             m_s[rows, :], l_s[rows, :], acc_s[rows, :] = m_new, l_new, acc
 
-    @pl.when(c == pl.num_programs(1) - 1)
+    @pl.when(last_pair)
     def _finalize():
         # a free lane (length 0) ran no chunk: acc is 0 and so is its row
         o_ref[...] = (acc_s[:, D2 // 2:] / jnp.maximum(l_s[...], 1e-30)
                       ).astype(o_ref.dtype)
 
 
-def _paged_kernel_wide(tbl_ref, len_ref, *refs, G: int, scale: float,
-                       windowed: bool = False):
+def _paged_kernel_wide(ent_ref, slot_ref, chunk_ref, nch_ref, len_ref,
+                       *refs, G: int, scale: float, windowed: bool = False):
     """The same grid step for heads that fill whole 128-lane rows
     (``D % 128 == 0``, an f32 or bf16 pool): the key lanes and the value
     lanes of a block are aligned slices, so the scores and the sum run
@@ -549,7 +563,7 @@ def _paged_kernel_wide(tbl_ref, len_ref, *refs, G: int, scale: float,
     ``g`` query rows (padded to a sublane tile, ``gp``) meet its
     ``[Bs, D]`` keys in one product a block.
 
-    Refs: tbl_ref, len_ref (and ``lo`` where ``windowed``),
+    Refs: the pairs' list and len_ref (and ``lo`` where ``windowed``),
     scalar-prefetched; q_ref [H, gp, D] in the pool's type; ``G`` pool
     blocks [H, Bs, 2 * D]; o_ref [H, gp, D]; scratch m, l [H, gp, 1] and
     acc [H, gp, D] (``H`` the KV heads). Masks as above: by position,
@@ -560,12 +574,12 @@ def _paged_kernel_wide(tbl_ref, len_ref, *refs, G: int, scale: float,
     H, Bs, D2 = kv_refs[0].shape
     D = D2 // 2
     gp = q_ref.shape[1]
-    c = pl.program_id(1)
-    length = len_ref[pl.program_id(0)]
-    lo = lo_ref[pl.program_id(0)] if windowed else 0
+    s, c, first_pair, last_pair, length = _pair(slot_ref, chunk_ref,
+                                                nch_ref, len_ref)
+    lo = lo_ref[s] if windowed else 0
     blk0 = (lo // Bs if windowed else 0) + c * G
 
-    @pl.when(c == 0)
+    @pl.when(first_pair)
     def _init():
         m_s[...] = jnp.full_like(m_s, _NEG_INF)
         l_s[...] = jnp.zeros_like(l_s)
@@ -600,10 +614,60 @@ def _paged_kernel_wide(tbl_ref, len_ref, *refs, G: int, scale: float,
                                     preferred_element_type=jnp.float32)
             m_s[h], l_s[h], acc_s[h] = m_new, l_new, acc
 
-    @pl.when(c == pl.num_programs(1) - 1)
+    @pl.when(last_pair)
     def _finalize():
         o_ref[...] = (acc_s[...] / jnp.maximum(l_s[...], 1e-30)
                       ).astype(o_ref.dtype)
+
+
+class LivePairs(NamedTuple):
+    """The decode kernel's grid: the (slot, chunk) pairs that hold live
+    keys, slot-major, with the table entries each pair's ``G`` operands
+    fetch. Arrays of the static length ``P = S * C``; pairs from ``n``
+    on repeat the last one and are never visited."""
+    n: jax.Array            # the pairs, sum(n_chunks): the grid's size
+    entry: jax.Array        # [P * G] the pool block of operand b of pair i
+    slot: jax.Array         # [P]
+    chunk: jax.Array        # [P] the pair's chunk inside its slot
+    n_chunks: jax.Array     # [S] a slot's pairs, at least one
+    lengths: jax.Array      # [S], past the table clipped to it
+    lo: Optional[jax.Array]  # [S] a window's first position, or None
+
+
+def live_pairs(block_tables, lengths, Bs: int, G: int, C: int,
+               window: Optional[int] = None) -> LivePairs:
+    """The pairs a decode call visits: for slot ``s`` its chunks of
+    ``G`` blocks from the block of its first live key (``max(0, len -
+    window)``, else 0) to the block of its last, at least one (a free
+    lane's, whose body is skipped and whose output is zeros) and at
+    most ``C``. Operand ``b`` of chunk ``c`` fetches table entry ``c *
+    G + b`` while that holds live keys, then the last live one it had,
+    its first if it has none: an index that does not change starts no
+    DMA. Worked out once a step in XLA (every layer's call shares it),
+    so that an index map is one SMEM read."""
+    S, B = block_tables.shape
+    lengths = jnp.asarray(lengths, jnp.int32)
+    if window is None:
+        lengths = jnp.minimum(lengths, B * Bs)
+        lo, blk_lo = None, jnp.zeros_like(lengths)
+    else:
+        lo = jnp.maximum(lengths - int(window), 0)
+        blk_lo = lo // Bs
+    last = (jnp.maximum(lengths, 1) - 1) // Bs - blk_lo   # from blk_lo
+    n_chunks = jnp.clip(jnp.where(lengths > 0, last // G + 1, 1), 1, C)
+    ends = jnp.cumsum(n_chunks)
+    i = jnp.arange(S * C, dtype=jnp.int32)
+    slot = jnp.minimum(jnp.sum(i[:, None] >= ends[None, :], axis=1,
+                               dtype=jnp.int32), S - 1)
+    chunk = jnp.minimum(i - (ends - n_chunks)[slot], n_chunks[slot] - 1)
+    gi = jnp.arange(G, dtype=jnp.int32)
+    ci = jnp.minimum(chunk[:, None],
+                     jnp.maximum(last[slot][:, None] - gi, 0) // G)
+    blk = blk_lo[slot][:, None] + ci * G + gi                   # [P, G]
+    blk = blk % B if window is not None else jnp.minimum(blk, B - 1)
+    entry = jnp.asarray(block_tables, jnp.int32)[slot[:, None], blk]
+    return LivePairs(ends[-1], entry.reshape(-1), slot, chunk, n_chunks,
+                     lengths, lo)
 
 
 def paged_attention_pallas(q, pool, block_tables, lengths,
@@ -612,22 +676,23 @@ def paged_attention_pallas(q, pool, block_tables, lengths,
     """Pallas paged decode attention. Same contract as
     :func:`paged_attention_xla` (grouped-query heads included: the
     query heads of a group are more rows against the same tile).
-    Grid ``(S, ceil(B / G))``: one grid
-    step attends all heads of a slot over ``G`` table entries
-    (:func:`blocks_per_chunk`). The pool enters as ``G`` operands, each
-    one whole block ``[H, Bs, 2 * D]`` (contiguous in the pool) that
-    the scalar-prefetched table aims at ``pool[tbl[s, c * G + g]]``.
-    Past the slot's last live block an operand is aimed at the block it
-    fetched last, so the pipeline fetches nothing new, and the body is
-    skipped: the cost follows the live length, not the table span. An
-    int8 QuantArray pool brings its ``[H, Bs]`` scale tiles the same
+    A grid step attends all heads of a slot over ``G`` table entries
+    (:func:`blocks_per_chunk`), and the grid is the list of (slot,
+    chunk) pairs that hold live keys (:func:`live_pairs`), its size a
+    traced scalar: the cost follows the live lengths, not the table
+    span. The pool enters as ``G`` operands, each one whole block
+    ``[H, Bs, 2 * D]`` (contiguous in the pool) that the
+    scalar-prefetched list aims at its pool block; q and the output
+    follow the pair's slot, so the output block changes at a slot's
+    boundary only. Past the slot's last live block an operand is aimed
+    at the block it fetched last, so the pipeline fetches nothing new.
+    An int8 QuantArray pool brings its ``[H, Bs]`` scale tiles the same
     way (one for K, one for V) and is dequantized in VMEM.
 
-    With ``window`` the tables are rings (module docstring) and the
-    grid's second axis covers the blocks a window can touch, counted
-    from the block of ``max(0, len - window)``: the cost follows
-    ``min(len, window)``. Heads of 128 lanes (an f32 or bf16 pool) run
-    :func:`_paged_kernel_wide`, on the MXU."""
+    With ``window`` the tables are rings (module docstring) and a
+    slot's chunks start at the block of ``max(0, len - window)``: the
+    cost follows ``min(len, window)``. Heads of 128 lanes (an f32 or
+    bf16 pool) run :func:`_paged_kernel_wide`, on the MXU."""
     if interpret is None:
         interpret = default_platform() != "tpu"
     quant = is_quantized(pool)
@@ -648,33 +713,16 @@ def paged_attention_pallas(q, pool, block_tables, lengths,
     # blocks a window touches (one more where it starts inside a block)
     span = min(B, _cdiv(int(window), Bs) + 1) if windowed else B
     G = blocks_per_chunk(Hkv, Bs, D, vals.dtype.itemsize, span)
-    C = _cdiv(span, G)
-    # The table entry each of a chunk's G operands fetches, [S, C * G]:
-    # its own (c * G + g) while that is live, then the last live one
-    # this operand had, its first if it has none: an index that does
-    # not change starts no DMA. Worked out here, once a step (every
-    # layer's call shares it), so that an index map is one SMEM read.
-    lengths = jnp.asarray(lengths, jnp.int32)
-    if not windowed:
-        lengths = jnp.minimum(lengths, B * Bs)
-    last = (jnp.maximum(lengths, 1) - 1)[:, None] // Bs
-    ci, gi = jnp.divmod(jnp.arange(C * G, dtype=jnp.int32), G)
-    prefetch = [lengths]
-    if windowed:
-        lo = jnp.maximum(lengths - int(window), 0)
-        blk_lo = (lo // Bs)[:, None]
-        ci = jnp.minimum(ci, jnp.maximum(last - blk_lo - gi, 0) // G)
-        entry = (blk_lo + ci * G + gi) % B
-        prefetch.append(lo)
-    else:
-        ci = jnp.minimum(ci, jnp.maximum(last - gi, 0) // G)
-        entry = jnp.minimum(ci * G + gi, B - 1)
-    fetched = jnp.take_along_axis(
-        jnp.asarray(block_tables, jnp.int32), entry, axis=1)
-    n_pre = 1 + len(prefetch)
+    pairs = live_pairs(block_tables, lengths, Bs, G, _cdiv(span, G), window)
+    prefetch = [pairs.entry, pairs.slot, pairs.chunk, pairs.n_chunks,
+                pairs.lengths] + ([pairs.lo] if windowed else [])
+    n_pre = len(prefetch)
 
     def entry_map(b, tail):
-        return lambda s, c, tbl, *_: (tbl[s, c * G + b],) + tail
+        return lambda i, ent, *_: (ent[i * G + b],) + tail
+
+    def slot_map(i, ent, slot, *_):
+        return (slot[i], 0, 0) + ((0,) if wide else ())
 
     if wide:
         # a KV head's g query rows, padded to a sublane tile of the
@@ -683,13 +731,12 @@ def paged_attention_pallas(q, pool, block_tables, lengths,
         gp = _cdiv(g, tile) * tile
         qw = jnp.pad(q.reshape(S, Hkv, g, D),
                      ((0, 0), (0, 0), (0, gp - g), (0, 0))).astype(vals.dtype)
-        q_spec = pl.BlockSpec((None, Hkv, gp, D),
-                              lambda s, c, *_: (s, 0, 0, 0))
+        q_spec = pl.BlockSpec((None, Hkv, gp, D), slot_map)
         out = pl.pallas_call(
             functools.partial(_paged_kernel_wide, G=G,
                               scale=1.0 / (D ** 0.5), windowed=windowed),
             grid_spec=pltpu.PrefetchScalarGridSpec(
-                num_scalar_prefetch=n_pre, grid=(S, C),
+                num_scalar_prefetch=n_pre, grid=(pairs.n,),
                 in_specs=[q_spec] + [
                     pl.BlockSpec((None, Hkv, Bs, D2),
                                  entry_map(b, (0, 0, 0)))
@@ -701,7 +748,7 @@ def paged_attention_pallas(q, pool, block_tables, lengths,
             out_shape=jax.ShapeDtypeStruct((S, Hkv, gp, D), q.dtype),
             interpret=interpret,
             name=KERNEL_NAME_WINDOW if windowed else KERNEL_NAME,
-        )(fetched, *prefetch, qw, *([vals] * G))
+        )(*prefetch, qw, *([vals] * G))
         return out[:, :, :g].reshape(S, H, D)
 
     if g > 1:       # member j of every group in rows j * Hkv ..
@@ -710,8 +757,7 @@ def paged_attention_pallas(q, pool, block_tables, lengths,
     q_pad = jnp.pad(q, ((0, 0), (0, 0), (0, D)))
 
     def row_spec(width):
-        return pl.BlockSpec((None, H, width),
-                            lambda s, c, *_: (s, 0, 0))
+        return pl.BlockSpec((None, H, width), slot_map)
 
     operands, in_specs = [q_pad], [row_spec(D2)]
     operands += [vals] * G
@@ -727,8 +773,8 @@ def paged_attention_pallas(q, pool, block_tables, lengths,
         functools.partial(_paged_kernel, quant=quant, G=G,
                           scale=1.0 / (D ** 0.5), g=g, windowed=windowed),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=n_pre,      # fetched, lengths (, lo)
-            grid=(S, C),
+            num_scalar_prefetch=n_pre,      # the pairs, lengths (, lo)
+            grid=(pairs.n,),
             in_specs=in_specs, out_specs=row_spec(D),
             scratch_shapes=[pltpu.VMEM((H, 1), jnp.float32),   # max
                             pltpu.VMEM((H, 1), jnp.float32),   # sum
@@ -738,7 +784,7 @@ def paged_attention_pallas(q, pool, block_tables, lengths,
         # the custom call's instruction name in the HLO and so in a
         # device trace (else it is named after the enclosing jit)
         name=KERNEL_NAME_WINDOW if windowed else KERNEL_NAME,
-    )(fetched, *prefetch, *operands)
+    )(*prefetch, *operands)
     if g > 1:
         out = out.reshape(S, g, Hkv, D).swapaxes(1, 2).reshape(S, H, D)
     return out

@@ -23,8 +23,8 @@ from deeplearning4j_tpu.kernels.decode_attention import \
 from deeplearning4j_tpu.kernels.flash_attention import flash_attention
 from deeplearning4j_tpu.kernels.kv_quant import QuantArray
 from deeplearning4j_tpu.kernels.paged_attention import (
-    KERNEL_NAME, kv_pool_set, kv_pool_set_span, paged_attention_pallas,
-    paged_prefill_attention)
+    KERNEL_NAME, KERNEL_NAME_WINDOW, kv_pool_set, kv_pool_set_span,
+    paged_attention_pallas, paged_prefill_attention)
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -125,15 +125,30 @@ def test_decode_kernels_compile_for_v5e(v5e, size, dt):
         q, k, v, l, interpret=False), q, cache, cache, lens)
 
 
-def test_grouped_query_paged_kernel_compiles_for_v5e_at_the_cells_widths(v5e):
-    """``lfm2-8b-a1b.decode_backlog``: 32 query heads over 8 KV heads of
-    64, a bf16 pool of 1,025 blocks of 16, 16 slots."""
+#: a cell's decode call: query heads, KV heads, head size, block size,
+#: table (or ring) entries, pool blocks, window
+DECODE_CALLS = {
+    "lfm2-8b-a1b": (32, 8, 64, 16, 64, 1025, None),
+    "smallthinker-21b-a3b.global": (28, 4, 128, 64, 256, 3073, None),
+    # a ring of blocks_for(window + chunk) + 1 entries a slot
+    "smallthinker-21b-a3b.window": (28, 4, 128, 64, 81, 1297, 4096),
+    "jamba2-3b": (20, 1, 128, 64, 256, 4097, None)}
+
+
+@pytest.mark.parametrize("cell", list(DECODE_CALLS))
+def test_grouped_query_paged_kernel_compiles_for_v5e_at_the_cells_widths(
+        v5e, cell):
+    """Each grouped-query cell's decode call, 16 slots of a bf16 pool:
+    one kernel, under the name its layer's metrics read, with the grid
+    over the live (slot, chunk) pairs sized by a traced scalar."""
+    Hq, Hkv, D, Bs, B, N, window = DECODE_CALLS[cell]
     sds = jax.ShapeDtypeStruct
     text = v5e(lambda q, kv, t, l: paged_attention_pallas(
-        q, kv, t, l, interpret=False),
-        sds((16, 32, 64), jnp.float32), _pool(1025, 8, 16, 64, "bf16"),
-        sds((16, 64), jnp.int32), sds((16,), jnp.int32))
-    assert _custom_calls(text) == [KERNEL_NAME]
+        q, kv, t, l, window=window, interpret=False),
+        sds((16, Hq, D), jnp.float32), _pool(N, Hkv, Bs, D, "bf16"),
+        sds((16, B), jnp.int32), sds((16,), jnp.int32))
+    assert _custom_calls(text) == [KERNEL_NAME_WINDOW if window
+                                   else KERNEL_NAME]
 
 
 # -- the pool's layout: no program relays a pool (ISSUE 31) -------------------
@@ -378,6 +393,9 @@ def test_the_long_context_cells_programs_fit_and_relay_no_pool(
             "chunk": {"paged_prefill_attention",
                       "paged_prefill_attention_window"}}[which]
     assert names == attn | {"moe_experts_up", "moe_experts_down"}
+    # one attention kernel a layer: 3 global layers, 9 window layers
+    calls = _custom_calls(text)
+    assert sorted(calls.count(n) for n in attn) == [3, 9]
 
 
 @pytest.mark.parametrize("which", ["step", "chunk"])
@@ -512,3 +530,4 @@ def test_the_jamba_cells_programs_fit_and_relay_no_state(
         assert names == {"paged_prefill_attention", "selective_scan_chunk"}
     else:       # the step's recurrence is XLA's fusion: no kernel
         assert names == {"paged_attention_decode"}
+        assert len(_custom_calls(text)) == 2    # one a attention layer

@@ -56,7 +56,7 @@ def _ref_greedy(lm, prompt, n):
     return out
 
 
-def ragged_paged_case(H, Bs, D, G, seed=0):
+def ragged_paged_case(H, Bs, D, G, seed=0, g=1, lens="edges"):
     """Inputs that put every edge of the paged kernel's grid in one
     call, for chunks of ``G`` blocks of ``Bs`` keys: lengths 0, 1, one
     short of / at / one past a block edge and a chunk edge, two whole
@@ -64,18 +64,21 @@ def ragged_paged_case(H, Bs, D, G, seed=0):
     chunk is ragged); tables that are a random permutation of the pool,
     not in pool order; and NaN wherever no key lives: in a pool block
     no table names, in the block every table entry past the length
-    names, and in the tail of each lane's last live block. Returns
-    (q, k_pool, v_pool, tables, lengths), the pools f32 and apart
-    ([N, H, Bs, D] each): ``fuse_kv`` lays them side by side as the
-    kernel reads them."""
+    names, and in the tail of each lane's last live block. ``lens="ones"``
+    puts every lane at length 1 instead (one pair a lane, the rest of
+    the table dead); ``g`` query heads to each of the ``H`` KV heads.
+    Returns (q, k_pool, v_pool, tables, lengths), the pools f32 and
+    apart ([N, H, Bs, D] each): ``fuse_kv`` lays them side by side as
+    the kernel reads them."""
     B = 2 * G + 1
     lens = sorted({0, 1, Bs - 1, Bs, Bs + 1, G * Bs - 1, G * Bs,
-                   G * Bs + 1, 2 * G * Bs, B * Bs})
+                   G * Bs + 1, 2 * G * Bs, B * Bs}) if lens == "edges" \
+        else [1] * 8
     S = len(lens)
     N = S * B + 3                   # + null block, 2 poisoned blocks
     rs = np.random.RandomState(seed)
     ks = jax.random.split(jax.random.PRNGKey(seed), 3)
-    q = jax.random.normal(ks[0], (S, H, D))
+    q = jax.random.normal(ks[0], (S, g * H, D))
     kp = np.array(jax.random.normal(ks[1], (N, H, Bs, D)))
     vp = np.array(jax.random.normal(ks[2], (N, H, Bs, D)))
     unnamed, past = N - 1, N - 2
@@ -343,20 +346,81 @@ class TestPagedAttentionKernel:
                     np.testing.assert_allclose(base[0], poisoned[0],
                                                rtol=1e-6)
 
-    @pytest.mark.parametrize("H,Bs,D,G", [
-        (4, 4, 8, 2), (4, 8, 8, 2), (4, 16, 8, 4),
-        (25, 4, 8, 2), (25, 8, 8, 4), (25, 16, 64, 8)])
+    @pytest.mark.parametrize("H,Bs,D,G,g,lens", [
+        (4, 4, 8, 2, 1, "edges"), (4, 8, 8, 2, 1, "edges"),
+        (4, 16, 8, 4, 1, "edges"), (25, 4, 8, 2, 1, "edges"),
+        (25, 8, 8, 4, 1, "edges"), (25, 16, 64, 8, 1, "edges"),
+        # every lane one pair long, the rest of its table dead
+        (4, 4, 8, 2, 1, "ones"), (25, 16, 64, 4, 1, "ones"),
+        # grouped-query heads, on the VPU and (128 lanes) the MXU form
+        (2, 4, 8, 2, 4, "edges"), (1, 8, 64, 2, 20, "edges"),
+        (2, 4, 128, 2, 4, "edges"), (1, 4, 128, 2, 20, "ones")])
     def test_every_grid_edge_matches_xla_and_nan_stays_dark(
-            self, monkeypatch, H, Bs, D, G):
+            self, monkeypatch, H, Bs, D, G, g, lens):
+        """The grid visits the live (slot, chunk) pairs only: a free
+        lane's one pair writes zeros, a lane filling the table visits
+        its ragged last chunk, and no NaN past a length reaches a
+        row."""
         chunks_of(monkeypatch, G)
-        q, kp, vp, tbl, lens = ragged_paged_case(H, Bs, D, G)
+        q, kp, vp, tbl, lens = ragged_paged_case(H, Bs, D, G, g=g,
+                                                 lens=lens)
         pool = fuse_kv(kp, vp)
         a = np.asarray(paged_attention_xla(q, pool, tbl, lens))
         b = np.asarray(paged_attention_pallas(q, pool, tbl, lens,
                                               interpret=True))
         assert np.isfinite(b).all()
-        assert np.abs(b[0]).max() == 0.0            # the empty lane
+        if lens[0] == 0:
+            assert np.abs(b[0]).max() == 0.0        # the empty lane
         np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-5)
+
+    @pytest.mark.parametrize("window", [None, 6])
+    @pytest.mark.parametrize("G", [1, 2, 4])
+    def test_live_pairs_list_the_live_chunks_slot_major(self, G, window):
+        """The kernel's grid (``live_pairs``): ``max(1, n_s)`` pairs a
+        slot, slot-major and in chunk order, ``n`` their count, and
+        every live pair's operands aimed at the table entries the static
+        grid's chunk fetched: entry ``c * G + b`` while it holds live
+        keys, else the last live one that operand had (from the window's
+        first block on a ring, wrapping)."""
+        from deeplearning4j_tpu.kernels.paged_attention import live_pairs
+        Bs, B = 4, 9
+        lens = np.array([0, 1, 4, 5, 13, 33, 36, 40, 77, 3], np.int32)
+        if window is None:
+            lens = np.minimum(lens, B * Bs)
+        S = len(lens)
+        span = B if window is None else min(B, -(-window // Bs) + 1)
+        C = -(-span // G)
+        tbl = np.random.RandomState(G).randint(1, 99, (S, B)).astype(
+            np.int32)
+        p = live_pairs(jnp.asarray(tbl), jnp.asarray(lens), Bs, G, C,
+                       window)
+        lo = np.zeros(S, int) if window is None \
+            else np.maximum(lens - window, 0)
+        want = []                       # (slot, chunk, G entries)
+        for s, n in enumerate(lens):
+            first, last = lo[s] // Bs, (max(n, 1) - 1) // Bs
+            n_s = (last - first) // G + 1 if n else 0
+            for c in range(max(1, n_s)):
+                ent = []
+                for b in range(G):
+                    rel = c * G + b
+                    while rel > last - first and rel >= G:
+                        rel -= G        # the operand's last live block
+                    blk = first + rel
+                    ent.append(tbl[s, blk % B if window else min(blk, B - 1)])
+                want.append((s, c, ent))
+        n = int(p.n)
+        assert n == len(want) == int(np.asarray(p.n_chunks).sum())
+        assert p.slot.shape == (S * C,) and p.entry.shape == (S * C * G,)
+        got = [(int(s), int(c), list(e)) for s, c, e in zip(
+            np.asarray(p.slot)[:n], np.asarray(p.chunk)[:n],
+            np.asarray(p.entry).reshape(-1, G)[:n])]
+        live = [(s, c, e) for s, c, e in want if lens[s] > 0]
+        assert [w[:2] for w in got] == [w[:2] for w in want]
+        assert [w for w in got if lens[w[0]] > 0] == live
+        # past n the list repeats its last pair: never visited, but
+        # every index map still reads a block the pool has
+        assert (np.asarray(p.slot)[n:] == S - 1).all()
 
     @pytest.mark.parametrize("shape,itemsize,B,want", [
         ((25, 16, 64), 4, 64, 4),       # the benchmark's cell

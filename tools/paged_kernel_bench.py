@@ -7,11 +7,19 @@ lengths.
 
     chiprun -- python3 tools/paged_kernel_bench.py [--kv f32|bf16|int8]
         [--chunk-blocks 2,4,8,16] [--heads 25] [--kv-heads 25]
-        [--layers 48] [--blocks 321]
+        [--layers 48] [--blocks 321] [--head-dim 64] [--block-size 16]
+        [--table 64] [--window W]
 
 ``--heads 32 --kv-heads 8 --layers 3 --blocks 1025 --kv bf16`` is the
 grouped-query shape of ``lfm2-8b-a1b.decode_backlog`` (PR 30): three
-calls a step, four query heads to a KV head.
+calls a step, four query heads to a KV head. The long-context cells'
+calls, heads of 128 lanes on the MXU form, bf16: ``--heads 28
+--kv-heads 4 --head-dim 128 --block-size 64 --table 256 --layers 3
+--blocks 3073`` (``smallthinker-21b-a3b``'s global layers), the same
+with ``--table 81 --window 4096 --layers 9 --blocks 1297`` (its window
+layers, a ring of 81 entries a slot), and ``--heads 20 --kv-heads 1
+--head-dim 128 --block-size 64 --table 256 --layers 2 --blocks 4097``
+(``jamba2-3b``).
 
 ``--prefill`` times a chunk's two pool operations instead (PR 33),
 ``--layers`` of each in one program, every call with a pool of its own
@@ -34,11 +42,15 @@ Prints, for XLA's gather path and for the Pallas kernel at each ``G``
 own budget and cap), the milliseconds per ``--layers`` calls and the largest error against the XLA path at ``highest``
 precision, and writes them to ``chiprun_out/paged_kernel_bench_<kv>.json``.
 ``ragged`` is 15 lanes of 122-640 keys and a free lane (4,400 live
-keys), ``full`` 16 lanes of 320 (the pool holds no more), ``ones``
-every lane at length 1: the walk over the table and nothing else.
-A scratch tool of PR 27 (PERF.md sections 5 and 6: how ``G`` was
-chosen; swept again in PR 31, when the pool's layout changed). It
-measures nothing off a TPU.
+keys at a table of 1,024 positions; the lengths scale with the table's
+positions, or with four times a window's, every lane past it), ``full`` every lane as long as the table or the pool allows
+(320 at the first cell's shape), ``ones`` every lane at length 1: with
+a grid over the live (slot, chunk) pairs one grid step a lane,
+before it the walk over the whole table and nothing else. A window
+reads the last ``--window`` positions through a ring of ``--table``
+entries. A scratch tool (PERF.md sections 5 and 6: how ``G`` was
+chosen, and swept again whenever the pool's layout or the grid
+changed). It measures nothing off a TPU.
 """
 from __future__ import annotations
 
@@ -55,9 +67,9 @@ if ROOT not in sys.path:
 
 S, D, BS, B = 16, 64, 16, 64
 C = 256     # --prefill: the chunk's rows
+#: lengths at a table of 1,024 positions (the first cell's)
 RAGGED = [301, 122, 275, 155, 179, 314, 378, 545, 169, 363, 268, 187, 274,
           229, 1, 640]
-CASES = {"ragged": RAGGED, "full": [320] * S, "ones": [1] * S}
 
 
 def main(argv=None) -> int:
@@ -75,8 +87,10 @@ def main(argv=None) -> int:
     ap.add_argument("--block-size", type=int, default=BS)
     ap.add_argument("--chunk", type=int, default=C,
                     help="--prefill: rows of the chunk")
+    ap.add_argument("--table", type=int, default=B,
+                    help="table (or ring) entries a slot of the decode call")
     ap.add_argument("--window", type=int, default=None,
-                    help="--prefill: also the windowed call, over a ring")
+                    help="the windowed call, over a ring (--prefill: also)")
     ap.add_argument("--prefill", action="store_true",
                     help="a chunk's write and attention, not the decode "
                          "step's kernel")
@@ -99,6 +113,9 @@ def main(argv=None) -> int:
             "int8": quantize_rows}[a.kv]
     itemsize = {"f32": 4, "bf16": 2, "int8": 1}[a.kv]
     tag = a.kv if HKV == H == 25 else f"{a.kv}_h{H}kv{HKV}"
+    if (a.head_dim, a.block_size, a.table) != (D, BS, B) or a.window:
+        tag += f"_d{a.head_dim}bs{a.block_size}t{a.table}" + (
+            f"w{a.window}" if a.window else "")
 
     def ms(f, *args, n=10):
         f(*args).block_until_ready()
@@ -115,19 +132,27 @@ def main(argv=None) -> int:
 
     if a.prefill:
         return prefill(a, pa, cast, ms, write, tag)
+    Dh, Bs, Bt, W = a.head_dim, a.block_size, a.table, a.window
     ks = jax.random.split(jax.random.PRNGKey(0), 3)
-    q = jax.random.normal(ks[0], (S, H, D), jnp.float32)
+    q = jax.random.normal(ks[0], (S, H, Dh), jnp.float32)
     pool = pa.fuse_kv(
-        cast(jax.random.normal(ks[1], (N, HKV, BS, D), jnp.float32)),
-        cast(jax.random.normal(ks[2], (N, HKV, BS, D), jnp.float32)))
+        cast(jax.random.normal(ks[1], (N, HKV, Bs, Dh), jnp.float32)),
+        cast(jax.random.normal(ks[2], (N, HKV, Bs, Dh), jnp.float32)))
     rs = np.random.RandomState(0)
+    # a window's lanes all past it, as the long-context cells' are
+    scale = (4 * W if W else Bt * Bs) / 1024
+    full = Bt * Bs if W else min(Bt, (N - 1) // S) * Bs
+    cases = {"ragged": [max(1, int(n * scale)) if n > 1 else n
+                        for n in RAGGED],
+             "full": [full] * S, "ones": [1] * S}
 
     def tables(lens):
-        """Every live lane owns a scattered run of pool blocks."""
-        tbl = np.zeros((S, B), np.int32)
+        """Every live lane owns a scattered run of pool blocks (a ring:
+        as many as it has touched, at most all its entries)."""
+        tbl = np.zeros((S, Bt), np.int32)
         free = list(rs.permutation(np.arange(1, N)))
         for s, n in enumerate(lens):
-            for i in range(-(-n // BS) if n > 1 else 0):
+            for i in range(min(Bt, -(-n // Bs)) if n > 1 else 0):
                 tbl[s, i] = free.pop()
         return jnp.asarray(tbl), jnp.asarray(lens, jnp.int32)
 
@@ -140,22 +165,24 @@ def main(argv=None) -> int:
                 lambda i, x: q + 1e-3 * fn(x, pool, tbl, lens), q)
         return jax.jit(run)
 
-    variants = {"xla": pa.paged_attention_xla}
+    def xla(*args):
+        return pa.paged_attention_xla(*args, window=W)
+    variants = {"xla": xla}
     for g in (int(x) for x in a.chunk_blocks.split(",")):
         def kernel(*args, g=g):
             # both are read while tracing: no budget, the cap alone
             saved = pa._VMEM_BLOCK_BUDGET, pa._MAX_BLOCKS
             pa._VMEM_BLOCK_BUDGET, pa._MAX_BLOCKS = 1 << 40, g
             try:
-                return pa.paged_attention_pallas(*args)
+                return pa.paged_attention_pallas(*args, window=W)
             finally:
                 pa._VMEM_BLOCK_BUDGET, pa._MAX_BLOCKS = saved
         variants[f"pallas_G{g}"] = kernel
     res = {}
-    for case, lens in CASES.items():
+    for case, lens in cases.items():
         tbl, ln = tables(lens)
         with jax.default_matmul_precision("highest"):
-            ref = jax.jit(pa.paged_attention_xla)(q, pool, tbl, ln)
+            ref = jax.jit(xla)(q, pool, tbl, ln)
         for name, fn in variants.items():
             err = float(jnp.max(jnp.abs(
                 jax.jit(fn)(q, pool, tbl, ln) - ref)))
@@ -164,9 +191,9 @@ def main(argv=None) -> int:
                                      "max_err": err}
             print(f"{case:7s} {name:11s} {t:9.3f} ms / {LAYERS} calls   "
                   f"err {err:.2e}", flush=True)
-    live = sum(RAGGED)
+    live = sum(min(n, W) if W else n for n in cases["ragged"])
     print(f"ragged: {live} live keys, "
-          f"{live * 2 * HKV * D * itemsize * LAYERS} pool bytes a step")
+          f"{live * 2 * HKV * Dh * itemsize * LAYERS} pool bytes a step")
     write(res, f"paged_kernel_bench_{tag}.json")
     return 0
 
