@@ -13,6 +13,32 @@ def layer_norm(x, gain, bias, eps: float = 1e-5):
     return (x - m) / jnp.sqrt(v + eps) * gain + bias
 
 
+#: lanes of a TPU vector register. The device keeps a table whose width
+#: is a multiple of it row-major, and any other with its rows on the
+#: lanes (``{0,1:T(8,128)}``, the least padding), where a gather asks
+#: for row-major: the compiler then copies the whole table for every
+#: lookup (321 MB for GPT-2 XL's ``[50257, 1600]``, ~1 ms a program on
+#: a v5e: PERF.md section 7).
+LANES = 128
+
+
+def lane_padded(table):
+    """``table`` ``[V, D]`` with zero columns appended up to a multiple
+    of :data:`LANES`, so that the device keeps it row-major and a row
+    lookup (:func:`embed_rows`) reads it where it lies; as it is where
+    ``D`` is such a multiple already."""
+    pad = -table.shape[-1] % LANES
+    return table if pad == 0 else jnp.pad(table, ((0, 0), (0, pad)))
+
+
+def embed_rows(table, ids, width):
+    """``table[ids]`` cut to its first ``width`` columns: the rows of a
+    table kept :func:`lane_padded` (or not) at the integer ``ids`` of
+    any shape, out of range clamped and negatives wrapped as indexing
+    does."""
+    return table[ids][..., :width]
+
+
 #: rows the sampled row's head is computed over. A product with ONE
 #: row is no matmul to the TPU's compiler: it rewrites ``[1, d] x
 #: [d, V]`` as a multiply and a reduce over the weights converted to

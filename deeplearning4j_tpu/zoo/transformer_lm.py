@@ -25,7 +25,8 @@ from typing import List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 
-from ..nn.functional import layer_norm, sampled_row_logits
+from ..nn.functional import (embed_rows, lane_padded, layer_norm,
+                             sampled_row_logits)
 from ..nn.layers.attention import TransformerEncoderLayer
 
 
@@ -61,6 +62,23 @@ class CausalTransformerLM:
         self._params = None
 
     # -- lifecycle -----------------------------------------------------
+    @property
+    def _params(self):
+        """The parameters the forwards take, as :meth:`init` made them or
+        as they were handed in, with the token and position tables kept
+        :func:`~..nn.functional.lane_padded`: at a width that is no
+        multiple of 128 (GPT-2 XL's 1,600) the device would keep them
+        with their rows on the lanes, and every program would copy a
+        table whole to look up its rows (PERF.md section 7)."""
+        return self._stored_params
+
+    @_params.setter
+    def _params(self, params):
+        if params is not None:
+            params = dict(params, tok=lane_padded(params["tok"]),
+                          pos=lane_padded(params["pos"]))
+        self._stored_params = params
+
     def init(self) -> "CausalTransformerLM":
         rng = jax.random.PRNGKey(self.seed)
         k_tok, k_pos, k_head, k_blocks = jax.random.split(rng, 4)
@@ -94,13 +112,19 @@ class CausalTransformerLM:
         return [blk.cache_shape(n) for blk in self.blocks]
 
     # -- pure forwards -------------------------------------------------
+    def _embed(self, params, tokens, pos):
+        """Token plus position embeddings ``[..., d_model]`` of the
+        integer ``tokens`` at the positions ``pos`` (broadcast)."""
+        return (embed_rows(params["tok"], tokens, self.d_model)
+                + embed_rows(params["pos"], pos, self.d_model))
+
     def forward_prefill(self, params, tokens, key_mask=None):
         """Full-prompt causal pass. tokens: [B, T] int32 (T <= the
         compiled bucket); key_mask: optional [B, T] validity for padded
         prompts. Returns (logits [B, T, V], ks, vs) where ks/vs are
         per-layer [B, H, T, Dh] slabs in decode-cache layout."""
         B, T = tokens.shape
-        x = params["tok"][tokens] + params["pos"][jnp.arange(T)][None]
+        x = self._embed(params, tokens, jnp.arange(T)[None])
         if key_mask is not None:
             x = x * key_mask[..., None]
         ks, vs = [], []
@@ -118,7 +142,7 @@ class CausalTransformerLM:
         position; k_caches/v_caches: per-layer [S, H, T_max, Dh].
         Returns (logits [S, V], k_caches, v_caches) with each layer's
         K/V written at ``pos``."""
-        x = params["tok"][tokens] + params["pos"][pos]
+        x = self._embed(params, tokens, pos)
         new_k, new_v = [], []
         for blk, bp, kc, vc in zip(self.blocks, params["blocks"],
                                    k_caches, v_caches):
@@ -141,7 +165,7 @@ class CausalTransformerLM:
         program for a model that keeps arrays a slot; this one keeps
         none and hands it back as it came.
         Returns (logits [S, V], pools, state)."""
-        x = params["tok"][tokens] + params["pos"][pos]
+        x = self._embed(params, tokens, pos)
         new_pools = []
         for blk, bp, pool in zip(self.blocks, params["blocks"], pools):
             x, pool = blk.apply_decode_paged(bp, x, pool, block_tables,
@@ -176,8 +200,8 @@ class CausalTransformerLM:
         # padded tail rows can run past the position table; clamp the
         # lookup — their embeddings are zeroed below and their K/V
         # lands beyond the live length, where the mask keeps it dark
-        x = (params["tok"][tokens[0]]
-             + params["pos"][jnp.clip(gpos, 0, self.max_seq_len - 1)])
+        x = self._embed(params, tokens[0],
+                        jnp.clip(gpos, 0, self.max_seq_len - 1))
         row_mask = (jnp.arange(C) < chunk_len).astype(x.dtype)
         x = (x * row_mask[:, None])[None]
         new_pools = []
@@ -207,8 +231,8 @@ class CausalTransformerLM:
         cache row. Returns (logits [C, V], k_caches, v_caches)."""
         C = tokens.shape[1]
         gpos = p0 + jnp.arange(C)
-        x = (params["tok"][tokens[0]]
-             + params["pos"][jnp.clip(gpos, 0, self.max_seq_len - 1)])
+        x = self._embed(params, tokens[0],
+                        jnp.clip(gpos, 0, self.max_seq_len - 1))
         row_mask = (jnp.arange(C) < chunk_len).astype(x.dtype)
         x = (x * row_mask[:, None])[None]
         new_k, new_v = [], []

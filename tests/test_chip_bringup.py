@@ -531,3 +531,53 @@ def test_the_jamba_cells_programs_fit_and_relay_no_state(
     else:       # the step's recurrence is XLA's fusion: no kernel
         assert names == {"paged_attention_decode"}
         assert len(_custom_calls(text)) == 2    # one a attention layer
+
+
+# -- the first cell's embedding tables ---------------------------------------
+@pytest.mark.parametrize("which", ["step", "chunk"])
+def test_the_first_cells_programs_fetch_token_rows_where_the_table_lies(
+        v5e, monkeypatch, which):
+    """One layer of ``CausalTransformerLM`` at ``gpt2-xl``'s widths: its
+    decode step over 16 slots and its 256-row chunk (last row only, as
+    the engine asks) compile for a v5e with the token and position
+    tables taken lane-padded to 1,664 and row-major, and no ``copy`` of
+    either table. At 1,600 lanes the device keeps a table with its rows
+    on the lanes, and both programs copied the 321 MB token table to
+    row-major for the gather: 0.995 ms of each (PERF.md section 7)."""
+    import importlib
+    from deeplearning4j_tpu.zoo.transformer_lm import CausalTransformerLM
+    monkeypatch.setattr(importlib.import_module(
+        "deeplearning4j_tpu.kernels.paged_attention"), "default_platform",
+        lambda: "tpu")
+    lm = CausalTransformerLM(vocab_size=50257, d_model=1600, n_layers=1,
+                             n_heads=25, d_ff=6400, max_seq_len=1024)
+    params = jax.eval_shape(lambda: lm.init()._params)
+    lm._params = None
+    sds = jax.ShapeDtypeStruct
+    i32 = lambda *s: sds(s, jnp.int32)                    # noqa: E731
+    pools = [_pool(321, 25, 16, 64, "f32")]
+    if which == "step":
+        text = v5e(lm.forward_decode_paged, params, i32(16), i32(16),
+                   pools, i32(16, 64), donate=(3,))
+    else:
+        text = v5e(lambda p, t, p0, n, pools, bt: lm.forward_prefill_chunk(
+            p, t, p0, n, pools, bt, last_only=True),
+            params, i32(1, 256), i32(), i32(), pools, i32(64),
+            donate=(4,), kernel=False)
+    (entry,) = re.findall(r"entry_computation_layout=\{\(([^\n]*)", text)
+    for rows in (50257, 1024):
+        assert "f32[%d,1664]{1,0:T(8,128)}" % rows in entry
+        table = r"f32\[%d,16(00|64)\]" % rows
+        assert not re.search(r"= %s\S* copy\(" % table, text)
+        assert not re.search(r"%s\{0,1" % table, text)
+
+
+def test_a_table_of_whole_lanes_is_looked_up_by_the_plain_gather():
+    """At 2,048 lanes (the other cells' widths are multiples of 128) the
+    padding and the row lookup lower to the program ``table[ids]``
+    lowers to."""
+    from deeplearning4j_tpu.nn.functional import embed_rows, lane_padded
+    args = (jax.ShapeDtypeStruct((65536, 2048), jnp.bfloat16),
+            jax.ShapeDtypeStruct((16,), jnp.int32))
+    assert (jax.jit(lambda t, i: embed_rows(lane_padded(t), i, 2048)).lower(
+        *args).as_text() == jax.jit(lambda t, i: t[i]).lower(*args).as_text())
